@@ -75,6 +75,15 @@ class Platform(abc.ABC):
         """
         return (self.name, {}, type(self).__module__)
 
+    def measures_accelerator(self) -> bool:
+        """Whether measuring runs on an attached accelerator chip.
+
+        A chip belongs to one process at a time, so the measurement runtime
+        refuses a worker pool over such a platform.  Analytical platforms
+        (and the synthetic mode of real ones) measure nothing on a device.
+        """
+        return False
+
     # ---- measurement ---------------------------------------------------------------
     @abc.abstractmethod
     def measure(self, layer_type: str, cfg: Config) -> float:

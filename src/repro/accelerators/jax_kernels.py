@@ -2,9 +2,9 @@
 
 Each platform's ``measure_batch`` calls its hook here first; a hook returns
 ``None`` whenever the jax backend is not active (``REPRO_PREDICT_BACKEND``,
-see :mod:`repro.core.jax_predict`), jax is unavailable, or the request needs
-scalar semantics the kernel cannot reproduce (noisy TPU mode, xla_cpu
-wall-clock mode) — the caller then continues on its numpy path unchanged.
+see :mod:`repro.core.jax_predict`) or the request needs scalar semantics the
+kernel cannot reproduce (noisy TPU mode, xla_cpu wall-clock mode) — the
+caller then continues on its numpy path unchanged.
 Third-party platforms never touch this module.
 
 Parity is **bitwise** with the numpy models (asserted in
@@ -26,14 +26,12 @@ import functools
 
 import numpy as np
 
-from repro.core.jax_predict import bucket_rows, jax_modules, resolve_backend
+from repro.core.jax_predict import bucket_rows, jax_modules, resolve_backend, x64
 
 
-def _active(backend: str | None) -> tuple | None:
-    """The jax module tuple when the backend resolves to jax, else None."""
-    if resolve_backend(backend) != "jax":
-        return None
-    return jax_modules()
+def _active(platform) -> bool:
+    """Whether the platform's predict backend resolves to jax."""
+    return resolve_backend(getattr(platform, "predict_backend", None)) == "jax"
 
 
 def _padded(col, n: int, nb: int) -> np.ndarray:
@@ -46,7 +44,7 @@ def _padded(col, n: int, nb: int) -> np.ndarray:
 # ------------------------------------------------------------------ TPU v5e
 @functools.lru_cache(maxsize=None)
 def _tpu_fn(layer_type: str, mxu: int, sublane: int, kv_page: int, ssd_chunk: int):
-    jax, jnp, _, _ = jax_modules()
+    jax, jnp, _ = jax_modules()
 
     def pad(v, m):
         return -(-v // m) * m
@@ -123,9 +121,8 @@ def _tpu_fn(layer_type: str, mxu: int, sublane: int, kv_page: int, ssd_chunk: in
 
 def tpu_measure_batch(platform, layer_type: str, batch) -> np.ndarray | None:
     """Jitted ``TPUv5eSim.measure_batch`` (noise-free mode only)."""
-    mods = _active(getattr(platform, "predict_backend", None))
     n = len(batch)
-    if mods is None or platform.noise > 0 or n == 0:
+    if not _active(platform) or platform.noise > 0 or n == 0:
         return None
     c = platform.chip
     try:
@@ -136,8 +133,7 @@ def tpu_measure_batch(platform, layer_type: str, batch) -> np.ndarray | None:
     cols = {p: _padded(batch.column(p), n, nb) for p in batch.params}
     kv = batch.get("kv_ratio", platform.kv_ratio)
     kv = _padded(kv, n, nb) if isinstance(kv, np.ndarray) else np.int64(kv)
-    _, _, _, enable_x64 = mods
-    with enable_x64():
+    with x64():
         t = fn(
             cols, kv,
             np.float64(c.peak_bf16_flops),
@@ -150,7 +146,7 @@ def tpu_measure_batch(platform, layer_type: str, batch) -> np.ndarray | None:
 # --------------------------------------------------------------- UltraTrail
 @functools.lru_cache(maxsize=None)
 def _ultratrail_fn(array: int):
-    jax, jnp, _, _ = jax_modules()
+    jax, jnp, _ = jax_modules()
 
     def run(C, K, C_w, F, s, pad_, overhead, clock):
         c_tiles = -(-C // array)
@@ -165,14 +161,12 @@ def _ultratrail_fn(array: int):
 
 def ultratrail_measure_batch(platform, layer_type: str, batch) -> np.ndarray | None:
     """Jitted ``UltraTrailSim.measure_batch``."""
-    mods = _active(getattr(platform, "predict_backend", None))
     n = len(batch)
-    if mods is None or layer_type != "conv1d" or n == 0:
+    if not _active(platform) or layer_type != "conv1d" or n == 0:
         return None
     nb = bucket_rows(n)
     fn = _ultratrail_fn(platform.ARRAY)
-    _, _, _, enable_x64 = mods
-    with enable_x64():
+    with x64():
         t = fn(
             _padded(batch.column("C"), n, nb),
             _padded(batch.column("K"), n, nb),
@@ -189,7 +183,7 @@ def ultratrail_measure_batch(platform, layer_type: str, batch) -> np.ndarray | N
 # ---------------------------------------------------------------------- VTA
 @functools.lru_cache(maxsize=None)
 def _vta_fn(layer_type: str, tile: int):
-    jax, jnp, _, _ = jax_modules()
+    jax, jnp, _ = jax_modules()
 
     def gemm_cycles(m, k, n, io_lanes):
         kt = -(-k // tile)
@@ -219,9 +213,8 @@ def _vta_fn(layer_type: str, tile: int):
 
 def vta_measure_batch(platform, layer_type: str, batch) -> np.ndarray | None:
     """Jitted ``VTASim.measure_batch``."""
-    mods = _active(getattr(platform, "predict_backend", None))
     n = len(batch)
-    if mods is None or n == 0 or layer_type not in ("conv2d", "fully_connected"):
+    if not _active(platform) or n == 0 or layer_type not in ("conv2d", "fully_connected"):
         return None
     nb = bucket_rows(n)
     if layer_type == "conv2d":
@@ -236,8 +229,7 @@ def vta_measure_batch(platform, layer_type: str, batch) -> np.ndarray | None:
         cols = {p: _padded(batch.column(p), n, nb) for p in ("in", "out")}
         pad_ = s = np.int64(1)
     fn = _vta_fn(layer_type, platform.GEMM_TILE)
-    _, _, _, enable_x64 = mods
-    with enable_x64():
+    with x64():
         t = fn(
             cols, pad_, s,
             np.float64(platform.IO_LANES),
@@ -250,7 +242,7 @@ def vta_measure_batch(platform, layer_type: str, batch) -> np.ndarray | None:
 # ------------------------------------------------------------------ XLA CPU
 @functools.lru_cache(maxsize=None)
 def _xla_synthetic_fn(tile_m: int, tile_kn: int):
-    jax, _, _, _ = jax_modules()
+    jax = jax_modules()[0]
 
     def run(m, k, n, syn_flops, overhead):
         em = -(-m // tile_m) * tile_m
@@ -268,14 +260,12 @@ def xla_cpu_measure_batch(platform, layer_type: str, batch) -> np.ndarray | None
     deterministic synthetic proxy compiles.  Values are identical whether or
     not they pass through ``platform._cache``, so the kernel skips it.
     """
-    mods = _active(getattr(platform, "predict_backend", None))
     n = len(batch)
-    if mods is None or not platform.synthetic or layer_type != "dense" or n == 0:
+    if not _active(platform) or not platform.synthetic or layer_type != "dense" or n == 0:
         return None
     nb = bucket_rows(n)
     fn = _xla_synthetic_fn(platform.SYN_TILE_M, platform.SYN_TILE_KN)
-    _, _, _, enable_x64 = mods
-    with enable_x64():
+    with x64():
         t = fn(
             _padded(batch.column("tokens"), n, nb),
             _padded(batch.column("d_in"), n, nb),

@@ -1,9 +1,14 @@
-"""XLA-CPU platform: *real* wall-clock measurements on this machine.
+"""XLA wall-clock platform: *real* measurements on JAX's first device.
 
 This is the black-box platform analog of the paper's Jetson AGX Xavier: a real,
 noisy computing device where nothing about tiling is documented to the
-methodology.  Layers are jitted with XLA and timed; the paper's median-of-k
-protocol (it used 500 runs on the Jetson) mitigates warm-up noise.
+methodology.  Layers are jitted with XLA and timed on ``jax.devices()[0]`` --
+the CPU where JAX has only the CPU, the chip where an accelerator is attached;
+the paper's median-of-k protocol (it used 500 runs on the Jetson) mitigates
+warm-up noise.  The device's platform and kind are part of the platform's
+``name`` and ``cache_key()``, so measurements (and the estimators trained on
+them) taken on one device are never cached, journaled or loaded as another's.
+The registry name stays ``xla_cpu``.
 
 Measurement is expensive -- keep parameter spaces small and use this platform
 for the black-box evaluation path only.  With the measurement runtime
@@ -15,15 +20,16 @@ analytical proxy (same parameter space, same step structure).  That mode
 exists for the runtime's reproducibility guarantees — bitwise-identical
 campaigns across worker counts, byte-identical resumed checkpoints — which a
 noisy wall clock cannot certify, and for CI smoke runs on contended runners.
-jax is imported lazily on the first real measurement, so synthetic workers
-(and journal replays) never pay the jax startup cost.
+jax is imported lazily, when a wall-clock platform first needs its device
+(its name, cache key or a measurement), so synthetic workers (and journal
+replays) never pay the jax startup cost.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 import time
-from functools import lru_cache
 
 import numpy as np
 
@@ -31,26 +37,22 @@ from repro.accelerators.base import Platform
 from repro.registry import register_platform
 from repro.core.batch import BlockBatch, ConfigBatch
 from repro.core.prs import Config, ParamSpace
+from repro.obs.metrics import metrics as obs_metrics
 
 
-@lru_cache(maxsize=1)
+def _matmul(a, b):
+    return a @ b
+
+
+@functools.lru_cache(maxsize=1)
 def _jit_dense():
     """Deferred jax import + jit: only the wall-clock path needs a device."""
-    from functools import partial
-
     import jax
-    import jax.numpy as jnp
 
-    @partial(jax.jit, static_argnums=(0, 1, 2))
-    def dense(m: int, k: int, n: int, a, b):
-        del m, k, n
-        return a @ b
-
-    return jnp, dense
+    return jax, jax.jit(_matmul)
 
 
 class XLACPUPlatform(Platform):
-    name = "xla_cpu"
     knowledge = "black"
 
     #: synthetic-mode model: row tile, contraction/output tile, GEMM rate
@@ -64,6 +66,23 @@ class XLACPUPlatform(Platform):
         self.dtype = np.dtype(dtype)  # accepts "float32", np.float32, jnp.float32
         self.synthetic = bool(synthetic)
         self._cache: dict[tuple, float] = {}
+
+    @functools.cached_property
+    def device(self):
+        """The JAX device wall-clock measurements run on (imports jax)."""
+        import jax
+
+        return jax.devices()[0]
+
+    @property
+    def name(self) -> str:
+        """``xla_cpu`` in synthetic mode, else ``xla_cpu[<platform>:<device kind>]``."""
+        if self.synthetic:
+            return "xla_cpu"
+        return f"xla_cpu[{self.device.platform}:{self.device.device_kind}]"
+
+    def measures_accelerator(self) -> bool:
+        return not self.synthetic and self.device.platform != "cpu"
 
     def cache_key(self) -> str:
         mode = "|synthetic" if self.synthetic else ""
@@ -108,16 +127,40 @@ class XLACPUPlatform(Platform):
         en = math.ceil(n / self.SYN_TILE_KN) * self.SYN_TILE_KN
         return 2.0 * em * ek * en / self.SYN_FLOPS + self.SYN_OVERHEAD_S
 
+    def _compile(self, key: tuple):
+        """Compile ``(m, k) @ (k, n)`` for :attr:`device` (no operands needed)."""
+        jax, dense = _jit_dense()
+        m, k, n = key
+        on = jax.sharding.SingleDeviceSharding(self.device)
+        return dense.lower(
+            jax.ShapeDtypeStruct((m, k), self.dtype, sharding=on),
+            jax.ShapeDtypeStruct((k, n), self.dtype, sharding=on),
+        ).compile()
+
     def _wallclock_time(self, m: int, k: int, n: int) -> float:
-        jnp, dense = _jit_dense()
-        a = jnp.ones((m, k), self.dtype)
-        b = jnp.ones((k, n), self.dtype)
-        dense(m, k, n, a, b).block_until_ready()  # compile + warm up
+        """Median of ``repeats`` timed runs on :attr:`device`.
+
+        The program is compiled ahead of time (operands are not needed for
+        that) so its compile seconds are measured apart from the run;
+        operands are then copied from the host and one untimed call warms
+        up.  Compile and timed-loop seconds go to the ``xla_cpu.compile_s`` /
+        ``xla_cpu.timed_s`` histograms; ``xla_cpu.shapes`` counts the shapes.
+        """
+        jax, _ = _jit_dense()
+        reg = obs_metrics()
+        t0 = time.perf_counter()
+        dense = self._compile((m, k, n))
+        reg.observe_value("xla_cpu.compile_s", time.perf_counter() - t0)
+        a = jax.device_put(np.ones((m, k), self.dtype), self.device)
+        b = jax.device_put(np.ones((k, n), self.dtype), self.device)
+        dense(a, b).block_until_ready()  # warm up
         samples = []
         for _ in range(self.repeats):
             t0 = time.perf_counter()
-            dense(m, k, n, a, b).block_until_ready()
+            dense(a, b).block_until_ready()
             samples.append(time.perf_counter() - t0)
+        reg.observe_value("xla_cpu.timed_s", sum(samples))
+        reg.inc("xla_cpu.shapes")
         return float(np.median(samples))
 
     def measure_batch(self, layer_type: str, batch: ConfigBatch) -> np.ndarray:

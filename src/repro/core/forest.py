@@ -324,9 +324,7 @@ class RandomForestRegressor:
             # Compiled traversal when the jax backend is active (explicit arg
             # or REPRO_PREDICT_BACKEND); bitwise-identical to the fold below.
             if jax_predict.resolve_backend(backend) == "jax":
-                y = jax_predict.forest_predict_raw(self, X)
-                if y is not None:
-                    return y
+                return jax_predict.forest_predict_raw(self, X)
         per_tree = self._stacked().predict_all(X)
         # Accumulate tree by tree (not np.sum's pairwise order) so the mean is
         # bitwise equal to the historical ``acc += tree.predict(X)`` loop.

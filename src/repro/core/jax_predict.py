@@ -14,29 +14,32 @@ Backend selection
   friends, or ``PerfOracle.predict_backend``) wins;
 * otherwise the ``REPRO_PREDICT_BACKEND`` environment variable
   (``numpy`` | ``jax`` | ``auto``) decides; unset means ``numpy``;
-* ``jax`` falls back to numpy (with a one-time warning) when jax cannot be
-  imported; ``auto`` means jax-if-available, silently.
+* ``jax`` raises when jax is not installed; ``auto`` means jax when it is
+  installed and numpy only when it is not.  An installed jax whose API does
+  not match this module raises at the first compiled call: it never turns
+  into numpy.
 
-Every jax entry point in this repo returns ``None`` when it cannot serve a
-request (jax missing, stub estimators, ragged inputs, noisy platforms) and the
-caller continues on the numpy path — third-party platforms and estimator
+Compiled entry points return ``None`` only for requests the kernels do not
+model (stub estimators, ragged inputs, noisy or wall-clock platforms), and
+the caller continues on the numpy path — third-party platforms and estimator
 stubs never see the backend at all.
 
 Parity contract (asserted in tests/test_jax_predict.py and in-bench)
 --------------------------------------------------------------------
-All kernels run in float64 via the scoped ``jax.experimental.enable_x64()``
-context (never the global flag: flipping ``jax_enable_x64`` process-wide
-would change the dtype behaviour of unrelated jax code in the same process).
+All kernels run in float64 via the scoped ``jax.enable_x64(True)`` context
+(never the global flag: flipping ``jax_enable_x64`` process-wide would change
+the dtype behaviour of unrelated jax code in the same process).
 
-* **Layer predictions are bitwise identical** to numpy.  The compiled
+* **Layer predictions are bitwise identical** to numpy on the CPU, and
+  within :data:`DEVICE_RTOL` on a TPU, whose float64 is emulated.  The compiled
   traversal replays the numpy descent loop gather-for-gather, accumulates
   per-tree values in tree order (``lax.fori_loop`` left fold — *not*
   ``jnp.sum``, whose pairwise order differs), and divides by a *traced*
   tree-count scalar (XLA strength-reduces division by a compile-time constant
   into multiplication by its reciprocal, a 1-ulp difference; a traced divisor
   keeps the true division).  The log-target inversion stays ``np.exp``
-  *outside* the jit, so :meth:`LayerEstimator.predict` is bit-for-bit equal
-  across backends.
+  *outside* the jit, so on the CPU :meth:`LayerEstimator.predict` is
+  bit-for-bit equal across backends.
 * **Platform timing kernels are bitwise identical**: integer tile padding is
   exact arithmetic, and every float hardware constant (peak FLOPs,
   bandwidths, clock rates) is passed as a traced scalar for the same
@@ -76,6 +79,11 @@ _BACKENDS = ("numpy", "jax", "auto")
 #: rows are padded up to the next power of two, at least this many
 _MIN_BUCKET = 64
 
+#: relative tolerance of compiled predictions against numpy on a chip with
+#: no native float64 (the TPU emulates it, so the per-tree sum differs in the
+#: last bits; 1.13e-13 at most on a TPU v5e, see README).  The CPU is bitwise.
+DEVICE_RTOL = 1e-12
+
 # Compile/retrace observability: jit caches on argument shapes, so a novel
 # shape signature means XLA is compiling right now.  ``jax.*.calls`` vs
 # ``jax.*.traces`` in the metrics snapshot is the direct retrace-rate signal —
@@ -94,29 +102,29 @@ def _count_trace(kind: str, seen: set, sig: tuple) -> None:
 
 _modules_cache: tuple | None = None
 _import_failed = False
-_warned_fallback = False
 
 
 def jax_modules() -> tuple | None:
-    """``(jax, jnp, lax, enable_x64)`` or None when jax cannot be imported.
+    """``(jax, jnp, lax)``, or None when jax is not installed.
 
     The import is deferred so numpy-only deployments (and the CI leg that
-    asserts no eager jax import) never pay for it at module load.
+    asserts no eager jax import) never pay for it at module load.  Only a
+    missing jax package means None; any other failure raises.
     """
     global _modules_cache, _import_failed
     if _modules_cache is None and not _import_failed:
         try:
             import jax
-            import jax.numpy as jnp
-            from jax import lax
-            from jax.experimental import enable_x64
-        except Exception:  # ImportError or backend-init failure: numpy path
+        except ImportError:
             _import_failed = True
             return None
+        import jax.numpy as jnp
+        from jax import lax
+
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable"
         )
-        _modules_cache = (jax, jnp, lax, enable_x64)
+        _modules_cache = (jax, jnp, lax)
     return _modules_cache
 
 
@@ -124,9 +132,13 @@ def jax_available() -> bool:
     return jax_modules() is not None
 
 
+def x64():
+    """Scoped float64 for one compiled call (never the process-wide flag)."""
+    return jax_modules()[0].enable_x64(True)
+
+
 def resolve_backend(backend: str | None = None) -> str:
     """Resolve an explicit/env backend request to ``"numpy"`` or ``"jax"``."""
-    global _warned_fallback
     choice = backend
     if choice is None:
         choice = os.environ.get(_ENV_VAR, "").strip().lower() or "numpy"
@@ -138,14 +150,8 @@ def resolve_backend(backend: str | None = None) -> str:
         return "numpy"
     if jax_available():
         return "jax"
-    if choice == "jax" and not _warned_fallback:
-        warnings.warn(
-            "predict backend 'jax' requested but jax is unavailable; "
-            "falling back to numpy",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        _warned_fallback = True
+    if choice == "jax":
+        raise RuntimeError("predict backend 'jax' requested but jax is not installed")
     return "numpy"
 
 
@@ -190,7 +196,7 @@ def _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees):
 
 @functools.lru_cache(maxsize=1)
 def _forest_fn():
-    jax, jnp, lax, _ = jax_modules()
+    jax, jnp, lax = jax_modules()
 
     def run(feature, threshold, left, right, value, X, n_trees):
         return _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees)
@@ -226,17 +232,14 @@ class ForestEngine:
             "forest", _seen_forest_sigs,
             tuple(a.shape for a in self._arrays) + ((nb, d),),
         )
-        _, _, _, enable_x64 = jax_modules()
         fn = _forest_fn()
-        with enable_x64():
+        with x64():
             y = fn(*self._arrays, Xp, self._n_trees)
         return np.asarray(y)[:n]
 
 
-def forest_predict_raw(forest, X: np.ndarray) -> np.ndarray | None:
-    """Jitted ``RandomForestRegressor.predict``; None when jax can't serve it."""
-    if jax_modules() is None:
-        return None
+def forest_predict_raw(forest, X: np.ndarray) -> np.ndarray:
+    """Jitted ``RandomForestRegressor.predict``."""
     stack = forest._stacked()
     engine = getattr(stack, "_jax_engine", None)
     if engine is None:
@@ -254,7 +257,7 @@ def _network_fn(log_flags: tuple):
     graph; everything else (positions, combination masks, constants) is
     traced so shape buckets are the only retrace axis.
     """
-    jax, jnp, lax, _ = jax_modules()
+    jax, jnp, lax = jax_modules()
 
     def run(
         groups, Xs, block_seg, counts, overlap, fused, w, c, ops, rep,
@@ -292,8 +295,6 @@ def predict_network_batch_jax(oracle, batch, net_id, n_nets) -> np.ndarray | Non
     with zero layers — the numpy path owns those semantics (including the
     empty-overlap-block ``ValueError``).
     """
-    if jax_modules() is None:
-        return None
     n_blocks = len(batch)
     counts = batch.layer_counts()
     if n_blocks == 0 or np.any(counts == 0):
@@ -375,9 +376,8 @@ def predict_network_batch_jax(oracle, batch, net_id, n_nets) -> np.ndarray | Non
         (tuple(log_flags), Lb, Bb, Nb)
         + tuple((g[0].shape, X.shape) for g, X in zip(groups, Xs)),
     )
-    _, _, _, enable_x64 = jax_modules()
     fn = _network_fn(tuple(log_flags))
-    with enable_x64():
+    with x64():
         out = fn(
             tuple(groups), tuple(Xs), block_seg, counts_p, overlap, fused, w, c,
             ops, rep, net_seg, net_dummy, np.float64(oracle.launch_overhead_s),

@@ -4,8 +4,11 @@ Grid layout: (batch, q_heads, num_q_blocks, num_k_blocks); the last grid axis
 is sequential on TPU, so the online-softmax running state (m, l, acc) lives in
 VMEM scratch that persists across the k-block iterations of one q block.
 
-BlockSpecs keep one (block_q x d) query tile, one (block_k x d) K and V tile in
-VMEM; with block_q = block_k = 128 and d = 128 the MXU sees 128x128 matmuls and
+Arrays are head-major -- q (B, H, Sq, D), k/v (B, KVH, Skv, D) -- so every
+block is ``(1, 1, rows, D)``: its last two dimensions are a sequence tile and
+the (128-padded) head dim, which is the (8, 128) tiling the TPU compiler
+requires.  BlockSpecs keep one (block_q x d) query tile, one (block_k x d) K
+and V tile in VMEM; with block_q = block_k = 128 and d = 128 the MXU sees 128x128 matmuls and
 the VMEM working set is ~4 tiles x 64 KiB -- far below the 128 MiB/core budget,
 leaving room for double buffering of the K/V streams.
 
@@ -27,10 +30,10 @@ NEG_INF = -1e30
 
 
 def _kernel(
-    q_ref,  # (1, block_q, 1, d)
-    k_ref,  # (1, block_k, 1, d)
-    v_ref,  # (1, block_k, 1, d)
-    o_ref,  # (1, block_q, 1, d)
+    q_ref,  # (1, 1, block_q, d)
+    k_ref,  # (1, 1, block_k, d)
+    v_ref,  # (1, 1, block_k, d)
+    o_ref,  # (1, 1, block_q, d)
     m_ref,  # scratch (block_q,)
     l_ref,  # scratch (block_q,)
     acc_ref,  # scratch (block_q, d)
@@ -58,9 +61,13 @@ def _kernel(
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * sm_scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        # Same precision as the XLA twin (models/attention.chunked_attention):
+        # q is scaled in its own dtype, both matmuls take the input dtype on
+        # the MXU with f32 accumulation, and probabilities enter P @ V in the
+        # input dtype while the softmax statistics stay f32.
+        q = q_ref[0, 0] * sm_scale
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bq, bk)
@@ -76,19 +83,20 @@ def _kernel(
         scale = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * scale + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * scale + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
         m_ref[...] = m_new
 
     @pl.when(ik == num_kb - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-37)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
-    q: jax.Array,  # (B, Sq, H, D)
-    k: jax.Array,  # (B, Skv, KVH, D)
+    q: jax.Array,  # (B, H, Sq, D)
+    k: jax.Array,  # (B, KVH, Skv, D)
     v: jax.Array,
     *,
     causal: bool = True,
@@ -97,8 +105,8 @@ def flash_attention_pallas(
     interpret: bool = False,
     sm_scale: float | None = None,
 ) -> jax.Array:
-    b, sq, h, d = q.shape
-    skv, kvh = k.shape[1], k.shape[2]
+    b, h, sq, d = q.shape
+    kvh, skv = k.shape[1], k.shape[2]
     assert h % kvh == 0, (h, kvh)
     assert sq % block_q == 0, "pad queries before calling (see ops.py)"
     assert skv % block_k == 0, "pad keys before calling (see ops.py)"
@@ -120,12 +128,12 @@ def flash_attention_pallas(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, h_, iq, ik: (b_, ik, h_ * kvh // h, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda b_, h_, iq, ik: (b_, ik, h_ * kvh // h, 0)),
+            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, iq, ik: (b_, h_ * kvh // h, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, iq, ik: (b_, h_ * kvh // h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d), lambda b_, h_, iq, ik: (b_, iq, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, iq, ik: (b_, h_, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),

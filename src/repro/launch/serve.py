@@ -22,6 +22,7 @@ numpy — so the server starts in milliseconds and runs anywhere:
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import numpy as np
@@ -33,33 +34,53 @@ from repro.configs import get_config
 # --serve-oracle) stay importable on a jax-free box.
 
 
-def generate(cfg, params, prompts: np.ndarray, gen_len: int, extras: dict | None = None):
-    """Greedy generation: prefill via forward-with-cache, then decode steps."""
+@functools.lru_cache(maxsize=None)
+def _serve_fns(cfg):
+    """Jitted (prefill, decode step) for one config, shared across calls."""
     import jax
-    import jax.numpy as jnp
 
     from repro.models import transformer as T
-    from repro.models.kvcache import init_cache
     from repro.train.steps import make_serve_step
+
+    prefill = jax.jit(lambda p, batch, c: T.forward(p, cfg, batch, c))
+    return prefill, jax.jit(make_serve_step(cfg))
+
+
+def generate(cfg, params, prompts: np.ndarray, gen_len: int, extras: dict | None = None):
+    """Greedy generation: prefill via forward-with-cache, then decode steps.
+
+    The prefill and every decode step end in ``block_until_ready``; their
+    wall seconds (compilation included on a config's first call) go to the
+    ``serve.prefill_s`` and ``serve.decode_step_s`` histograms.
+    """
+    import jax.numpy as jnp
+
+    from repro.models.kvcache import init_cache
+    from repro.obs.metrics import metrics as obs_metrics
 
     b, s = prompts.shape
     cache = init_cache(cfg, b, s + gen_len)
     if cfg.family == "audio":
         cache.pop("enc_kv")  # computed at prefill
-
-    prefill = jax.jit(lambda p, batch, c: T.forward(p, cfg, batch, c))
-    serve_step = jax.jit(make_serve_step(cfg))
+    reg = obs_metrics()
+    prefill, serve_step = _serve_fns(cfg)
 
     batch = {"tokens": jnp.asarray(prompts)}
     if extras:
         batch.update({k: jnp.asarray(v) for k, v in extras.items()})
+    t0 = time.perf_counter()
     logits, _, cache = prefill(params, batch, cache)
     next_tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+    next_tok.block_until_ready()
+    reg.observe_value("serve.prefill_s", time.perf_counter() - t0)
 
     out = [next_tok]
     for _ in range(gen_len - 1):
         step_batch = {"tokens": out[-1][:, None]}
+        t0 = time.perf_counter()
         next_tok, cache = serve_step(params, cache, step_batch)
+        next_tok.block_until_ready()
+        reg.observe_value("serve.decode_step_s", time.perf_counter() - t0)
         out.append(next_tok)
     return jnp.stack(out, axis=1)
 
@@ -308,8 +329,10 @@ def main() -> None:
     import jax
 
     from repro.distributed import single_device_rules, use_rules
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import transformer as T
 
+    enable_compile_cache()
     rules = single_device_rules()
     with use_rules(rules):
         params = T.init_params(cfg, jax.random.PRNGKey(0))

@@ -19,6 +19,7 @@ import jax
 
 from repro.configs import get_config
 from repro.distributed import for_mesh, single_device_rules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.config import InputShape, reduced
 from repro.optim.adamw import AdamWConfig
@@ -41,6 +42,7 @@ def main() -> None:
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(message)s")
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -54,6 +54,8 @@ class RuntimeSpec:
     """Declarative description of how a campaign's measurements execute."""
 
     #: 1 => in-process serial executor; >1 => process pool of this size
+    #: (refused for a platform that measures on an attached accelerator:
+    #: one process per chip)
     workers: int = 1
     #: rows per scheduler chunk (the unit of dispatch, retry and journaling);
     #: None derives the size adaptively from the platform's measured per-item
@@ -104,6 +106,12 @@ class MeasurementRuntime:
             if spec.journal_path
             else None
         )
+        if spec.workers > 1 and platform.measures_accelerator():
+            raise ValueError(
+                f"platform {platform.name!r} measures on an attached accelerator, "
+                "and a chip belongs to one process: one process per chip, so "
+                f"run it with workers=1, not workers={spec.workers}"
+            )
         if spec.workers > 1:
             self.executor = WorkerPool(
                 platform.spawn_spec(), spec.workers, mp_context=spec.mp_context

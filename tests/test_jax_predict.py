@@ -6,8 +6,10 @@ The contract under test (see src/repro/core/jax_predict.py):
   are **bitwise** identical across backends;
 * whole-network predictions are bitwise except when a log-target ``exp``
   runs inside the compiled call (rtol 1e-12 there);
-* every jax entry point degrades to the numpy path (never an error) when jax
-  is unavailable or the request needs scalar semantics;
+* ``jax`` is an error when jax is not installed, ``auto`` is numpy only then,
+  and an installed jax whose API breaks raises instead of serving numpy;
+* a compiled entry point declines (numpy path) only requests that need
+  scalar semantics;
 * importing the library never imports jax (the numpy-only CI leg).
 """
 
@@ -26,11 +28,6 @@ from repro.core.forest import RandomForestRegressor
 from repro.registry import get_platform
 
 FAST_FOREST = {"n_estimators": 8, "max_depth": 10}
-
-needs_jax = pytest.mark.skipif(
-    not jax_predict.jax_available(), reason="jax not importable in this env"
-)
-
 
 def _oracle(platform, layer_types, **platform_kwargs) -> PerfOracle:
     spec = CampaignSpec(
@@ -85,7 +82,6 @@ def test_resolve_backend_env(monkeypatch):
         jax_predict.resolve_backend()
 
 
-@needs_jax
 def test_resolve_backend_jax_and_auto(monkeypatch):
     assert jax_predict.resolve_backend("jax") == "jax"
     assert jax_predict.resolve_backend("auto") == "jax"
@@ -94,26 +90,28 @@ def test_resolve_backend_jax_and_auto(monkeypatch):
 
 
 def test_fallback_when_jax_unavailable(monkeypatch, toy_oracle):
-    """With jax unimportable, backend 'jax' warns once and serves numpy."""
-    monkeypatch.setattr(jax_predict, "_modules_cache", None)
-    monkeypatch.setattr(jax_predict, "_import_failed", True)
-    monkeypatch.setattr(jax_predict, "_warned_fallback", False)
-    assert not jax_predict.jax_available()
-    with pytest.warns(RuntimeWarning, match="falling back to numpy"):
-        assert jax_predict.resolve_backend("jax") == "numpy"
-    # warned exactly once
-    assert jax_predict.resolve_backend("jax") == "numpy"
-    # auto is a silent numpy fallback
-    assert jax_predict.resolve_backend("auto") == "numpy"
+    """No jax installed: 'jax' raises, 'auto' serves numpy; a broken jax raises."""
+    import jax
 
     cfgs = [{"a": i % 40 + 1, "b": i % 20 + 1} for i in range(17)]
     y_np = toy_oracle.predict("toy", cfgs)
-    assert np.array_equal(y_np, toy_oracle.predict("toy", cfgs, backend="jax"))
-    nets = [[Block(kind="k", layers=(("toy", {"a": 4, "b": 2}),))]]
-    assert np.array_equal(
-        toy_oracle.predict_networks(nets),
-        toy_oracle.predict_networks(nets, backend="jax"),
-    )
+
+    with monkeypatch.context() as m:
+        m.setattr(jax_predict, "_modules_cache", None)
+        m.setattr(jax_predict, "_import_failed", True)
+        assert not jax_predict.jax_available()
+        with pytest.raises(RuntimeError, match="jax is not installed"):
+            jax_predict.resolve_backend("jax")
+        with pytest.raises(RuntimeError, match="jax is not installed"):
+            toy_oracle.predict("toy", cfgs, backend="jax")
+        assert jax_predict.resolve_backend("auto") == "numpy"
+        assert np.array_equal(y_np, toy_oracle.predict("toy", cfgs, backend="auto"))
+
+    # An installed jax whose API no longer matches raises; it is not numpy.
+    monkeypatch.delattr(jax, "enable_x64")
+    assert jax_predict.resolve_backend("auto") == "jax"
+    with pytest.raises(AttributeError, match="enable_x64"):
+        toy_oracle.predict("toy", cfgs, backend="auto")
 
 
 def test_no_eager_jax_import():
@@ -132,8 +130,22 @@ def test_no_eager_jax_import():
     subprocess.run([sys.executable, "-c", code], check=True)
 
 
+def test_worker_pool_refused_for_attached_accelerator():
+    """One process per chip: no worker pool over a platform measuring on one."""
+    from repro.runtime import MeasurementRuntime, RuntimeSpec
+    from repro.runtime.testing import SteppedSimPlatform
+
+    class OnChip(SteppedSimPlatform):
+        def measures_accelerator(self) -> bool:
+            return True
+
+    with pytest.raises(ValueError, match="one process per chip"):
+        MeasurementRuntime(RuntimeSpec(workers=2), OnChip())
+    with MeasurementRuntime(RuntimeSpec(workers=1), OnChip()) as rt:
+        assert rt.executor.workers == 1
+
+
 # ------------------------------------------------------------ forest parity
-@needs_jax
 @pytest.mark.parametrize("n", [0, 1, 5, 64, 333])
 def test_forest_predict_bitwise(n):
     rng = np.random.default_rng(3)
@@ -145,7 +157,6 @@ def test_forest_predict_bitwise(n):
     assert np.array_equal(forest.predict(X), forest.predict(X, backend="jax"))
 
 
-@needs_jax
 def test_forest_engine_invalidated_on_refit():
     rng = np.random.default_rng(4)
     X = rng.uniform(0, 10, size=(100, 2))
@@ -158,7 +169,6 @@ def test_forest_engine_invalidated_on_refit():
     assert not np.array_equal(y1, y2)
 
 
-@needs_jax
 def test_layer_predict_bitwise_including_ragged(toy_oracle):
     cfgs = [{"a": (i * 7) % 64 + 1, "b": (i * 3) % 32 + 1} for i in range(333)]
     assert np.array_equal(
@@ -183,7 +193,6 @@ PLATFORMS = [
 ]
 
 
-@needs_jax
 @pytest.mark.parametrize("name,kwargs", PLATFORMS)
 def test_measure_batch_bitwise(name, kwargs):
     plat = get_platform(name, **kwargs)
@@ -197,7 +206,6 @@ def test_measure_batch_bitwise(name, kwargs):
             assert np.array_equal(y_np, y_jx), f"{name}/{lt} n={n}"
 
 
-@needs_jax
 def test_noisy_tpu_stays_numpy():
     """Per-config hash-seeded noise is scalar semantics; jax must not engage."""
     from repro.accelerators import jax_kernels
@@ -210,7 +218,6 @@ def test_noisy_tpu_stays_numpy():
     assert np.array_equal(plat.measure_batch("dense", batch), ref)
 
 
-@needs_jax
 def test_wallclock_xla_cpu_stays_numpy():
     from repro.accelerators import jax_kernels
 
@@ -232,7 +239,6 @@ def _toy_nets():
     ]
 
 
-@needs_jax
 def test_predict_networks_tolerance_log_target(toy_oracle):
     """log-target exp runs inside the compiled call: rtol 1e-12 applies."""
     assert all(e.log_target for e in toy_oracle.estimators.values())
@@ -241,7 +247,6 @@ def test_predict_networks_tolerance_log_target(toy_oracle):
     np.testing.assert_allclose(p_jx, p_np, rtol=1e-12, atol=0.0)
 
 
-@needs_jax
 def test_predict_networks_bitwise_without_log_target(toy_oracle):
     import dataclasses
 
@@ -255,7 +260,6 @@ def test_predict_networks_bitwise_without_log_target(toy_oracle):
     assert np.array_equal(p_np, p_jx)
 
 
-@needs_jax
 def test_predict_networks_platform_oracles(tpu_oracle):
     nets = [
         [
@@ -276,7 +280,6 @@ def test_predict_networks_platform_oracles(tpu_oracle):
     np.testing.assert_allclose(p_jx, p_np, rtol=1e-12, atol=0.0)
 
 
-@needs_jax
 def test_predict_network_batch_jax_matches_columnar(toy_oracle):
     nets = _toy_nets()
     flat = [b for net in nets for b in net]
@@ -314,7 +317,6 @@ def test_empty_overlap_block_raises(toy_oracle):
 
 
 # ----------------------------------------------------------------- autotune
-@needs_jax
 def test_autotune_parity_across_backends_and_paths(tpu_oracle):
     import dataclasses as dc
 
@@ -437,7 +439,6 @@ def test_run_sweeps_grouped_matches_per_window():
 
 
 # ----------------------------------------------------------------- serving
-@needs_jax
 def test_served_equals_direct_with_jax_backend(toy_oracle):
     from repro.serving import OracleServer, ServeSpec
 
@@ -480,7 +481,6 @@ def _payload(block: Block) -> dict:
     }
 
 
-@needs_jax
 def test_network_cache_keys_are_backend_scoped(toy_oracle):
     """A numpy-warmed network cache entry must not serve a jax-backend oracle
     (answers can differ by an ulp via the compiled log-target exp); layer

@@ -1,6 +1,8 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode on CPU).
 
-Per-kernel shape/dtype sweeps with assert_allclose against ref.py.
+Per-kernel shape/dtype sweeps with assert_allclose against ref.py.  The
+kernels compile only for the TPU, so every call here asks for the Pallas
+interpreter explicitly.
 """
 
 import jax
@@ -35,7 +37,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (b, sq, h, d), dt)
         k = jax.random.normal(ks[1], (b, sq, kvh, d), dt)
         v = jax.random.normal(ks[2], (b, sq, kvh, d), dt)
-        o = ops.flash_attention(q, k, v, causal=True)
+        o = ops.flash_attention(q, k, v, causal=True, interpret=True)
         o_ref = ref.flash_attention_ref(q, k, v, causal=True)
         np.testing.assert_allclose(
             np.asarray(o, np.float32), np.asarray(o_ref, np.float32), **_tol(dt)
@@ -46,7 +48,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (1, 128, 4, 64))
         k = jax.random.normal(ks[1], (1, 256, 4, 64))
         v = jax.random.normal(ks[2], (1, 256, 4, 64))
-        o = ops.flash_attention(q, k, v, causal=False)
+        o = ops.flash_attention(q, k, v, causal=False, interpret=True)
         o_ref = ref.flash_attention_ref(q, k, v, causal=False)
         np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5, rtol=2e-5)
 
@@ -56,9 +58,25 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (1, 256, 4, 64))
         k = jax.random.normal(ks[1], (1, 256, 2, 64))
         v = jax.random.normal(ks[2], (1, 256, 2, 64))
-        o = ops.flash_attention(q, k, v, causal=True, block_q=block_q, block_k=block_k)
+        o = ops.flash_attention(
+            q, k, v, causal=True, block_q=block_q, block_k=block_k, interpret=True
+        )
         o_ref = ref.flash_attention_ref(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5, rtol=2e-5)
+
+
+class TestNoInterpretFallback:
+    """Off the TPU a kernel runs only when the caller asks for the interpreter."""
+
+    def test_compiled_call_off_tpu_raises(self):
+        assert jax.default_backend() != "tpu"
+        q = jnp.ones((1, 128, 2, 64))
+        with pytest.raises(ValueError, match="interpret"):
+            ops.flash_attention(q, q, q)
+        la = jnp.zeros((1, 128, 2))
+        bm = jnp.ones((1, 128, 16))
+        with pytest.raises(ValueError, match="interpret"):
+            ops.ssd_scan(q, la, bm, bm)
 
 
 class TestSSDScan:
@@ -76,7 +94,7 @@ class TestSSDScan:
         la = -jnp.abs(jax.random.normal(ks[1], (b, s, h), jnp.float32)) * 0.1
         bm = jax.random.normal(ks[2], (b, s, n), dt) * 0.3
         cm = jax.random.normal(ks[3], (b, s, n), dt) * 0.3
-        y = ops.ssd_scan(xb, la, bm, cm)
+        y = ops.ssd_scan(xb, la, bm, cm, interpret=True)
         y_ref, _ = ref.ssd_ref(xb, la, bm, cm)
         np.testing.assert_allclose(
             np.asarray(y, np.float32), np.asarray(y_ref, np.float32),

@@ -5,6 +5,7 @@ import pytest
 
 from repro.accelerators import TPUv5eSim, UltraTrailSim, VTASim, XLACPUPlatform
 from repro.core import prs, steps, sweeps
+from repro.core.batch import ConfigBatch
 
 
 class TestUltraTrail:
@@ -108,3 +109,34 @@ class TestXLACPU:
         t_small = cpu.measure("dense", {"tokens": 16, "d_in": 32, "d_out": 32})
         t_big = cpu.measure("dense", {"tokens": 256, "d_in": 768, "d_out": 768})
         assert t_small > 0 and t_big > t_small
+
+    def test_wallclock_names_its_device(self):
+        import jax
+
+        dev = jax.devices()[0]
+        wall = XLACPUPlatform(repeats=1)
+        assert wall.name == f"xla_cpu[{dev.platform}:{dev.device_kind}]"
+        assert wall.cache_key().startswith(wall.name + "|")
+        assert wall.measures_accelerator() == (dev.platform != "cpu")
+        # synthetic mode never touches a device and keeps its key
+        syn = XLACPUPlatform(synthetic=True)
+        assert syn.name == "xla_cpu"
+        assert syn.cache_key() == "xla_cpu|dtype=float32|repeats=5|synthetic"
+        assert not syn.measures_accelerator()
+
+    def test_batch_times_each_unique_shape_once(self):
+        from repro.obs.metrics import metrics
+
+        def shapes():
+            return metrics().snapshot()["counters"].get("xla_cpu.shapes", 0)
+
+        wall = XLACPUPlatform(repeats=1)
+        cfgs = [
+            {"tokens": 16, "d_in": 32, "d_out": 64},
+            {"tokens": 24, "d_in": 48, "d_out": 64},
+            {"tokens": 16, "d_in": 32, "d_out": 64},
+        ]
+        before = shapes()
+        y = wall.measure_batch("dense", ConfigBatch.from_dicts(cfgs))
+        assert y.shape == (3,) and (y > 0).all() and y[0] == y[2]
+        assert shapes() == before + 2
