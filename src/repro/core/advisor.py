@@ -22,6 +22,7 @@ from typing import Protocol, Sequence
 from repro.core.blocks import Block
 from repro.core.network import decompose, decompose_batch
 from repro.models.config import InputShape, ModelConfig
+from repro.obs.trace import span
 
 
 class NetworkPredictor(Protocol):
@@ -129,11 +130,12 @@ def autotune(
 
             from repro.core.batch import BlockBatch
 
-            batches = [candidate_block_batch(cfg, shape, c) for _, c in chosen]
-            merged = BlockBatch.concat(batches)
-            net_id = np.repeat(
-                np.arange(len(batches)), [len(b) for b in batches]
-            )
+            with span("advisor.decompose"):
+                batches = [candidate_block_batch(cfg, shape, c) for _, c in chosen]
+                merged = BlockBatch.concat(batches)
+                net_id = np.repeat(
+                    np.arange(len(batches)), [len(b) for b in batches]
+                )
             preds = predict_batch(merged, net_id=net_id, n_nets=len(batches))
         elif predict_many is not None:
             preds = predict_many([candidate_blocks(cfg, shape, c) for _, c in chosen])

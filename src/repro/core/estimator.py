@@ -24,6 +24,7 @@ from repro.core import prs
 from repro.core.batch import ConfigBatch
 from repro.core.features import derived_features, derived_features_batch
 from repro.core.forest import RandomForestRegressor, mape, rmspe
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -49,22 +50,23 @@ class LayerEstimator:
         (columnarised on the fly); heterogeneous key sets fall back to the
         per-row dict path.
         """
-        if not isinstance(configs, ConfigBatch):
-            configs = list(configs)
-            if not configs:
-                # An empty list carries no key set to columnarise from.
-                return self._features_rows(configs, snap)
-            try:
-                configs = ConfigBatch.from_dicts(configs)
-            except ValueError:
-                return self._features_rows(configs, snap)
-        if snap:
-            configs = prs.map_to_pr_batch(configs, self.widths, self.space)
-        base = configs.matrix(self.params)
-        extra = derived_features_batch(self.layer_type, configs)
-        if extra.size == 0:
-            return base
-        return np.concatenate([base, extra], axis=1)
+        with span("estimator.features"):
+            if not isinstance(configs, ConfigBatch):
+                configs = list(configs)
+                if not configs:
+                    # An empty list carries no key set to columnarise from.
+                    return self._features_rows(configs, snap)
+                try:
+                    configs = ConfigBatch.from_dicts(configs)
+                except ValueError:
+                    return self._features_rows(configs, snap)
+            if snap:
+                configs = prs.map_to_pr_batch(configs, self.widths, self.space)
+            base = configs.matrix(self.params)
+            extra = derived_features_batch(self.layer_type, configs)
+            if extra.size == 0:
+                return base
+            return np.concatenate([base, extra], axis=1)
 
     def _features_rows(self, configs: Sequence[prs.Config], snap: bool) -> np.ndarray:
         """Row-at-a-time fallback for ragged (mixed-key) config lists."""

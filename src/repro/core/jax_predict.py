@@ -72,6 +72,7 @@ import warnings
 import numpy as np
 
 from repro.obs.metrics import metrics as obs_metrics
+from repro.obs.trace import span
 
 _ENV_VAR = "REPRO_PREDICT_BACKEND"
 _BACKENDS = ("numpy", "jax", "auto")
@@ -89,16 +90,38 @@ DEVICE_RTOL = 1e-12
 # ``jax.*.traces`` in the metrics snapshot is the direct retrace-rate signal —
 # ``traces`` growing under steady live traffic means the bucketing is not
 # absorbing the batch-size jitter (a bug this repo previously could not see).
+# ``jax.*.h2d_bytes`` sums what each call copies to the device: every
+# argument is a host array, node tables included.
 _seen_forest_sigs: set[tuple] = set()
 _seen_network_sigs: set[tuple] = set()
 
+#: JAX's compile stages, each observed in the ``jax.compile_s`` histogram
+_COMPILE_EVENTS = frozenset(
+    {
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration",
+        "/jax/core/compile/backend_compile_duration",
+    }
+)
 
-def _count_trace(kind: str, seen: set, sig: tuple) -> None:
+
+def _host_bytes(args: tuple) -> int:
+    """Bytes of the host arrays and scalars in ``args`` (tuples nest)."""
+    return sum(_host_bytes(a) if isinstance(a, tuple) else a.nbytes for a in args)
+
+
+def _count_trace(kind: str, seen: set, sig: tuple, args: tuple) -> None:
     reg = obs_metrics()
     reg.inc(f"jax.{kind}.calls")
+    reg.inc(f"jax.{kind}.h2d_bytes", _host_bytes(args))
     if sig not in seen:
         seen.add(sig)
         reg.inc(f"jax.{kind}.traces")
+
+
+def _observe_compile(event: str, duration_s: float, **kwargs) -> None:
+    if event in _COMPILE_EVENTS:
+        obs_metrics().observe_value("jax.compile_s", duration_s)
 
 _modules_cache: tuple | None = None
 _import_failed = False
@@ -121,6 +144,7 @@ def jax_modules() -> tuple | None:
         import jax.numpy as jnp
         from jax import lax
 
+        jax.monitoring.register_event_duration_secs_listener(_observe_compile)
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable"
         )
@@ -198,10 +222,10 @@ def _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees):
 def _forest_fn():
     jax, jnp, lax = jax_modules()
 
-    def run(feature, threshold, left, right, value, X, n_trees):
+    def forest_traverse(feature, threshold, left, right, value, X, n_trees):
         return _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees)
 
-    return jax.jit(run, donate_argnums=(5,))
+    return jax.jit(forest_traverse, donate_argnums=(5,))
 
 
 class ForestEngine:
@@ -228,13 +252,14 @@ class ForestEngine:
         nb = bucket_rows(n)
         Xp = np.zeros((nb, d), dtype=np.float64)
         Xp[:n] = X
+        args = (*self._arrays, Xp, self._n_trees)
         _count_trace(
             "forest", _seen_forest_sigs,
-            tuple(a.shape for a in self._arrays) + ((nb, d),),
+            tuple(a.shape for a in self._arrays) + ((nb, d),), args,
         )
         fn = _forest_fn()
-        with x64():
-            y = fn(*self._arrays, Xp, self._n_trees)
+        with x64(), span("forest.launch"):
+            y = fn(*args)
         return np.asarray(y)[:n]
 
 
@@ -259,7 +284,7 @@ def _network_fn(log_flags: tuple):
     """
     jax, jnp, lax = jax_modules()
 
-    def run(
+    def network_estimate(
         groups, Xs, block_seg, counts, overlap, fused, w, c, ops, rep,
         net_seg, net_dummy, launch,
     ):
@@ -285,7 +310,7 @@ def _network_fn(log_flags: tuple):
         # rep == 0 and net segment Nb (the dump segment).
         return jax.ops.segment_sum(t * rep, net_seg, num_segments=net_dummy.shape[0])
 
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(network_estimate, donate_argnums=(1,))
 
 
 def predict_network_batch_jax(oracle, batch, net_id, n_nets) -> np.ndarray | None:
@@ -299,6 +324,21 @@ def predict_network_batch_jax(oracle, batch, net_id, n_nets) -> np.ndarray | Non
     counts = batch.layer_counts()
     if n_blocks == 0 or np.any(counts == 0):
         return None
+    n_nets = int(n_nets)
+    with span("network.pack"):
+        packed = _pack_network(oracle, batch, counts, net_id, n_nets)
+    if packed is None:
+        return None
+    log_flags, args = packed
+    fn = _network_fn(log_flags)
+    with x64(), span("network.launch"):
+        out = fn(*args)
+    return np.asarray(out)[:n_nets]
+
+
+def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
+    """``(log_flags, args)`` of the network program for ``batch``: features,
+    bucket padding, positions, and the block and network segment tables."""
     ests = []
     for lt in batch.group_types:
         try:
@@ -314,11 +354,11 @@ def predict_network_batch_jax(oracle, batch, net_id, n_nets) -> np.ndarray | Non
 
     from repro.core.blocks import block_ops_batch
 
+    n_blocks = len(batch)
     L = batch.n_layers
     Lb = bucket_rows(L)
     Bb = bucket_rows(n_blocks)
     net_id = np.asarray(net_id, dtype=np.int64)
-    n_nets = int(n_nets)
     Nb = bucket_rows(max(1, n_nets))
 
     groups = []
@@ -371,15 +411,14 @@ def predict_network_batch_jax(oracle, batch, net_id, n_nets) -> np.ndarray | Non
     net_seg[:n_blocks] = net_id
     net_dummy = np.zeros(Nb + 1, dtype=np.float64)
 
+    args = (
+        tuple(groups), tuple(Xs), block_seg, counts_p, overlap, fused, w, c,
+        ops, rep, net_seg, net_dummy, np.float64(oracle.launch_overhead_s),
+    )
     _count_trace(
         "network", _seen_network_sigs,
         (tuple(log_flags), Lb, Bb, Nb)
         + tuple((g[0].shape, X.shape) for g, X in zip(groups, Xs)),
+        args,
     )
-    fn = _network_fn(tuple(log_flags))
-    with x64():
-        out = fn(
-            tuple(groups), tuple(Xs), block_seg, counts_p, overlap, fused, w, c,
-            ops, rep, net_seg, net_dummy, np.float64(oracle.launch_overhead_s),
-        )
-    return np.asarray(out)[:n_nets]
+    return tuple(log_flags), args

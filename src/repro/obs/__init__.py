@@ -3,9 +3,11 @@
 Two halves, one import:
 
 * :mod:`repro.obs.trace` — nested spans to an append-only JSONL trace with a
-  Chrome/Perfetto exporter.  Disabled (the default) a span is the shared
-  :data:`NULL_SPAN` singleton: no allocation, no clock read, nanoseconds of
-  overhead — cheap enough to leave on the measurement hot path.
+  Chrome/Perfetto exporter, and/or into a ``jax.profiler`` trace
+  (``Tracer(path=None, profiler=True)``).  Disabled (the default) a span is
+  the shared :data:`NULL_SPAN` singleton: no allocation, no clock read,
+  nanoseconds of overhead — cheap enough to leave on the measurement hot
+  path.
 * :mod:`repro.obs.metrics` — counters, pull-based gauges, and p50/p95/p99
   histograms in one :class:`MetricsRegistry`; supersedes the old
   ``repro.serving.metrics`` (which now re-exports from here).
@@ -59,7 +61,6 @@ from repro.obs.trace import (
     load_events,
     set_tracer,
     span,
-    traced,
     tracing,
 )
 
@@ -81,6 +82,5 @@ __all__ = [
     "set_metrics",
     "set_tracer",
     "span",
-    "traced",
     "tracing",
 ]

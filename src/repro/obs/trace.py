@@ -4,8 +4,17 @@ The paper's whole argument is about where benchmarking time goes; the tracer
 is how this repo answers that question about *itself*.  One process-global
 :class:`Tracer` (installed with :func:`set_tracer` / :func:`tracing` /
 ``Campaign.run(trace=...)``) receives spans from every instrumented seam —
-campaign phases, scheduler chunks, forest fitting, serving requests — and
-appends them to a JSONL trace file.
+campaign phases, scheduler chunks, forest fitting, serving requests, the
+oracle's query path — and hands them to its sinks:
+
+* a JSONL trace file (``Tracer(path)``), on the tracer's own
+  ``perf_counter`` epoch, for :mod:`repro.obs.report` and
+  :func:`export_chrome`;
+* the JAX profiler (``Tracer(path=None, profiler=True)``): every span also
+  enters a ``jax.profiler.TraceAnnotation`` of the same name, so it lands in
+  the profiler's host plane on the clock the device ops share.  Only the
+  name travels; ``set()`` arguments stay with the JSONL sink.  Outside a
+  running ``jax.profiler`` trace the annotations record nothing.
 
 Zero overhead when disabled — the hard contract
 -----------------------------------------------
@@ -45,12 +54,11 @@ timeline through the epoch pair captured at construction (``time.time`` and
 from __future__ import annotations
 
 import contextlib
-import functools
 import json
 import os
 import threading
 import time
-from typing import Any, Callable, Iterator, Mapping
+from typing import Any, Iterator, Mapping
 
 
 class _NullSpan:
@@ -111,29 +119,6 @@ def instant(name: str, args: Mapping | None = None, cat: str = "repro") -> None:
         tracer.instant(name, args=args, cat=cat)
 
 
-def traced(name: str | None = None, cat: str = "repro") -> Callable:
-    """Decorator form of :func:`span`; the label defaults to the qualname.
-
-    The tracer is looked up per *call*, so decorated functions stay no-op
-    (one global read) when tracing is disabled.
-    """
-
-    def decorate(fn: Callable) -> Callable:
-        label = name if name is not None else fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            tracer = _TRACER
-            if tracer is None:
-                return fn(*a, **kw)
-            with _Span(tracer, label, cat, None):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return decorate
-
-
 @contextlib.contextmanager
 def tracing(target) -> Iterator["Tracer | None"]:
     """Activate tracing for one block: a path creates (and closes) a tracer.
@@ -176,7 +161,7 @@ def disable_tracing() -> None:
 class _Span:
     """One live span: records enter/exit on the owning tracer."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args) -> None:
         self._tracer = tracer
@@ -194,15 +179,23 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        annotate = self._tracer.annotate
+        if annotate is not None:
+            self._annotation = annotate(self._name)
+            self._annotation.__enter__()
         self._t0 = self._tracer.now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        tracer = self._tracer
+        if tracer.annotate is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        if tracer.path is None:
+            return False
         args = self._args
         if exc_type is not None:
             args = dict(args or ())
             args["error"] = exc_type.__name__
-        tracer = self._tracer
         tracer.complete(
             self._name, self._t0, tracer.now_us() - self._t0,
             args=args, cat=self._cat,
@@ -211,7 +204,12 @@ class _Span:
 
 
 class Tracer:
-    """Append-only JSONL trace writer (Chrome ``trace_event`` records).
+    """Span sinks: an append-only JSONL trace file (Chrome ``trace_event``
+    records) at ``path``, and with ``profiler`` the JAX profiler's host trace.
+
+    ``path=None`` writes no file.  ``profiler=True`` imports jax here (never
+    at module scope) and makes every span a ``jax.profiler.TraceAnnotation``
+    as well.
 
     Thread-safe: spans may be emitted from any thread (serving handlers, the
     admission batcher, scheduler journal callbacks); each writer thread gets
@@ -219,12 +217,22 @@ class Tracer:
     metadata event.
     """
 
-    def __init__(self, path: str, process_name: str = "repro") -> None:
+    def __init__(
+        self, path: str | None, process_name: str = "repro", profiler: bool = False
+    ) -> None:
         self.path = path
-        directory = os.path.dirname(path)
-        if directory:
-            os.makedirs(directory, exist_ok=True)
-        self._fh = open(path, "a", encoding="utf-8")
+        self._fh = None
+        if path is not None:
+            directory = os.path.dirname(path)
+            if directory:
+                os.makedirs(directory, exist_ok=True)
+            self._fh = open(path, "a", encoding="utf-8")
+        #: ``jax.profiler.TraceAnnotation`` under the profiler sink, else None
+        self.annotate = None
+        if profiler:
+            import jax.profiler
+
+            self.annotate = jax.profiler.TraceAnnotation
         self._lock = threading.Lock()
         self.pid = os.getpid()
         # Epoch pair: perf_counter timestamps (monotonic, high resolution) for
@@ -253,6 +261,8 @@ class Tracer:
 
     # --------------------------------------------------------------- writing
     def _write(self, record: dict) -> None:
+        if self._fh is None:
+            return
         line = json.dumps(record, separators=(",", ":"), default=str)
         with self._lock:
             self._fh.write(line + "\n")
@@ -347,11 +357,12 @@ class Tracer:
     # ------------------------------------------------------------- lifecycle
     def flush(self) -> None:
         with self._lock:
-            self._fh.flush()
+            if self._fh is not None:
+                self._fh.flush()
 
     def close(self) -> None:
         with self._lock:
-            if not self._fh.closed:
+            if self._fh is not None and not self._fh.closed:
                 self._fh.flush()
                 self._fh.close()
 

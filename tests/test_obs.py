@@ -44,7 +44,6 @@ from repro.obs.trace import (
     load_events,
     set_tracer,
     span,
-    traced,
     tracing,
 )
 from repro.runtime import (
@@ -274,21 +273,33 @@ class TestTracer:
         (event,) = [e for e in load_events(path) if e["ph"] == "X"]
         assert event["args"]["error"] == "ValueError"
 
-    def test_traced_decorator_is_noop_without_tracer(self, tmp_path):
-        calls = []
+    def test_profiler_sink_writes_no_file(self, tmp_path, monkeypatch):
+        pytest.importorskip("jax")
+        monkeypatch.chdir(tmp_path)
+        entered = []
+        tracer = Tracer(None, profiler=True)
 
-        @traced(cat="test")
-        def work(x):
-            calls.append(x)
-            return x * 2
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
 
-        assert work(3) == 6  # no tracer installed: plain call
-        path = str(tmp_path / "t.jsonl")
-        with tracing(path):
-            assert work(4) == 8
-        names = [e["name"] for e in load_events(path) if e["ph"] == "X"]
-        assert names == [work.__qualname__]  # exactly one span, labelled by qualname
-        assert calls == [3, 4]
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                entered.append("/" + self.name)
+
+        tracer.annotate = Annotation
+        with tracing(tracer):
+            with span("outer") as sp:
+                sp.set(rows=3)
+                with span("inner"):
+                    pass
+            instant("marker")
+        tracer.close()
+        assert entered == ["outer", "inner", "/inner", "/outer"]
+        assert tracer.events_written == 0 and list(tmp_path.iterdir()) == []
+        assert span("outer") is NULL_SPAN  # uninstalled again
 
     def test_tracing_restores_an_already_installed_tracer(self, tmp_path):
         outer = Tracer(str(tmp_path / "outer.jsonl"))
@@ -571,6 +582,129 @@ class TestReportCLI:
         assert spans["phase.measurement"]["count"] == 1
         assert spans["phase.measurement"]["total_us"] >= 1000  # slept 1ms
         assert summary["wall_us"] > 0
+
+
+# ------------------------------------------------ the oracle's query path
+def _autotune_args():
+    from repro.configs import get_config
+    from repro.models.config import InputShape
+
+    shape = InputShape(name="t", seq_len=1024, global_batch=8, kind="decode")
+    return get_config("qwen2-1.5b"), shape
+
+
+def _fitted_forest(n_features: int, seed: int = 7):
+    from repro.core.forest import RandomForestRegressor
+
+    X = np.random.default_rng(seed).uniform(0, 10, size=(64, n_features))
+    forest = RandomForestRegressor(n_estimators=3, max_depth=6, seed=0)
+    forest.fit(X, X.sum(axis=1))
+    return forest
+
+
+class TestQueryPathObservability:
+    @pytest.fixture(scope="class")
+    def oracle(self):
+        pytest.importorskip("jax")
+        import dataclasses
+
+        spec = CampaignSpec(
+            platform="tpu_v5e", layer_types=("dense", "attention_decode", "embed"),
+            n_samples=64, seed=0, forest_kwargs=FAST_FOREST,
+        )
+        return dataclasses.replace(Campaign(spec).run(), predict_backend="jax")
+
+    def test_profiler_sink_puts_query_spans_in_the_jax_trace(self, oracle, tmp_path):
+        import jax
+
+        from repro.core.advisor import autotune
+
+        cfg, shape = _autotune_args()
+        plain = autotune(oracle, cfg, shape, chips=16)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with tracing(Tracer(None, profiler=True)):
+                traced = autotune(oracle, cfg, shape, chips=16)
+        finally:
+            jax.profiler.stop_trace()
+        assert traced == plain
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        names = {
+            e.name
+            for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines
+            for e in line.events
+        }
+        assert {"advisor.decompose", "estimator.features", "network.pack",
+                "network.launch", "PjitFunction(network_estimate)"} <= names
+
+    @pytest.mark.parametrize("kind", ["forest", "network"])
+    def test_h2d_bytes_equal_the_arguments_nbytes(self, kind, oracle, monkeypatch):
+        import jax
+
+        from repro.core import jax_predict
+        from repro.core.advisor import autotune
+
+        sent = []
+        fn = jax_predict._forest_fn if kind == "forest" else jax_predict._network_fn
+
+        def spy(*key):
+            jitted = fn(*key)
+
+            def call(*args):
+                sent.append(args)
+                return jitted(*args)
+
+            return call
+
+        monkeypatch.setattr(jax_predict, fn.__name__, spy)
+        if kind == "forest":
+            _fitted_forest(3).predict(np.ones((100, 3)), backend="jax")
+        else:
+            autotune(oracle, *_autotune_args(), chips=16)
+        (args,) = sent
+        nbytes = sum(np.asarray(a).nbytes for a in jax.tree_util.tree_leaves(args))
+        counters = obs_metrics().snapshot()["counters"]
+        assert counters[f"jax.{kind}.h2d_bytes"] == nbytes > 0
+
+    def test_compile_seconds_grow_on_a_new_bucket_only(self):
+        pytest.importorskip("jax")
+        forest = _fitted_forest(5)
+
+        def compile_s():
+            h = obs_metrics().snapshot()["histograms"].get("jax.compile_s", {})
+            return h.get("count", 0), h.get("total", 0.0)
+
+        forest.predict(np.ones((64, 5)), backend="jax")
+        first = compile_s()
+        forest.predict(np.ones((50, 5)), backend="jax")  # the same 64-row bucket
+        assert compile_s() == first
+        forest.predict(np.ones((65, 5)), backend="jax")  # a new 128-row bucket
+        count, total = compile_s()
+        assert count > first[0] and total > first[1]
+
+    def test_answers_bitwise_with_profiler_sink_on_and_off(self, oracle):
+        from repro.core.advisor import autotune
+
+        cfg, shape = _autotune_args()
+        forest = _fitted_forest(3)
+        X = np.random.default_rng(1).uniform(0, 10, size=(200, 3))
+        cfgs = [{"tokens": 8 * i + 8, "d_in": 64 * i + 64, "d_out": 256} for i in range(40)]
+
+        def answers():
+            return (
+                forest.predict(X, backend="jax").tobytes(),
+                oracle.predict("dense", cfgs).tobytes(),
+                [(c, np.float64(s).tobytes())
+                 for c, s in autotune(oracle, cfg, shape, chips=16)],
+            )
+
+        plain = answers()
+        with tracing(Tracer(None, profiler=True)):
+            assert answers() == plain
 
 
 # --------------------------------------------------------- jax retrace counts
