@@ -231,7 +231,7 @@ class RandomForestRegressor:
 
     # The tree list is a property so that direct assignment (fit, and the
     # EstimatorHub, which rebuilds ``forest._trees`` on load) invalidates the
-    # cached stacked node tables.
+    # cached stacked node tables, and with them their device copies.
     @property
     def _trees(self) -> list[_Tree]:
         return self.__trees

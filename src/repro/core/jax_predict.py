@@ -52,21 +52,32 @@ the dtype behaviour of unrelated jax code in the same process).
   when none is.  The serving cache scopes its network keys accordingly
   (:meth:`repro.serving.server.OracleServer._network_key_scope`).
 
-Shapes, retracing and donation
-------------------------------
+Shapes, retracing, residency and donation
+-----------------------------------------
 Batch rows are padded to power-of-two buckets (min 64) before entering a
 kernel and sliced back after, so the admission batcher's variable batch sizes
 hit a handful of warm-compiled shapes instead of retracing per request.
-Input buffers are donated (``donate_argnums``); on CPU XLA currently declines
-input-shaped donations and copies instead — the donation is kept for
-device backends and the resulting "donated buffers were not usable" warning
-is suppressed, since the padded copy is ours to give away either way.
+
+A forest's node tables and tree count go to the device once
+(:func:`resident_forest`, cached on its ``_ForestStack``, so a refit retires
+them), as does the oracle's launch overhead; a call copies from the host only
+what it produced itself.  A forest call sends its padded rows; a network call
+sends one int64 and one float64 buffer that the program splits at static
+offsets given by the bucket sizes, so a call costs two transfers whatever the
+number of groups.
+
+The per-call buffers are donated (``donate_argnums``), never the resident
+tables; on CPU XLA currently declines input-shaped donations and copies
+instead — the donation is kept for device backends and the resulting
+"donated buffers were not usable" warning is suppressed, since the padded
+copy is ours to give away either way.
 """
 
 from __future__ import annotations
 
 import functools
 import os
+import threading
 import warnings
 
 import numpy as np
@@ -90,10 +101,13 @@ DEVICE_RTOL = 1e-12
 # ``jax.*.traces`` in the metrics snapshot is the direct retrace-rate signal —
 # ``traces`` growing under steady live traffic means the bucketing is not
 # absorbing the batch-size jitter (a bug this repo previously could not see).
-# ``jax.*.h2d_bytes`` sums what each call copies to the device: every
-# argument is a host array, node tables included.
+# ``jax.*.h2d_bytes`` sums what each call copies from the host: its numpy
+# arrays and scalars.  The forests' node tables and tree counts are uploaded
+# once (:func:`resident_forest`, counted in ``jax.resident.uploads`` and
+# ``jax.resident.bytes``) and passed as device arrays, which copy nothing.
 _seen_forest_sigs: set[tuple] = set()
 _seen_network_sigs: set[tuple] = set()
+_upload_lock = threading.Lock()
 
 #: JAX's compile stages, each observed in the ``jax.compile_s`` histogram
 _COMPILE_EVENTS = frozenset(
@@ -105,9 +119,12 @@ _COMPILE_EVENTS = frozenset(
 )
 
 
-def _host_bytes(args: tuple) -> int:
-    """Bytes of the host arrays and scalars in ``args`` (tuples nest)."""
-    return sum(_host_bytes(a) if isinstance(a, tuple) else a.nbytes for a in args)
+def _host_bytes(args) -> int:
+    """Bytes of the numpy arrays and scalars in ``args`` (tuples nest); device
+    arrays and static values copy nothing."""
+    if isinstance(args, tuple):
+        return sum(map(_host_bytes, args))
+    return args.nbytes if isinstance(args, (np.ndarray, np.generic)) else 0
 
 
 def _count_trace(kind: str, seen: set, sig: tuple, args: tuple) -> None:
@@ -228,89 +245,116 @@ def _forest_fn():
     return jax.jit(forest_traverse, donate_argnums=(5,))
 
 
-class ForestEngine:
-    """Compiled traversal bound to one stacked forest.
+def resident_forest(stack) -> tuple:
+    """``(feature, threshold, left, right, value, n_trees)`` of a stacked
+    forest on the device, uploaded on first use and cached on the stack.
 
-    Instances memoize on the ``_ForestStack`` object itself (see
-    :func:`forest_predict_raw`), so the ``RandomForestRegressor._trees``
-    setter's stack invalidation retires the engine automatically on refit.
+    A refit builds a new ``_ForestStack``, which retires the old arrays.  The
+    copy runs under :func:`x64`, which keeps float64 (outside it ``device_put``
+    narrows to float32); int32 tables stay int32.  Never donated.
     """
+    with _upload_lock:
+        tables = getattr(stack, "_jax_resident", None)
+        if tables is None:
+            host = (stack.feature, stack.threshold, stack.left, stack.right,
+                    stack.value, np.float64(stack.feature.shape[0]))
+            with x64():
+                tables = tuple(jax_modules()[0].device_put(host))
+            reg = obs_metrics()
+            reg.inc("jax.resident.uploads")
+            reg.inc("jax.resident.bytes", _host_bytes(host))
+            stack._jax_resident = tables
+    return tables
 
-    def __init__(self, stack, n_trees: int) -> None:
-        self._arrays = (
-            stack.feature,
-            stack.threshold,
-            stack.left,
-            stack.right,
-            stack.value,
-        )
-        self._n_trees = np.float64(n_trees)
 
-    def predict_raw(self, X: np.ndarray) -> np.ndarray:
-        """Mean-over-trees raw prediction, bitwise equal to the numpy fold."""
-        n, d = X.shape
-        nb = bucket_rows(n)
-        Xp = np.zeros((nb, d), dtype=np.float64)
-        Xp[:n] = X
-        args = (*self._arrays, Xp, self._n_trees)
-        _count_trace(
-            "forest", _seen_forest_sigs,
-            tuple(a.shape for a in self._arrays) + ((nb, d),), args,
-        )
-        fn = _forest_fn()
-        with x64(), span("forest.launch"):
-            y = fn(*args)
-        return np.asarray(y)[:n]
+def _resident_launch(oracle):
+    """``oracle.launch_overhead_s`` as a float64 device scalar, cached on the
+    oracle and uploaded again only when the value changes."""
+    value = float(oracle.launch_overhead_s)
+    cached = getattr(oracle, "_jax_launch", None)
+    if cached is None or cached[0] != value:
+        with x64():
+            cached = (value, jax_modules()[0].device_put(np.float64(value)))
+        oracle._jax_launch = cached
+    return cached[1]
 
 
 def forest_predict_raw(forest, X: np.ndarray) -> np.ndarray:
-    """Jitted ``RandomForestRegressor.predict``."""
-    stack = forest._stacked()
-    engine = getattr(stack, "_jax_engine", None)
-    if engine is None:
-        engine = ForestEngine(stack, len(forest._trees))
-        stack._jax_engine = engine
-    return engine.predict_raw(np.asarray(X, dtype=np.float64))
+    """Jitted ``RandomForestRegressor.predict``: the mean-over-trees raw
+    prediction, bitwise equal to the numpy fold."""
+    X = np.asarray(X, dtype=np.float64)
+    tables = resident_forest(forest._stacked())
+    n, d = X.shape
+    nb = bucket_rows(n)
+    Xp = np.zeros((nb, d), dtype=np.float64)
+    Xp[:n] = X
+    args = (*tables[:5], Xp, tables[5])
+    _count_trace(
+        "forest", _seen_forest_sigs,
+        tuple(a.shape for a in tables[:5]) + ((nb, d),), args,
+    )
+    fn = _forest_fn()
+    with x64(), span("forest.launch"):
+        y = fn(*args)
+    return np.asarray(y)[:n]
 
 
 # -------------------------------------------------------------- network kernel
+def _split(buf, sizes):
+    """Consecutive slices of ``buf`` of the given static sizes."""
+    out, at = [], 0
+    for n in sizes:
+        out.append(buf[at:at + n])
+        at += n
+    return out
+
+
 @functools.lru_cache(maxsize=None)
 def _network_fn(log_flags: tuple):
     """One-call Eq. 9-12 kernel for a fixed per-group log-target signature.
 
     ``log_flags`` decides at trace time which groups exponentiate inside the
-    graph; everything else (positions, combination masks, constants) is
-    traced so shape buckets are the only retrace axis.
+    graph.  The call's own tables arrive in two host buffers, split at static
+    offsets that follow from ``layout`` ``(Lb, Bb, Nb, X shapes)``, the bucket
+    sizes: one int64 (each group's positions, the block and network segment
+    ids, the overlap and fused flags as 0/1) and one float64 (counts, w, c,
+    ops, repeats, each group's flattened features).  Everything else is traced
+    or resident, so shape buckets are the only retrace axis.
     """
     jax, jnp, lax = jax_modules()
 
-    def network_estimate(
-        groups, Xs, block_seg, counts, overlap, fused, w, c, ops, rep,
-        net_seg, net_dummy, launch,
-    ):
-        n_slots = block_seg.shape[0]  # Lb + 1: padded layer table + dump slot
-        Bb = counts.shape[0]
-        times = jnp.zeros((n_slots,), dtype=jnp.float64)
-        for (feature, threshold, left, right, value, n_trees, pos), X, is_log in zip(
-            groups, Xs, log_flags
+    def network_estimate(groups, ints, floats, launch, layout):
+        Lb, Bb, Nb, x_shapes = layout
+        *pos, block_seg, net_seg, overlap, fused = _split(
+            ints, [nb for nb, _ in x_shapes] + [Lb + 1, Bb, Bb, Bb]
+        )
+        counts, w, c, ops, rep, *Xs = _split(
+            floats, [Bb] * 5 + [nb * d for nb, d in x_shapes]
+        )
+        # Lb + 1 slots: the padded layer table and a dump slot for pad rows
+        times = jnp.zeros((Lb + 1,), dtype=jnp.float64)
+        for (feature, threshold, left, right, value, n_trees), p, X, shape, is_log in zip(
+            groups, pos, Xs, x_shapes, log_flags
         ):
-            y = _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees)
+            y = _traverse(
+                jnp, lax, feature, threshold, left, right, value, X.reshape(shape), n_trees
+            )
             if is_log:
                 y = jnp.exp(y)
-            times = times.at[pos].set(y)
+            times = times.at[p].set(y)
         # Eq. 10 first term / Eq. 9: per-block left-fold sum and max.  Padded
         # layer rows carry segment id Bb (the dump segment, sliced away).
         sums = jax.ops.segment_sum(times, block_seg, num_segments=Bb + 1)[:Bb]
         maxs = jax.ops.segment_max(times, block_seg, num_segments=Bb + 1)[:Bb]
         t = sums - launch * jnp.maximum(0.0, counts - 1.0)
-        t = jnp.where(fused, t - (ops * w + c), t)  # Eq. 10/11
-        t = jnp.where(overlap, maxs, t)  # Eq. 9
+        t = jnp.where(fused != 0, t - (ops * w + c), t)  # Eq. 10/11
+        t = jnp.where(overlap != 0, maxs, t)  # Eq. 9
         t = jnp.maximum(t, jnp.where(counts > 0.0, launch, 0.0))
         # Eq. 12: per-network sum of block time x repeat; padded blocks have
         # rep == 0 and net segment Nb (the dump segment).
-        return jax.ops.segment_sum(t * rep, net_seg, num_segments=net_dummy.shape[0])
+        return jax.ops.segment_sum(t * rep, net_seg, num_segments=Nb + 1)
 
-    return jax.jit(network_estimate, donate_argnums=(1,))
+    return jax.jit(network_estimate, static_argnums=(4,), donate_argnums=(2,))
 
 
 def predict_network_batch_jax(oracle, batch, net_id, n_nets) -> np.ndarray | None:
@@ -337,8 +381,9 @@ def predict_network_batch_jax(oracle, batch, net_id, n_nets) -> np.ndarray | Non
 
 
 def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
-    """``(log_flags, args)`` of the network program for ``batch``: features,
-    bucket padding, positions, and the block and network segment tables."""
+    """``(log_flags, args)`` of the network program for ``batch``: the
+    resident forests, features, bucket padding, positions, and the block and
+    network segment tables, packed into one int64 and one float64 buffer."""
     ests = []
     for lt in batch.group_types:
         try:
@@ -362,6 +407,7 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
     Nb = bucket_rows(max(1, n_nets))
 
     groups = []
+    pos = []
     Xs = []
     log_flags = []
     for g, (est, cfgs) in enumerate(zip(ests, batch.group_configs)):
@@ -370,20 +416,10 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
         nb = bucket_rows(ng)
         Xp = np.zeros((nb, d), dtype=np.float64)
         Xp[:ng] = X
-        pos = np.full(nb, Lb, dtype=np.int64)  # pads write the dump slot
-        pos[:ng] = np.flatnonzero(batch.group_of == g)
-        stack = est.forest._stacked()
-        groups.append(
-            (
-                stack.feature,
-                stack.threshold,
-                stack.left,
-                stack.right,
-                stack.value,
-                np.float64(len(est.forest._trees)),
-                pos,
-            )
-        )
+        p = np.full(nb, Lb, dtype=np.int64)  # pads write the dump slot
+        p[:ng] = np.flatnonzero(batch.group_of == g)
+        groups.append(resident_forest(est.forest._stacked()))
+        pos.append(p)
         Xs.append(Xp)
         log_flags.append(bool(getattr(est, "log_target", False)))
 
@@ -409,16 +445,15 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
     rep[:n_blocks] = batch.repeat
     net_seg = np.full(Bb, Nb, dtype=np.int64)
     net_seg[:n_blocks] = net_id
-    net_dummy = np.zeros(Nb + 1, dtype=np.float64)
 
-    args = (
-        tuple(groups), tuple(Xs), block_seg, counts_p, overlap, fused, w, c,
-        ops, rep, net_seg, net_dummy, np.float64(oracle.launch_overhead_s),
-    )
+    # Two host buffers per call, in the order network_estimate splits them.
+    ints = np.concatenate([*pos, block_seg, net_seg, overlap, fused], dtype=np.int64)
+    floats = np.concatenate([counts_p, w, c, ops, rep, *(X.ravel() for X in Xs)])
+    layout = (Lb, Bb, Nb, tuple(X.shape for X in Xs))
+    args = (tuple(groups), ints, floats, _resident_launch(oracle), layout)
     _count_trace(
         "network", _seen_network_sigs,
-        (tuple(log_flags), Lb, Bb, Nb)
-        + tuple((g[0].shape, X.shape) for g, X in zip(groups, Xs)),
+        (tuple(log_flags), layout) + tuple(g[0].shape for g in groups),
         args,
     )
     return tuple(log_flags), args

@@ -163,10 +163,61 @@ def test_forest_engine_invalidated_on_refit():
     forest = RandomForestRegressor(n_estimators=5, max_depth=6, seed=0)
     forest.fit(X, X.sum(axis=1))
     y1 = forest.predict(X, backend="jax")
-    forest.fit(X, X.prod(axis=1))  # refit resets the stack and its engine
+    forest.fit(X, X.prod(axis=1))  # refit resets the stack and its device tables
     y2 = forest.predict(X, backend="jax")
     assert np.array_equal(y2, forest.predict(X))
     assert not np.array_equal(y1, y2)
+
+
+def _counters(*names):
+    from repro.obs.metrics import metrics
+
+    c = metrics().snapshot()["counters"]
+    return {n: c.get(n, 0) for n in names}
+
+
+def _delta(before, after):
+    return {k: after[k] - before[k] for k in before}
+
+
+_RESIDENT = ("jax.resident.uploads", "jax.resident.bytes")
+
+
+def test_resident_tables_keep_their_dtypes_and_values():
+    import jax
+
+    rng = np.random.default_rng(5)
+    X = rng.uniform(0, 10, size=(80, 3))
+    forest = RandomForestRegressor(n_estimators=4, max_depth=5, seed=0)
+    forest.fit(X, X.sum(axis=1))
+    stack = forest._stacked()
+    tables = jax_predict.resident_forest(stack)
+    assert tables is jax_predict.resident_forest(stack)  # cached on the stack
+    host = (stack.feature, stack.threshold, stack.left, stack.right, stack.value)
+    for dev, ref in zip(tables, host):
+        assert isinstance(dev, jax.Array)
+        assert dev.dtype == ref.dtype and dev.dtype in (np.int32, np.float64)
+        assert np.array_equal(np.asarray(dev), ref)
+    assert tables[5].dtype == np.float64 and float(tables[5]) == 4.0
+
+
+def test_refit_forest_uploads_again():
+    rng = np.random.default_rng(6)
+    X = rng.uniform(0, 10, size=(100, 2))
+    forest = RandomForestRegressor(n_estimators=5, max_depth=6, seed=0)
+    forest.fit(X, X.sum(axis=1))
+    before = _counters(*_RESIDENT)
+    forest.predict(X, backend="jax")
+    forest.predict(X[:7], backend="jax")
+    once = _counters(*_RESIDENT)
+    stack = forest._stacked()
+    nbytes = sum(getattr(stack, t).nbytes
+                 for t in ("feature", "threshold", "left", "right", "value")) + 8
+    assert _delta(before, once) == {"jax.resident.uploads": 1, "jax.resident.bytes": nbytes}
+    forest.fit(X, X.prod(axis=1))
+    y = forest.predict(X, backend="jax")
+    assert _delta(once, _counters(*_RESIDENT))["jax.resident.uploads"] == 1
+    assert np.array_equal(y, forest.predict(X))
 
 
 def test_layer_predict_bitwise_including_ragged(toy_oracle):
@@ -360,6 +411,70 @@ def test_autotune_parity_across_backends_and_paths(tpu_oracle):
     np.testing.assert_allclose(
         [s for _, s in ranked_jx], [s for _, s in ranked], rtol=1e-12
     )
+
+
+def test_autotune_uploads_each_forest_once():
+    """A warm oracle copies only the call's own tables: each forest goes to
+    the device on the first call, nothing on the second, and a repeated
+    shape compiles no new network program."""
+    import dataclasses as dc
+
+    from repro.configs import get_config
+    from repro.core.advisor import autotune
+    from repro.models.config import InputShape
+
+    oracle = _oracle("tpu_v5e", ("dense", "attention_decode", "embed"))
+    cfg = get_config("qwen2-1.5b")
+    shape = InputShape(name="t", seq_len=1024, global_batch=8, kind="decode")
+    ranked = autotune(oracle, cfg, shape, chips=16)
+
+    jax_oracle = dc.replace(oracle, predict_backend="jax")
+    names = (*_RESIDENT, "jax.network.calls", "jax.network.traces")
+    before = _counters(*names)
+    first = autotune(jax_oracle, cfg, shape, chips=16)
+    mid = _counters(*names)
+    second = autotune(jax_oracle, cfg, shape, chips=16)
+    d1, d2 = _delta(before, mid), _delta(mid, _counters(*names))
+    assert d1["jax.resident.uploads"] == len(oracle.estimators)
+    assert d1["jax.network.calls"] == 1
+    assert d2 == {"jax.resident.uploads": 0, "jax.resident.bytes": 0,
+                  "jax.network.calls": 1, "jax.network.traces": 0}
+    assert first == second
+    assert [c for c, _ in first] == [c for c, _ in ranked]
+    np.testing.assert_allclose(
+        [s for _, s in first], [s for _, s in ranked], rtol=1e-12
+    )
+
+
+def test_network_program_per_bucket_signature(toy_oracle):
+    """Networks of other sizes inside the same buckets reuse the compiled
+    program and still match numpy."""
+    nets = _toy_nets()
+    other = [[Block(kind="k", layers=(("toy", {"a": 2 * i + 1, "b": i + 1}),) * (i % 2 + 1),
+                    repeat=i % 4 + 1)
+              for i in range(j, j + 3)]
+             for j in range(5)]
+    toy_oracle.predict_networks(nets, backend="jax")
+    before = _counters("jax.network.calls", "jax.network.traces")
+    y = toy_oracle.predict_networks(other, backend="jax")
+    assert _delta(before, _counters("jax.network.calls", "jax.network.traces")) == {
+        "jax.network.calls": 1, "jax.network.traces": 0}
+    np.testing.assert_allclose(y, toy_oracle.predict_networks(other), rtol=1e-12, atol=0.0)
+
+
+def test_launch_overhead_change_reaches_the_device(tpu_oracle):
+    import dataclasses as dc
+
+    nets = [[Block(kind="mlp", layers=(
+        ("dense", {"tokens": 512, "d_in": 1024, "d_out": 4096}),
+        ("dense", {"tokens": 512, "d_in": 4096, "d_out": 1024}),
+    ))]]
+    oracle = dc.replace(tpu_oracle, launch_overhead_s=0.0)
+    y0 = oracle.predict_networks(nets, backend="jax")
+    oracle.launch_overhead_s = 1e-3
+    y1 = oracle.predict_networks(nets, backend="jax")
+    assert y1[0] != y0[0]
+    np.testing.assert_allclose(y1, oracle.predict_networks(nets), rtol=1e-12, atol=0.0)
 
 
 # ---------------------------------------------------------- decompose_batch

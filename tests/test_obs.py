@@ -643,6 +643,8 @@ class TestQueryPathObservability:
 
     @pytest.mark.parametrize("kind", ["forest", "network"])
     def test_h2d_bytes_equal_the_arguments_nbytes(self, kind, oracle, monkeypatch):
+        """The counter counts the call's host leaves (numpy arrays and
+        scalars), not the resident node tables passed beside them."""
         import jax
 
         from repro.core import jax_predict
@@ -662,13 +664,21 @@ class TestQueryPathObservability:
 
         monkeypatch.setattr(jax_predict, fn.__name__, spy)
         if kind == "forest":
-            _fitted_forest(3).predict(np.ones((100, 3)), backend="jax")
+            forests = [_fitted_forest(3)]
+            forests[0].predict(np.ones((100, 3)), backend="jax")
         else:
+            forests = [est.forest for est in oracle.estimators.values()]
             autotune(oracle, *_autotune_args(), chips=16)
         (args,) = sent
-        nbytes = sum(np.asarray(a).nbytes for a in jax.tree_util.tree_leaves(args))
+        leaves = jax.tree_util.tree_leaves(args)
+        nbytes = sum(a.nbytes for a in leaves if isinstance(a, (np.ndarray, np.generic)))
         counters = obs_metrics().snapshot()["counters"]
         assert counters[f"jax.{kind}.h2d_bytes"] == nbytes > 0
+        assert any(isinstance(a, jax.Array) for a in leaves)
+        stacks = [f._stacked() for f in forests]
+        tables = sum(getattr(s, t).nbytes for s in stacks
+                     for t in ("feature", "threshold", "left", "right", "value"))
+        assert nbytes < tables
 
     def test_compile_seconds_grow_on_a_new_bucket_only(self):
         pytest.importorskip("jax")

@@ -96,12 +96,16 @@ def test_forest_traversal_f64(one_chip):
 
 
 def test_network_kernel_eq9_12(one_chip):
-    """One log-target and one linear group through the fused Eq. 9-12 call."""
+    """One log-target and one linear group through the fused Eq. 9-12 call,
+    with its per-call tables packed into one int64 and one float64 buffer."""
     from repro.core import jax_predict
 
     trees, nodes, feats = 32, 2048, 5
     n_layers, n_blocks, n_nets = 256, 128, 64
     group_rows = (128, 128)
+    layout = (n_layers, n_blocks, n_nets, tuple((rows, feats) for rows in group_rows))
+    n_ints = sum(group_rows) + n_layers + 1 + 3 * n_blocks
+    n_floats = 5 * n_blocks + sum(rows * feats for rows in group_rows)
     with jax.enable_x64(True):
         groups = tuple(
             (
@@ -111,20 +115,15 @@ def test_network_kernel_eq9_12(one_chip):
                 _sds((trees, nodes), jnp.int32, one_chip),
                 _sds((trees, nodes), jnp.float64, one_chip),
                 _sds((), jnp.float64, one_chip),
-                _sds((rows,), jnp.int64, one_chip),
             )
-            for rows in group_rows
+            for _ in group_rows
         )
-        xs = tuple(_sds((rows, feats), jnp.float64, one_chip) for rows in group_rows)
-        f64 = lambda *shape: _sds(shape, jnp.float64, one_chip)  # noqa: E731
-        flags = lambda: _sds((n_blocks,), jnp.bool_, one_chip)  # noqa: E731
         compiled = jax_predict._network_fn((True, False)).lower(
-            groups, xs,
-            _sds((n_layers + 1,), jnp.int64, one_chip),
-            f64(n_blocks), flags(), flags(), f64(n_blocks), f64(n_blocks),
-            f64(n_blocks), f64(n_blocks),
-            _sds((n_blocks,), jnp.int64, one_chip),
-            f64(n_nets + 1), f64(),
+            groups,
+            _sds((n_ints,), jnp.int64, one_chip),
+            _sds((n_floats,), jnp.float64, one_chip),
+            _sds((), jnp.float64, one_chip),
+            layout,
         ).compile()
     # one float64 estimate per network slot, plus the padding dump slot
     assert compiled.out_info.shape == (n_nets + 1,)
