@@ -81,12 +81,12 @@ def _tpu_fn(layer_type: str, mxu: int, sublane: int, kv_page: int, ssd_chunk: in
     elif layer_type == "moe_gemm":
 
         def terms(cols, kv):
-            e, topk = cols["E"], cols["topk"]
+            e, topk, mats = cols["E"], cols["topk"], cols.get("mats", 3)
             per_expert = pad(-(-(cols["tokens"] * topk) // e), sublane)
             dm = pad(cols["d_model"], mxu)
             df = pad(cols["d_ff"], mxu)
-            flops = 3.0 * 2.0 * e * per_expert * dm * df
-            bytes_ = 2.0 * (3 * e * dm * df + e * per_expert * (2 * dm + 2 * df))
+            flops = 2.0 * mats * e * per_expert * dm * df
+            bytes_ = 2.0 * (mats * e * dm * df + e * per_expert * (2 * dm + 2 * df))
             return flops, bytes_
 
     elif layer_type == "ssd_scan":
@@ -100,7 +100,16 @@ def _tpu_fn(layer_type: str, mxu: int, sublane: int, kv_page: int, ssd_chunk: in
             nchunks = s // q
             per_chunk = 2.0 * q * q * n + 2.0 * q * q * p + 4.0 * q * n * p
             flops = b * h * nchunks * per_chunk
-            bytes_ = 2.0 * b * s * (h * p * 2 + 2 * n + h)
+            bytes_ = 2.0 * b * s * (h * p * 2 + 2 * cols.get("G", 1) * n + h)
+            return flops, bytes_
+
+    elif layer_type == "ssd_decode":
+
+        def terms(cols, kv):
+            b, h, p, n = cols["B"], cols["H"], cols["P"], cols["N"]
+            state = b * h * pad(p, sublane) * pad(n, mxu)
+            flops = 5.0 * state
+            bytes_ = 2.0 * (2 * state + b * (h * p * 2 + 2 * cols.get("G", 1) * n + h))
             return flops, bytes_
 
     elif layer_type == "embed":

@@ -7,7 +7,9 @@ hierarchy:
   * MXU: 128x128 systolic array -> matmul contraction/output dims pad to 128;
   * VREG sublanes: 8 -> the token/row dimension pads to 8;
   * KV caches are paged in 128-token pages -> decode S_kv pads to 128;
-  * Mamba2 SSD runs in 128-token chunks;
+  * Mamba2 SSD runs in 128-token chunks; a decode step advances each
+    sequence's (H, P, N) state by one token instead, reading and writing the
+    whole state once (memory bound), with (P, N) in (8, 128) tiles;
   * MoE expert GEMMs pad tokens-per-expert to 8 -> the *token* step width of an
     (E, top-k) MoE layer is E*8/topk, a step width that is only discoverable by
     sweeps (gray/black-box) unless the mapping is documented (white-box).
@@ -78,6 +80,7 @@ class TPUv5eSim(Platform):
         moe_experts: int = 64,
         moe_topk: int = 8,
         kv_ratio: int = 4,
+        moe_mats: int = 3,
         chip: V5EChip = V5E,
     ) -> None:
         assert knowledge in ("white", "gray", "black")
@@ -87,13 +90,15 @@ class TPUv5eSim(Platform):
         self.moe_experts = moe_experts
         self.moe_topk = moe_topk
         self.kv_ratio = kv_ratio
+        #: matrices of one expert: 3 gated (in, gate, out), 2 otherwise
+        self.moe_mats = moe_mats
         self.chip = chip
 
     def cache_key(self) -> str:
         # The timing model depends on these beyond what `name` encodes.
         return (
             f"{self.name}|noise={self.noise}|E={self.moe_experts}"
-            f"|topk={self.moe_topk}|kv={self.kv_ratio}"
+            f"|topk={self.moe_topk}|kv={self.kv_ratio}|mats={self.moe_mats}"
         )
 
     def spawn_spec(self) -> tuple[str, dict, str]:
@@ -105,6 +110,7 @@ class TPUv5eSim(Platform):
             "moe_experts": self.moe_experts,
             "moe_topk": self.moe_topk,
             "kv_ratio": self.kv_ratio,
+            "moe_mats": self.moe_mats,
         }
         if self.chip is not V5E:
             kwargs["chip"] = self.chip  # frozen dataclass, pickles fine
@@ -118,6 +124,7 @@ class TPUv5eSim(Platform):
             "attention_decode",
             "moe_gemm",
             "ssd_scan",
+            "ssd_decode",
             "embed",
         )
 
@@ -140,12 +147,14 @@ class TPUv5eSim(Platform):
         if layer_type == "moe_gemm":
             return ParamSpace(
                 ranges={"tokens": (64, 65536), "d_model": (128, 4096), "d_ff": (128, 8192)},
-                fixed={"E": self.moe_experts, "topk": self.moe_topk},
+                fixed={"E": self.moe_experts, "topk": self.moe_topk, "mats": self.moe_mats},
             )
         if layer_type == "ssd_scan":
             return ParamSpace(
                 ranges={"B": (1, 64), "S": (128, 32768), "H": (1, 128), "P": (32, 256), "N": (16, 256)}
             )
+        if layer_type == "ssd_decode":
+            return ParamSpace(ranges={"B": (1, 256), "H": (1, 128), "P": (32, 256), "N": (16, 256)})
         if layer_type == "embed":
             return ParamSpace(ranges={"tokens": (8, 131072), "vocab": (1024, 262144), "d_model": (128, 8192)})
         raise KeyError(layer_type)
@@ -155,8 +164,12 @@ class TPUv5eSim(Platform):
             "dense": {"tokens": 2048, "d_in": 2048, "d_out": 2048},
             "attention_prefill": {"B": 8, "S": 2048, "H": 16, "Dh": 128, "kv_ratio": self.kv_ratio},
             "attention_decode": {"B": 32, "S_kv": 4096, "H": 16, "Dh": 128, "kv_ratio": self.kv_ratio},
-            "moe_gemm": {"tokens": 4096, "d_model": 2048, "d_ff": 1024, "E": self.moe_experts, "topk": self.moe_topk},
+            "moe_gemm": {
+                "tokens": 4096, "d_model": 2048, "d_ff": 1024,
+                "E": self.moe_experts, "topk": self.moe_topk, "mats": self.moe_mats,
+            },
             "ssd_scan": {"B": 8, "S": 2048, "H": 48, "P": 64, "N": 64},
+            "ssd_decode": {"B": 32, "H": 48, "P": 64, "N": 128},
             "embed": {"tokens": 8192, "vocab": 32000, "d_model": 2048},
         }[layer_type]
 
@@ -172,6 +185,7 @@ class TPUv5eSim(Platform):
                 "d_ff": c.mxu,
             },
             "ssd_scan": {"B": 1, "S": c.ssd_chunk, "H": c.sublane, "P": c.mxu, "N": c.mxu},
+            "ssd_decode": {"B": 1, "H": 1, "P": c.sublane, "N": c.mxu},
             "embed": {"tokens": 1, "vocab": 1, "d_model": 1},
         }
         if self.knowledge == "white":
@@ -208,13 +222,13 @@ class TPUv5eSim(Platform):
             flops = 4.0 * b * h * s * dh
             bytes_ = 2.0 * (2 * b * kvh * s * dh + 2 * b * h * dh)
         elif layer_type == "moe_gemm":
-            e, topk = cfg["E"], cfg["topk"]
+            e, topk, mats = cfg["E"], cfg["topk"], cfg.get("mats", 3)
             per_expert = _pad(int(math.ceil(cfg["tokens"] * topk / e)), c.sublane)
             dm = _pad(cfg["d_model"], c.mxu)
             df = _pad(cfg["d_ff"], c.mxu)
-            # gated MLP per expert: in+gate+out = 3 GEMMs
-            flops = 3.0 * 2.0 * e * per_expert * dm * df
-            bytes_ = 2.0 * (3 * e * dm * df + e * per_expert * (2 * dm + 2 * df))
+            # one GEMM per matrix of each expert: in+gate+out gated, in+out not
+            flops = 2.0 * mats * e * per_expert * dm * df
+            bytes_ = 2.0 * (mats * e * dm * df + e * per_expert * (2 * dm + 2 * df))
         elif layer_type == "ssd_scan":
             b, h = cfg["B"], _pad(cfg["H"], c.sublane)
             p = _pad(cfg["P"], c.mxu)
@@ -225,7 +239,14 @@ class TPUv5eSim(Platform):
             # per chunk: C B^T (q x q), (L.(CB^T)) x (q x p), plus state in/out
             per_chunk = 2.0 * q * q * n + 2.0 * q * q * p + 4.0 * q * n * p
             flops = b * h * nchunks * per_chunk
-            bytes_ = 2.0 * b * s * (h * p * 2 + 2 * n + h)  # x,y,B,C,dt
+            bytes_ = 2.0 * b * s * (h * p * 2 + 2 * cfg.get("G", 1) * n + h)  # x,y,B,C,dt
+        elif layer_type == "ssd_decode":
+            b, h, p, n = cfg["B"], cfg["H"], cfg["P"], cfg["N"]
+            state = b * h * _pad(p, c.sublane) * _pad(n, c.mxu)
+            # decay and x.B^T into the state, C.state out: 5 operations a
+            # state element; the state read and written once, x,y,B,C,dt
+            flops = 5.0 * state
+            bytes_ = 2.0 * (2 * state + b * (h * p * 2 + 2 * cfg.get("G", 1) * n + h))
         elif layer_type == "embed":
             t, dm = cfg["tokens"], cfg["d_model"]
             flops = 0.0
@@ -277,12 +298,12 @@ class TPUv5eSim(Platform):
             flops = 4.0 * b * h * s * dh
             bytes_ = 2.0 * (2 * b * kvh * s * dh + 2 * b * h * dh)
         elif layer_type == "moe_gemm":
-            e, topk = col("E"), col("topk")
+            e, topk, mats = col("E"), col("topk"), get("mats", 3)
             per_expert = _pad_arr(-(-(col("tokens") * topk) // e), c.sublane)
             dm = _pad_arr(col("d_model"), c.mxu)
             df = _pad_arr(col("d_ff"), c.mxu)
-            flops = 3.0 * 2.0 * e * per_expert * dm * df
-            bytes_ = 2.0 * (3 * e * dm * df + e * per_expert * (2 * dm + 2 * df))
+            flops = 2.0 * mats * e * per_expert * dm * df
+            bytes_ = 2.0 * (mats * e * dm * df + e * per_expert * (2 * dm + 2 * df))
         elif layer_type == "ssd_scan":
             b, h = col("B"), _pad_arr(col("H"), c.sublane)
             p = _pad_arr(col("P"), c.mxu)
@@ -292,7 +313,12 @@ class TPUv5eSim(Platform):
             nchunks = s // q
             per_chunk = 2.0 * q * q * n + 2.0 * q * q * p + 4.0 * q * n * p
             flops = b * h * nchunks * per_chunk
-            bytes_ = 2.0 * b * s * (h * p * 2 + 2 * n + h)
+            bytes_ = 2.0 * b * s * (h * p * 2 + 2 * get("G", 1) * n + h)
+        elif layer_type == "ssd_decode":
+            b, h, p, n = col("B"), col("H"), col("P"), col("N")
+            state = b * h * _pad_arr(p, c.sublane) * _pad_arr(n, c.mxu)
+            flops = 5.0 * state
+            bytes_ = 2.0 * (2 * state + b * (h * p * 2 + 2 * get("G", 1) * n + h))
         elif layer_type == "embed":
             t, dm = col("tokens"), col("d_model")
             flops = np.zeros(len(batch), dtype=np.float64)

@@ -1,3 +1,3 @@
-from repro.configs.registry import ARCHS, get_config
+from repro.configs.registry import ARCHS, ESTIMATED, get_config
 
-__all__ = ["ARCHS", "get_config"]
+__all__ = ["ARCHS", "ESTIMATED", "get_config"]

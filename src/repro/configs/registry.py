@@ -1,4 +1,6 @@
-"""Registry of the 10 assigned architectures (``--arch <id>``)."""
+"""Registry of the 10 assigned architectures (``--arch <id>``), and of the
+architectures the oracle only estimates (``ESTIMATED``): ``get_config`` knows
+both, the model zoo builds only ``ARCHS``."""
 
 from __future__ import annotations
 
@@ -17,6 +19,9 @@ ARCHS: tuple[str, ...] = (
     "mamba2-780m",
 )
 
+#: decomposed, scored and ranked by the oracle; no forward pass in the zoo
+ESTIMATED: tuple[str, ...] = ("nemotron-3-nano-30b-a3b",)
+
 _MODULES = {
     "zamba2-2.7b": "zamba2_2p7b",
     "granite-20b": "granite_20b",
@@ -28,11 +33,12 @@ _MODULES = {
     "qwen2-vl-2b": "qwen2_vl_2b",
     "whisper-medium": "whisper_medium",
     "mamba2-780m": "mamba2_780m",
+    "nemotron-3-nano-30b-a3b": "nemotron3_nano_30b_a3b",
 }
 
 
 def get_config(arch: str):
     if arch not in _MODULES:
-        raise KeyError(f"unknown arch {arch!r}; choose from {ARCHS}")
+        raise KeyError(f"unknown arch {arch!r}; choose from {ARCHS + ESTIMATED}")
     mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
     return mod.CONFIG

@@ -20,7 +20,7 @@ import dataclasses
 from typing import Protocol, Sequence
 
 from repro.core.blocks import Block
-from repro.core.network import decompose, decompose_batch
+from repro.core.network import decompose, decompose_steps
 from repro.models.config import InputShape, ModelConfig
 from repro.obs.trace import span
 
@@ -69,13 +69,17 @@ def candidate_blocks(
     return decompose(cfg, micro_shape, cand.dp, cand.tp)
 
 
-def candidate_block_batch(cfg: ModelConfig, shape: InputShape, cand: Candidate):
-    """Columnar :func:`candidate_blocks`: one :class:`BlockBatch` per candidate,
-    built without materialising ``Block`` objects."""
-    micro_shape = dataclasses.replace(
-        shape, global_batch=max(1, shape.global_batch // cand.microbatches)
-    )
-    return decompose_batch(cfg, micro_shape, cand.dp, cand.tp)
+def candidates_block_batch(
+    cfg: ModelConfig, shape: InputShape, cands: Sequence[Candidate]
+):
+    """Columnar :func:`candidate_blocks` of every candidate in one
+    :class:`BlockBatch`, built without materialising ``Block`` objects, and
+    the candidate index of each block."""
+    return decompose_steps(cfg, [
+        (dataclasses.replace(shape, global_batch=max(1, shape.global_batch // c.microbatches)),
+         c.dp, c.tp)
+        for c in cands
+    ])
 
 
 def estimate_candidate(
@@ -124,19 +128,11 @@ def autotune(
         predict_batch = getattr(estimator, "predict_network_batch", None)
         predict_many = getattr(estimator, "predict_networks", None)
         if predict_batch is not None:
-            # Columnar-native: decompose each candidate straight into a
-            # BlockBatch (no Block objects), merge, and score in one call.
-            import numpy as np
-
-            from repro.core.batch import BlockBatch
-
+            # Columnar-native: decompose every candidate straight into one
+            # BlockBatch (no Block objects) and score it in one call.
             with span("advisor.decompose"):
-                batches = [candidate_block_batch(cfg, shape, c) for _, c in chosen]
-                merged = BlockBatch.concat(batches)
-                net_id = np.repeat(
-                    np.arange(len(batches)), [len(b) for b in batches]
-                )
-            preds = predict_batch(merged, net_id=net_id, n_nets=len(batches))
+                merged, net_id = candidates_block_batch(cfg, shape, [c for _, c in chosen])
+            preds = predict_batch(merged, net_id=net_id, n_nets=len(chosen))
         elif predict_many is not None:
             preds = predict_many([candidate_blocks(cfg, shape, c) for _, c in chosen])
         else:

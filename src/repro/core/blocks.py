@@ -50,9 +50,12 @@ def op_count(layer_type: str, cfg: Config) -> float:
     if layer_type == "attention_decode":
         return 4.0 * cfg["B"] * cfg["H"] * cfg["S_kv"] * cfg["Dh"]
     if layer_type == "moe_gemm":
-        return 6.0 * cfg["tokens"] * cfg["topk"] * cfg["d_model"] * cfg["d_ff"]
+        mats = cfg.get("mats", 3)  # matrices of one expert: 3 gated, 2 not
+        return 2.0 * mats * cfg["tokens"] * cfg["topk"] * cfg["d_model"] * cfg["d_ff"]
     if layer_type == "ssd_scan":
         return 2.0 * cfg["B"] * cfg["S"] * cfg["H"] * cfg["P"] * (2 * cfg["N"] + 128)
+    if layer_type == "ssd_decode":
+        return 4.0 * cfg["B"] * cfg["H"] * cfg["P"] * cfg["N"]
     if layer_type == "embed":
         return 2.0 * cfg["tokens"] * cfg["d_model"]
     if layer_type == "conv1d":
@@ -87,9 +90,12 @@ def op_count_batch(layer_type: str, batch) -> np.ndarray:
     if layer_type == "attention_decode":
         return 4.0 * col("B") * col("H") * col("S_kv") * col("Dh")
     if layer_type == "moe_gemm":
-        return 6.0 * col("tokens") * col("topk") * col("d_model") * col("d_ff")
+        mats = get("mats", 3)
+        return 2.0 * mats * col("tokens") * col("topk") * col("d_model") * col("d_ff")
     if layer_type == "ssd_scan":
         return 2.0 * col("B") * col("S") * col("H") * col("P") * (2 * col("N") + 128)
+    if layer_type == "ssd_decode":
+        return 4.0 * col("B") * col("H") * col("P") * col("N")
     if layer_type == "embed":
         return 2.0 * col("tokens") * col("d_model")
     if layer_type == "conv1d":

@@ -48,14 +48,19 @@ def derived_features(layer_type: str, cfg: Config) -> dict[str, float]:
         byt = cfg["B"] * kvh * cfg["S_kv"] * cfg["Dh"] * 2
         return {"macs": macs, "bytes": byt}
     if layer_type == "moe_gemm":
+        mats = cfg.get("mats", 3)
         per_expert = cfg["tokens"] * cfg["topk"] / max(1, cfg["E"])
-        macs = 3 * cfg["tokens"] * cfg["topk"] * cfg["d_model"] * cfg["d_ff"]
-        weights = 3 * cfg["E"] * cfg["d_model"] * cfg["d_ff"]
+        macs = mats * cfg["tokens"] * cfg["topk"] * cfg["d_model"] * cfg["d_ff"]
+        weights = mats * cfg["E"] * cfg["d_model"] * cfg["d_ff"]
         return {"macs": macs, "weights": weights, "per_expert": per_expert}
     if layer_type == "ssd_scan":
         macs = cfg["B"] * cfg["S"] * cfg["H"] * cfg["P"] * (2 * cfg["N"] + 128)
-        byt = cfg["B"] * cfg["S"] * (2 * cfg["H"] * cfg["P"] + 2 * cfg["N"])
+        byt = cfg["B"] * cfg["S"] * (2 * cfg["H"] * cfg["P"] + 2 * cfg.get("G", 1) * cfg["N"])
         return {"macs": macs, "bytes": byt}
+    if layer_type == "ssd_decode":
+        state = cfg["B"] * cfg["H"] * cfg["P"] * cfg["N"]
+        byt = 2 * state + cfg["B"] * (2 * cfg["H"] * cfg["P"] + 2 * cfg.get("G", 1) * cfg["N"])
+        return {"macs": 2 * state, "bytes": byt}
     if layer_type == "embed":
         return {"bytes": cfg["tokens"] * cfg["d_model"], "macs": cfg["tokens"] * cfg["d_model"]}
     return {}
@@ -100,14 +105,19 @@ def derived_features_batch(layer_type: str, batch: ConfigBatch) -> np.ndarray:
         byt = col("B") * kvh * col("S_kv") * col("Dh") * 2
         cols = [macs, byt]
     elif layer_type == "moe_gemm":
+        mats = get("mats", 3)
         per_expert = col("tokens") * col("topk") / np.maximum(1, col("E"))
-        macs = 3 * col("tokens") * col("topk") * col("d_model") * col("d_ff")
-        weights = 3 * col("E") * col("d_model") * col("d_ff")
+        macs = mats * col("tokens") * col("topk") * col("d_model") * col("d_ff")
+        weights = mats * col("E") * col("d_model") * col("d_ff")
         cols = [macs, weights, per_expert]
     elif layer_type == "ssd_scan":
         macs = col("B") * col("S") * col("H") * col("P") * (2 * col("N") + 128)
-        byt = col("B") * col("S") * (2 * col("H") * col("P") + 2 * col("N"))
+        byt = col("B") * col("S") * (2 * col("H") * col("P") + 2 * get("G", 1) * col("N"))
         cols = [macs, byt]
+    elif layer_type == "ssd_decode":
+        state = col("B") * col("H") * col("P") * col("N")
+        byt = 2 * state + col("B") * (2 * col("H") * col("P") + 2 * get("G", 1) * col("N"))
+        cols = [2 * state, byt]
     elif layer_type == "embed":
         td = col("tokens") * col("d_model")
         cols = [td, td]

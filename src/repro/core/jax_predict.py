@@ -105,6 +105,13 @@ DEVICE_RTOL = 1e-12
 # arrays and scalars.  The forests' node tables and tree counts are uploaded
 # once (:func:`resident_forest`, counted in ``jax.resident.uploads`` and
 # ``jax.resident.bytes``) and passed as device arrays, which copy nothing.
+# ``jax.network.rows`` counts the block-layer rows a network call sends to its
+# forests, ``jax.network.pad_rows`` the rows added to fill each group's
+# bucket, and ``jax.network.rows.ssm`` the rows of the Mamba-2 groups.
+
+#: layer types of the Mamba-2 mixer, counted in ``jax.network.rows.ssm``
+SSM_LAYER_TYPES = frozenset({"ssd_scan", "ssd_decode"})
+
 _seen_forest_sigs: set[tuple] = set()
 _seen_network_sigs: set[tuple] = set()
 _upload_lock = threading.Lock()
@@ -410,10 +417,14 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
     pos = []
     Xs = []
     log_flags = []
-    for g, (est, cfgs) in enumerate(zip(ests, batch.group_configs)):
+    pad_rows = ssm_rows = 0
+    for g, (lt, est, cfgs) in enumerate(zip(batch.group_types, ests, batch.group_configs)):
         X = est._features(cfgs, snap=True)
         ng, d = X.shape
         nb = bucket_rows(ng)
+        pad_rows += nb - ng
+        if lt in SSM_LAYER_TYPES:
+            ssm_rows += ng
         Xp = np.zeros((nb, d), dtype=np.float64)
         Xp[:ng] = X
         p = np.full(nb, Lb, dtype=np.int64)  # pads write the dump slot
@@ -456,4 +467,8 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
         (tuple(log_flags), layout) + tuple(g[0].shape for g in groups),
         args,
     )
+    reg = obs_metrics()
+    reg.inc("jax.network.rows", L)
+    reg.inc("jax.network.pad_rows", pad_rows)
+    reg.inc("jax.network.rows.ssm", ssm_rows)
     return tuple(log_flags), args

@@ -1,7 +1,9 @@
 """Whole-model decomposition: ModelConfig x InputShape x mesh -> building blocks.
 
 This is the bridge between the paper's methodology and the framework: any of
-the 10 assigned architectures decomposes into per-device building-block
+the 10 assigned architectures, and any model built by a layer pattern
+(``ModelConfig.layer_pattern``, NemotronH's Mamba-2 / MoE / attention),
+decomposes into per-device building-block
 instances (attention block, MLP block, MoE block, SSD block, embed, LM head)
 whose layer configurations live in the TPU-v5e platform's parameter spaces.
 The PR-trained single-layer estimators then predict per-block times, combined
@@ -17,6 +19,8 @@ from __future__ import annotations
 
 import math
 from typing import Sequence
+
+import numpy as np
 
 from repro.core.blocks import Block
 from repro.core.prs import Config
@@ -75,36 +79,54 @@ def _decompose_plan(
         layers.append(("dense", {"tokens": t_loc, "d_in": h_loc * hd, "d_out": d}))
         return ("attn", tuple(layers), coll_act)
 
-    def mlp_block() -> tuple:
-        f_loc = max(1, f // tp)
+    def mlp_layers(width: int) -> list:
+        f_loc = max(1, width // tp)
         n_in = 2 if cfg.mlp == "swiglu" else 1
         layers = [("dense", {"tokens": t_loc, "d_in": d, "d_out": f_loc})] * n_in
         layers.append(("dense", {"tokens": t_loc, "d_in": f_loc, "d_out": d}))
-        return ("mlp", tuple(layers), coll_act)
+        return layers
+
+    def mlp_block() -> tuple:
+        return ("mlp", tuple(mlp_layers(f)), coll_act)
 
     def moe_block() -> tuple:
         e_loc = max(1, cfg.moe_experts // tp)
+        experts = {
+            "tokens": max(1, t_loc // tp),
+            "d_model": d,
+            "d_ff": f,
+            "E": e_loc,
+            "topk": cfg.moe_top_k,
+        }
+        if cfg.mlp_mats != 3:  # the platform's default is a gated expert
+            experts["mats"] = cfg.mlp_mats
         layers = [
             ("dense", {"tokens": t_loc, "d_in": d, "d_out": cfg.moe_experts}),  # router
-            (
-                "moe_gemm",
-                {
-                    "tokens": max(1, t_loc // tp),
-                    "d_model": d,
-                    "d_ff": f,
-                    "E": e_loc,
-                    "topk": cfg.moe_top_k,
-                },
-            ),
+            ("moe_gemm", experts),
         ]
+        if cfg.moe_shared_d_ff:
+            layers += mlp_layers(cfg.moe_shared_d_ff)
         return ("moe", tuple(layers), 2 * coll_act)
 
     def ssd_block() -> tuple:
         di_loc = max(1, cfg.d_inner // tp)
         h_ssm = max(1, cfg.ssm_heads // tp)
+        p, n = cfg.ssm_headdim, cfg.ssm_state
+        # decode advances each sequence's state by one token; it scans no chunk
+        if is_decode:
+            mixer = {"B": b_loc, "H": h_ssm, "P": p, "N": n}
+        else:
+            mixer = {"B": b_loc, "S": s, "H": h_ssm, "P": p, "N": n}
+        if cfg.layer_pattern:
+            # z, x, B, C and dt column-sharded: this chip's share of each
+            g_loc = max(1, cfg.ssm_groups // tp)
+            d_proj = 2 * di_loc + 2 * g_loc * n + h_ssm
+            mixer["G"] = g_loc
+        else:
+            d_proj = 2 * di_loc + 2 * n + cfg.ssm_heads
         layers = [
-            ("dense", {"tokens": t_loc, "d_in": d, "d_out": 2 * di_loc + 2 * cfg.ssm_state + cfg.ssm_heads}),
-            ("ssd_scan", {"B": b_loc, "S": s, "H": h_ssm, "P": cfg.ssm_headdim, "N": cfg.ssm_state}),
+            ("dense", {"tokens": t_loc, "d_in": d, "d_out": d_proj}),
+            ("ssd_decode" if is_decode else "ssd_scan", mixer),
             ("dense", {"tokens": t_loc, "d_in": di_loc, "d_out": d}),
         ]
         return ("ssd", tuple(layers), coll_act)
@@ -117,7 +139,11 @@ def _decompose_plan(
     yield ("embed", (("embed", {"tokens": t_loc, "vocab": v, "d_model": d}),), 0.0, rep)
 
     # ---- body ----
-    if cfg.family in ("dense", "vlm"):
+    if cfg.layer_pattern:
+        blocks = {"M": ssd_block, "E": moe_block, "*": attn_block}
+        for kind, n in cfg.layer_counts().items():
+            yield body(blocks[kind](), n)
+    elif cfg.family in ("dense", "vlm"):
         yield body(attn_block(), cfg.n_layers)
         yield body(mlp_block(), cfg.n_layers)
     elif cfg.family == "moe":
@@ -206,12 +232,30 @@ def decompose_batch(
     objects and the re-grouping pass of ``BlockBatch.from_blocks``.  Field-
     for-field identical to ``BlockBatch.from_blocks(decompose(...))``.
     """
+    return decompose_steps(cfg, [(shape, dp, tp)], train_factor)[0]
+
+
+def decompose_steps(
+    cfg: ModelConfig,
+    steps: Sequence[tuple[InputShape, int, int]],
+    train_factor: float = 3.0,
+):
+    """Many ``(shape, dp, tp)`` steps of one model in one
+    :class:`~repro.core.batch.BlockBatch`, and the step index of each block.
+
+    Field-for-field identical to ``BlockBatch.concat`` of each step's
+    :func:`decompose_batch`: groups merge by first occurrence either way,
+    but one builder validates and stacks the table once, not once a step.
+    """
     from repro.core.batch import BlockBatchBuilder
 
     builder = BlockBatchBuilder()
-    for kind, layers, coll, repeat in _decompose_plan(cfg, shape, dp, tp, train_factor):
-        builder.add(kind, layers, collective_bytes=coll, repeat=repeat)
-    return builder.build()
+    step_of: list[int] = []
+    for k, (shape, dp, tp) in enumerate(steps):
+        for kind, layers, coll, repeat in _decompose_plan(cfg, shape, dp, tp, train_factor):
+            builder.add(kind, layers, collective_bytes=coll, repeat=repeat)
+            step_of.append(k)
+    return builder.build(), np.asarray(step_of, dtype=np.int64)
 
 
 def simulate_network(platform, blocks: Sequence[Block]) -> float:

@@ -103,7 +103,7 @@ def estimate_decode_step(cfg, batch: int, seq_len: int,
     from repro.core.network import decompose
     from repro.models.config import InputShape
 
-    layer_types = ("dense", "attention_decode", "moe_gemm", "ssd_scan", "embed")
+    layer_types = ("dense", "attention_decode", "moe_gemm", "ssd_decode", "embed")
     platform_name = "tpu_v5e[gray]"
     oracle = None
     if hub_dir:

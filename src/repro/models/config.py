@@ -13,6 +13,9 @@ from typing import Literal
 
 Family = Literal["dense", "moe", "ssm", "hybrid", "vlm", "audio"]
 
+#: kinds of layer in ``ModelConfig.layer_pattern``: Mamba-2, experts, attention
+LAYER_KINDS = ("M", "E", "*")
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
@@ -27,7 +30,8 @@ class ModelConfig:
 
     head_dim: int = 0  # 0 -> d_model // n_heads
     qkv_bias: bool = False
-    mlp: Literal["swiglu", "gelu"] = "swiglu"
+    #: "relu2" is a non-gated squared-ReLU MLP: two matrices, like "gelu"
+    mlp: Literal["swiglu", "gelu", "relu2"] = "swiglu"
     rope_theta: float = 1e6
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -36,6 +40,8 @@ class ModelConfig:
     moe_experts: int = 0
     moe_top_k: int = 0
     capacity_factor: float = 1.25
+    #: width of the shared expert every token passes through (0 = none)
+    moe_shared_d_ff: int = 0
 
     # --- SSM (Mamba2) ---
     ssm_state: int = 0
@@ -43,10 +49,20 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    #: groups sharing one B and one C projection (Mamba-2 ``n_groups``)
+    ssm_groups: int = 1
+    #: Mamba heads given outright; then d_inner = heads x head dim (NemotronH)
+    #: and not ``ssm_expand * d_model``.  0 = derived from ``ssm_expand``.
+    ssm_n_heads: int = 0
 
     # --- hybrid (zamba2-style): one shared attention+MLP block applied
     # after every `attn_every` mamba blocks (weights shared across uses) ---
     attn_every: int = 0
+
+    # --- hybrid by layer pattern (NemotronH-style): one character per layer,
+    # "M" a Mamba-2 mixer, "E" a mixture of experts with no attention in
+    # front of it, "*" a GQA attention layer.  Empty = the family's own body.
+    layer_pattern: str = ""
 
     # --- encoder-decoder (whisper-style) ---
     n_encoder_layers: int = 0
@@ -70,15 +86,35 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.n_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.layer_pattern:
+            unknown = set(self.layer_pattern) - set(LAYER_KINDS)
+            if unknown or len(self.layer_pattern) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r} must hold {self.n_layers} "
+                    f"characters of {''.join(LAYER_KINDS)!r}"
+                )
 
     # ---------------------------------------------------------- derived sizes
     @property
     def d_inner(self) -> int:
+        if self.ssm_n_heads:
+            return self.ssm_n_heads * self.ssm_headdim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_heads(self) -> int:
+        if self.ssm_n_heads:
+            return self.ssm_n_heads
         return self.d_inner // self.ssm_headdim if self.ssm_state else 0
+
+    @property
+    def mlp_mats(self) -> int:
+        """Matrices of one MLP or expert: three gated, two otherwise."""
+        return 3 if self.mlp == "swiglu" else 2
+
+    def layer_counts(self) -> dict[str, int]:
+        """Layers of each kind of ``layer_pattern``, in order of first use."""
+        return {k: self.layer_pattern.count(k) for k in dict.fromkeys(self.layer_pattern)}
 
     @property
     def is_encoder_decoder(self) -> bool:
@@ -102,17 +138,23 @@ class ModelConfig:
         attn = d * n_q + 2 * d * n_kv + n_q * d
         if self.qkv_bias:
             attn += n_q + 2 * n_kv
-        mlp = d * f * (3 if self.mlp == "swiglu" else 2)
-        moe_mlp = 3 * d * f * self.moe_experts + d * self.moe_experts
+        mlp = d * f * self.mlp_mats
+        moe_mlp = self.mlp_mats * d * f * self.moe_experts + d * self.moe_experts
         ssm = 0
         if self.ssm_state:
-            di, g, n, h = self.d_inner, 1, self.ssm_state, self.ssm_heads
+            di, g, n, h = self.d_inner, self.ssm_groups, self.ssm_state, self.ssm_heads
             proj_out = 2 * di + 2 * g * n + h
             ssm = d * proj_out + self.ssm_conv * (di + 2 * g * n) + 3 * h + di + di * d
         emb = v * d * (1 if self.tie_embeddings else 2)
         n = emb + 2 * d  # final norm(s)
         per_layer_norms = 2 * d
-        if self.family == "moe":
+        if self.layer_pattern:
+            kinds = self.layer_counts()
+            shared = self.mlp_mats * d * self.moe_shared_d_ff
+            n += kinds.get("M", 0) * (ssm + d)
+            n += kinds.get("E", 0) * (moe_mlp + shared + d)
+            n += kinds.get("*", 0) * (attn + d)
+        elif self.family == "moe":
             n += self.n_layers * (attn + moe_mlp + per_layer_norms)
         elif self.family == "ssm":
             n += self.n_layers * (ssm + d)
@@ -131,12 +173,13 @@ class ModelConfig:
 
     def active_param_count(self) -> int:
         """Active parameters per token (MoE: top-k experts only)."""
-        if self.family != "moe":
+        if not self.moe_experts:
             return self.param_count()
+        moe_layers = self.layer_counts().get("E", 0) if self.layer_pattern else self.n_layers
         d, f = self.d_model, self.d_ff
-        dense_moe = 3 * d * f * self.moe_experts
-        active_moe = 3 * d * f * self.moe_top_k
-        return int(self.param_count() - self.n_layers * (dense_moe - active_moe))
+        dense_moe = self.mlp_mats * d * f * self.moe_experts
+        active_moe = self.mlp_mats * d * f * self.moe_top_k
+        return int(self.param_count() - moe_layers * (dense_moe - active_moe))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,10 +208,14 @@ def shape_applicable(cfg: ModelConfig, shape: InputShape) -> bool:
 
 
 def reduced(cfg: ModelConfig) -> ModelConfig:
-    """Tiny same-family variant for CPU smoke tests."""
+    """Tiny same-family variant for CPU smoke tests; a layer pattern keeps
+    one layer of each of its kinds, in order of first use."""
+    pattern = "".join(dict.fromkeys(cfg.layer_pattern))
     return dataclasses.replace(
         cfg,
-        n_layers=min(cfg.n_layers, 2 * max(1, cfg.attn_every) if cfg.attn_every else 2),
+        n_layers=len(pattern) if pattern else min(
+            cfg.n_layers, 2 * max(1, cfg.attn_every) if cfg.attn_every else 2),
+        layer_pattern=pattern,
         d_model=128,
         n_heads=4,
         n_kv_heads=min(cfg.n_kv_heads, 2) if cfg.n_kv_heads < cfg.n_heads else 4,
@@ -177,8 +224,11 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         vocab=512,
         moe_experts=min(cfg.moe_experts, 8) if cfg.moe_experts else 0,
         moe_top_k=min(cfg.moe_top_k, 2) if cfg.moe_top_k else 0,
+        moe_shared_d_ff=256 if cfg.moe_shared_d_ff else 0,
         ssm_state=min(cfg.ssm_state, 16) if cfg.ssm_state else 0,
         ssm_headdim=32 if cfg.ssm_state else cfg.ssm_headdim,
+        ssm_groups=min(cfg.ssm_groups, 2),
+        ssm_n_heads=4 if cfg.ssm_n_heads else 0,
         mrope_sections=(4, 6, 6) if cfg.mrope else cfg.mrope_sections,
         n_encoder_layers=min(cfg.n_encoder_layers, 2),
         encoder_seq=min(cfg.encoder_seq, 64) if cfg.encoder_seq else 0,
