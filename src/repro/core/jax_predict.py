@@ -2,7 +2,7 @@
 
 The predict path (never the fit path) can run through ``jax.jit``: the stacked
 forest traversal, the four analytical timing models, and the whole-network
-combination are pure int64/float64 array programs.  This module owns the
+combination are pure integer and float64 array programs.  This module owns the
 backend selection and the compiled kernels for the forest and network paths;
 the platform timing kernels live in :mod:`repro.accelerators.jax_kernels`.
 
@@ -31,15 +31,23 @@ All kernels run in float64 via the scoped ``jax.enable_x64(True)`` context
 the dtype behaviour of unrelated jax code in the same process).
 
 * **Layer predictions are bitwise identical** to numpy on the CPU, and
-  within :data:`DEVICE_RTOL` on a TPU, whose float64 is emulated.  The compiled
-  traversal replays the numpy descent loop gather-for-gather, accumulates
-  per-tree values in tree order (``lax.fori_loop`` left fold — *not*
-  ``jnp.sum``, whose pairwise order differs), and divides by a *traced*
-  tree-count scalar (XLA strength-reduces division by a compile-time constant
-  into multiplication by its reciprocal, a 1-ulp difference; a traced divisor
-  keeps the true division).  The log-target inversion stays ``np.exp``
-  *outside* the jit, so on the CPU :meth:`LayerEstimator.predict` is
-  bit-for-bit equal across backends.
+  within :data:`DEVICE_RTOL` on a TPU, whose float64 is emulated.  The dense
+  traversal does not replay the numpy descent; three things keep it exact.
+  The compare is the descent's: a row goes left at a node exactly when its
+  float64 value is at most the node's threshold, decided as an int32 compare
+  of the row's rank among its feature's sorted thresholds with the
+  threshold's own rank (NaN ranks above all, so it goes right, as in the
+  descent).  The path sums are integers no larger than the depth, summed
+  in int8 x int8 -> int32 products, never a float64 dot; so the leaf found
+  is the leaf the descent reaches, and its float64 value is read unchanged.
+  The per-tree values are folded in tree order (a left fold, *not*
+  ``jnp.sum``, whose pairwise order differs) and divided by a *traced*
+  tree-count scalar (XLA strength-reduces division by a compile-time
+  constant into multiplication by its reciprocal, a 1-ulp difference; a
+  traced divisor keeps the true division).  A forest over the dense budget
+  keeps the compiled descent, gather for gather, under the same fold.  The
+  log-target inversion stays ``np.exp`` *outside* the jit, so on the CPU
+  :meth:`LayerEstimator.predict` is bit-for-bit equal across backends.
 * **Platform timing kernels are bitwise identical**: integer tile padding is
   exact arithmetic, and every float hardware constant (peak FLOPs,
   bandwidths, clock rates) is passed as a traced scalar for the same
@@ -58,13 +66,21 @@ Batch rows are padded to power-of-two buckets (min 64) before entering a
 kernel and sliced back after, so the admission batcher's variable batch sizes
 hit a handful of warm-compiled shapes instead of retracing per request.
 
-A forest's node tables and tree count go to the device once
-(:func:`resident_forest`, cached on its ``_ForestStack``, so a refit retires
-them), as does the oracle's launch overhead; a call copies from the host only
-what it produced itself.  A forest call sends its padded rows; a network call
-sends one int64 and one float64 buffer that the program splits at static
-offsets given by the bucket sizes, so a call costs two transfers whatever the
-number of groups.
+A forest goes to the device once (:func:`resident_forest`, cached on its
+``_ForestStack``, so a refit retires it), as does the oracle's launch
+overhead; a call copies from the host only what it produced itself.  Where
+its path matrices (trees x internal nodes x leaves, int8, both padded to
+128) fit :data:`DENSE_BUDGET_BYTES`, the forest goes in the dense form
+(:class:`DenseForest`: each feature's sorted thresholds, each internal
+node's feature and threshold rank, the path matrices, each leaf's left
+turns and value), built once in numpy by walking every leaf up to its root;
+a larger forest goes as the descent's node tables (:class:`DescentForest`).
+The choice follows the forest's shape alone.  A dense traversal takes its
+trees in chunks sized from the static row bucket (:data:`_CHUNK_ELEMENTS`),
+so its path sums stay bounded at every batch size.  A forest call sends its
+padded rows; a network call sends one int64 and one float64 buffer that the
+program splits at static offsets given by the bucket sizes, so a call costs
+two transfers whatever the number of groups.
 
 The per-call buffers are donated (``donate_argnums``), never the resident
 tables; on CPU XLA currently declines input-shaped donations and copies
@@ -79,6 +95,7 @@ import functools
 import os
 import threading
 import warnings
+from typing import NamedTuple
 
 import numpy as np
 
@@ -102,9 +119,12 @@ DEVICE_RTOL = 1e-12
 # ``traces`` growing under steady live traffic means the bucketing is not
 # absorbing the batch-size jitter (a bug this repo previously could not see).
 # ``jax.*.h2d_bytes`` sums what each call copies from the host: its numpy
-# arrays and scalars.  The forests' node tables and tree counts are uploaded
-# once (:func:`resident_forest`, counted in ``jax.resident.uploads`` and
-# ``jax.resident.bytes``) and passed as device arrays, which copy nothing.
+# arrays and scalars.  The forests' tables are uploaded once
+# (:func:`resident_forest`, counted in ``jax.resident.uploads`` and
+# ``jax.resident.bytes``, the path matrices also in
+# ``jax.resident.path_bytes``) and passed as device arrays, which copy
+# nothing.  ``jax.traverse.dense_groups`` and ``jax.traverse.descent_groups``
+# count the forest calls and network groups each form traversed.
 # ``jax.network.rows`` counts the block-layer rows a network call sends to its
 # forests, ``jax.network.pad_rows`` the rows added to fill each group's
 # bucket, and ``jax.network.rows.ssm`` the rows of the Mamba-2 groups.
@@ -211,15 +231,173 @@ def bucket_rows(n: int) -> int:
 
 
 # --------------------------------------------------------------- forest kernel
-def _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees):
-    """Compiled twin of ``_ForestStack.predict_all`` + the per-tree fold.
+#: largest path-matrix table (trees x internal nodes x leaves, one int8 each,
+#: both node counts padded to the matrix unit's 128) a forest is traversed
+#: densely with; a larger forest keeps the gather descent
+DENSE_BUDGET_BYTES = 256 << 20
 
-    Same descent (every (tree, sample) pair advances until its node is a
-    leaf), same accumulation order, and a *traced* divisor — see the module
-    docstring's parity contract.
+#: most path sums (rows x leaves x trees, int32) one chunk of trees of a
+#: dense traversal holds; the chunk follows from the static row bucket
+_CHUNK_ELEMENTS = 1 << 25
+
+_MATRIX_TILE = 128
+
+
+class DescentForest(NamedTuple):
+    """A stacked forest's node tables for the gather descent, on the device."""
+
+    feature: object  # (T, N) int32, -1 at leaves and padding
+    threshold: object  # (T, N) float64
+    left: object  # (T, N) int32
+    right: object  # (T, N) int32
+    value: object  # (T, N) float64
+    n_trees: object  # () float64
+
+
+class DenseForest(NamedTuple):
+    """A stacked forest as a compare and a path-matrix product.
+
+    Internal node ``i`` of tree ``t`` sends a row left when the row's rank
+    in ``edges[feature[t, i]]`` (the count of that feature's distinct
+    thresholds below the row's value) is at most ``rank[t, i]``, its own
+    threshold's place there: exactly ``x <= threshold``.  ``paths[t, i, l]``
+    is +1 where leaf ``l`` lies under ``i``'s left child and -1 under its
+    right child, so a row's path sum at ``l`` reaches ``left_turns[t, l]``,
+    the left turns on ``l``'s path, at its own leaf only.
     """
-    T = feature.shape[0]
+
+    edges: object  # (F, K) float64, each feature's thresholds ascending, +inf padded
+    feature: object  # (T, I) int32
+    rank: object  # (T, I) int32, -1 for a NaN threshold (never left)
+    paths: object  # (T, I, L) int8
+    left_turns: object  # (T, L) int32, -1 on padded leaf columns (never reached)
+    leaf_value: object  # (T, L) float64
+    n_trees: object  # () float64
+
+
+def _padded(k: int) -> int:
+    return max(_MATRIX_TILE, -(-int(k) // _MATRIX_TILE) * _MATRIX_TILE)
+
+
+def _dense_tables(stack) -> DenseForest | None:
+    """Host tables of the dense form of ``stack``, or None when its path
+    matrices exceed :data:`DENSE_BUDGET_BYTES`.
+
+    Reachable leaves only: padding slots and the root of a one-leaf tree are
+    told apart by having no parent.  Each leaf walks up to the root once,
+    all leaves of all trees together, so the loop runs the depth.
+    """
+    feature, threshold = stack.feature, stack.threshold
+    T, N = feature.shape
+    inner = feature >= 0
+    t, i = np.nonzero(inner)
+    parent = np.full((T, N), -1, dtype=np.int64)
+    turn = np.zeros((T, N), dtype=np.int8)
+    for child, side in ((stack.left, 1), (stack.right, -1)):
+        parent[t, child[t, i]] = i
+        turn[t, child[t, i]] = side
+    leaf = ~inner & (parent >= 0)
+    leaf[:, 0] |= ~inner[:, 0]
+    I, L = _padded(inner.sum(axis=1).max()), _padded(leaf.sum(axis=1).max())
+    if T * I * L > DENSE_BUDGET_BYTES:
+        return None
+    col = np.cumsum(inner, axis=1) - 1
+    node_feature = np.zeros((T, I), dtype=np.int32)
+    node_feature[t, col[t, i]] = feature[t, i]
+    n_feat = int(feature.max()) + 1 if t.size else 1
+    per_feat = [np.unique(threshold[inner & (feature == f)]) for f in range(n_feat)]
+    per_feat = [v[~np.isnan(v)] for v in per_feat]
+    edges = np.full((n_feat, max(1, max(map(len, per_feat)))), np.inf)
+    rank = np.zeros((T, I), dtype=np.int32)
+    for f, v in enumerate(per_feat):
+        edges[f, :len(v)] = v
+        at = feature[t, i] == f
+        thr = threshold[t[at], i[at]]
+        rank[t[at], col[t[at], i[at]]] = np.where(
+            np.isnan(thr), -1, np.searchsorted(v, thr, side="left"))
+    lt, node = np.nonzero(leaf)
+    lc = (np.cumsum(leaf, axis=1) - 1)[lt, node]
+    paths = np.zeros((T, I, L), dtype=np.int8)
+    left_turns = np.full((T, L), -1, dtype=np.int32)
+    left_turns[lt, lc] = 0
+    leaf_value = np.zeros((T, L), dtype=np.float64)
+    leaf_value[lt, lc] = stack.value[lt, node]
+    while True:
+        p = parent[lt, node]
+        up = p >= 0
+        if not up.any():
+            break
+        lt, lc, node, p = lt[up], lc[up], node[up], p[up]
+        side = turn[lt, node]
+        paths[lt, col[lt, p], lc] = side
+        left_turns[lt, lc] += side > 0
+        node = p
+    return DenseForest(edges, node_feature, rank, paths, left_turns, leaf_value,
+                       np.float64(T))
+
+
+def _row_ranks(jnp, edges, X):
+    """Each row's rank in each feature's thresholds (how many lie below its
+    value), int32; a NaN value ranks above every threshold, so it goes right
+    at every node, as in the descent."""
+    x = X[:, :edges.shape[0]]
+    below = jnp.sum(edges[None, :, :] < x[:, :, None], axis=-1, dtype=jnp.int32)
+    return jnp.where(jnp.isnan(x), edges.shape[1], below)
+
+
+def _tree_chunk(n_trees: int, rows: int, leaves: int) -> int:
+    """Trees a chunk of the dense traversal takes: the largest divisor of the
+    tree count whose path sums stay within :data:`_CHUNK_ELEMENTS`."""
+    fit = max(1, _CHUNK_ELEMENTS // (rows * leaves))
+    return max(c for c in range(1, min(n_trees, fit) + 1) if n_trees % c == 0)
+
+
+def _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees):
+    """Mean over trees of each row's leaf value, folded in tree order.
+
+    Takes one of two forms, told apart by the third table:
+
+    * the descent (:class:`DescentForest`'s first five tables, ``X`` the
+      float64 rows): the compiled twin of ``_ForestStack.predict_all``, every
+      (tree, row) pair advancing until its node is a leaf;
+    * the dense form (:class:`DenseForest`'s ``feature``, ``rank``, ``paths``,
+      ``left_turns`` and ``leaf_value``, ``X`` the rows' ranks from
+      :func:`_row_ranks`): for a chunk of trees at a time, an integer compare
+      of every row at every internal node, an int8 product with the path
+      matrices, and the one leaf whose path sum equals its left turns.
+
+    Either way the per-tree values are added in tree order and divided by a
+    *traced* tree count (see the module docstring's parity contract).
+    """
     n = X.shape[0]
+    if left.ndim == 3:
+        T, I, L = left.shape
+        c = _tree_chunk(T, n, L)
+
+        def chunk(acc, tables):
+            f, q, p, d, v = tables
+            r = jnp.broadcast_to(X[None, :, 0, None], (c, n, I))
+            for k in range(1, X.shape[1]):
+                r = jnp.where(f[:, None, :] == k, X[None, :, k, None], r)
+            go_left = (r <= q[:, None, :]).astype(jnp.int8)
+            sums = jnp.einsum("cni,cil->cnl", go_left, p,
+                              preferred_element_type=jnp.int32)
+            # one column per row meets its left turns; a max of its index
+            # compiles and runs as a plain reduce, where argmax pairs values
+            # with indices
+            column = lax.broadcasted_iota(jnp.int32, sums.shape, 2)
+            leaf = jnp.max(jnp.where(sums == d[:, None, :], column, -1), axis=-1)
+            y = jnp.take_along_axis(v, leaf, axis=1)
+            for j in range(c):
+                acc = acc + y[j]
+            return acc, None
+
+        split = tuple(a.reshape((T // c, c) + a.shape[1:])
+                      for a in (feature, threshold, left, right, value))
+        acc, _ = lax.scan(chunk, jnp.zeros((n,), value.dtype), split)
+        return acc / n_trees
+
+    T = feature.shape[0]
     rows = jnp.arange(T)[:, None]
     cols = jnp.arange(n)[None, :]
 
@@ -242,34 +420,53 @@ def _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees):
     return acc / n_trees
 
 
+def _forest_rows(jnp, lax, forest, X):
+    """:func:`_traverse` of ``X`` (float64 rows) through a resident forest."""
+    if isinstance(forest, DenseForest):
+        return _traverse(jnp, lax, *forest[1:6], _row_ranks(jnp, forest.edges, X),
+                         forest.n_trees)
+    return _traverse(jnp, lax, *forest[:5], X, forest.n_trees)
+
+
+def _count_form(forest) -> None:
+    dense = isinstance(forest, DenseForest)
+    obs_metrics().inc(f"jax.traverse.{'dense' if dense else 'descent'}_groups")
+
+
 @functools.lru_cache(maxsize=1)
 def _forest_fn():
     jax, jnp, lax = jax_modules()
 
-    def forest_traverse(feature, threshold, left, right, value, X, n_trees):
-        return _traverse(jnp, lax, feature, threshold, left, right, value, X, n_trees)
+    def forest_traverse(forest, X):
+        return _forest_rows(jnp, lax, forest, X)
 
-    return jax.jit(forest_traverse, donate_argnums=(5,))
+    return jax.jit(forest_traverse, donate_argnums=(1,))
 
 
-def resident_forest(stack) -> tuple:
-    """``(feature, threshold, left, right, value, n_trees)`` of a stacked
-    forest on the device, uploaded on first use and cached on the stack.
+def resident_forest(stack) -> DenseForest | DescentForest:
+    """A stacked forest's tables on the device, built and uploaded on first
+    use and cached on the stack: the dense form where its path matrices fit
+    :data:`DENSE_BUDGET_BYTES`, else the descent's node tables.
 
     A refit builds a new ``_ForestStack``, which retires the old arrays.  The
     copy runs under :func:`x64`, which keeps float64 (outside it ``device_put``
-    narrows to float32); int32 tables stay int32.  Never donated.
+    narrows to float32); integer tables keep their dtypes.  Never donated.
     """
     with _upload_lock:
         tables = getattr(stack, "_jax_resident", None)
         if tables is None:
-            host = (stack.feature, stack.threshold, stack.left, stack.right,
-                    stack.value, np.float64(stack.feature.shape[0]))
+            host = _dense_tables(stack)
+            if host is None:
+                host = DescentForest(stack.feature, stack.threshold, stack.left,
+                                     stack.right, stack.value,
+                                     np.float64(stack.feature.shape[0]))
             with x64():
-                tables = tuple(jax_modules()[0].device_put(host))
+                tables = jax_modules()[0].device_put(host)
             reg = obs_metrics()
             reg.inc("jax.resident.uploads")
             reg.inc("jax.resident.bytes", _host_bytes(host))
+            if isinstance(host, DenseForest):
+                reg.inc("jax.resident.path_bytes", host.paths.nbytes)
             stack._jax_resident = tables
     return tables
 
@@ -295,11 +492,12 @@ def forest_predict_raw(forest, X: np.ndarray) -> np.ndarray:
     nb = bucket_rows(n)
     Xp = np.zeros((nb, d), dtype=np.float64)
     Xp[:n] = X
-    args = (*tables[:5], Xp, tables[5])
+    args = (tables, Xp)
     _count_trace(
         "forest", _seen_forest_sigs,
-        tuple(a.shape for a in tables[:5]) + ((nb, d),), args,
+        tuple(a.shape for a in tables) + ((nb, d),), args,
     )
+    _count_form(tables)
     fn = _forest_fn()
     with x64(), span("forest.launch"):
         y = fn(*args)
@@ -340,12 +538,8 @@ def _network_fn(log_flags: tuple):
         )
         # Lb + 1 slots: the padded layer table and a dump slot for pad rows
         times = jnp.zeros((Lb + 1,), dtype=jnp.float64)
-        for (feature, threshold, left, right, value, n_trees), p, X, shape, is_log in zip(
-            groups, pos, Xs, x_shapes, log_flags
-        ):
-            y = _traverse(
-                jnp, lax, feature, threshold, left, right, value, X.reshape(shape), n_trees
-            )
+        for forest, p, X, shape, is_log in zip(groups, pos, Xs, x_shapes, log_flags):
+            y = _forest_rows(jnp, lax, forest, X.reshape(shape))
             if is_log:
                 y = jnp.exp(y)
             times = times.at[p].set(y)
@@ -429,7 +623,9 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
         Xp[:ng] = X
         p = np.full(nb, Lb, dtype=np.int64)  # pads write the dump slot
         p[:ng] = np.flatnonzero(batch.group_of == g)
-        groups.append(resident_forest(est.forest._stacked()))
+        forest = resident_forest(est.forest._stacked())
+        _count_form(forest)
+        groups.append(forest)
         pos.append(p)
         Xs.append(Xp)
         log_flags.append(bool(getattr(est, "log_target", False)))
@@ -464,7 +660,7 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
     args = (tuple(groups), ints, floats, _resident_launch(oracle), layout)
     _count_trace(
         "network", _seen_network_sigs,
-        (tuple(log_flags), layout) + tuple(g[0].shape for g in groups),
+        (tuple(log_flags), layout) + tuple(tuple(a.shape for a in g) for g in groups),
         args,
     )
     reg = obs_metrics()

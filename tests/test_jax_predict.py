@@ -24,7 +24,7 @@ from repro.api import Campaign, CampaignSpec, PerfOracle
 from repro.core import jax_predict
 from repro.core.batch import BlockBatch, ConfigBatch
 from repro.core.blocks import Block
-from repro.core.forest import RandomForestRegressor
+from repro.core.forest import RandomForestRegressor, _Tree
 from repro.registry import get_platform
 
 FAST_FOREST = {"n_estimators": 8, "max_depth": 10}
@@ -180,7 +180,8 @@ def _delta(before, after):
     return {k: after[k] - before[k] for k in before}
 
 
-_RESIDENT = ("jax.resident.uploads", "jax.resident.bytes")
+_RESIDENT = ("jax.resident.uploads", "jax.resident.bytes", "jax.resident.path_bytes")
+_FORMS = ("jax.traverse.dense_groups", "jax.traverse.descent_groups")
 
 
 def test_resident_tables_keep_their_dtypes_and_values():
@@ -193,12 +194,24 @@ def test_resident_tables_keep_their_dtypes_and_values():
     stack = forest._stacked()
     tables = jax_predict.resident_forest(stack)
     assert tables is jax_predict.resident_forest(stack)  # cached on the stack
-    host = (stack.feature, stack.threshold, stack.left, stack.right, stack.value)
-    for dev, ref in zip(tables, host):
+    assert isinstance(tables, jax_predict.DenseForest)
+    host = jax_predict._dense_tables(stack)
+    dtypes = (np.float64, np.int32, np.int32, np.int8, np.int32, np.float64, np.float64)
+    for dev, ref, dtype in zip(tables, host, dtypes):
         assert isinstance(dev, jax.Array)
-        assert dev.dtype == ref.dtype and dev.dtype in (np.int32, np.float64)
+        assert dev.dtype == ref.dtype == dtype
         assert np.array_equal(np.asarray(dev), ref)
-    assert tables[5].dtype == np.float64 and float(tables[5]) == 4.0
+    assert float(tables.n_trees) == 4.0
+    # each real leaf column: its left turns are its +1 entries, its value a
+    # leaf value of its tree; padded columns are never reached
+    paths, turns = np.asarray(tables.paths), np.asarray(tables.left_turns)
+    for t in range(4):
+        leaves = stack.feature[t] < 0
+        n_leaves = int(leaves[: len(forest._trees[t].feature)].sum())
+        assert np.array_equal(turns[t, :n_leaves], (paths[t, :, :n_leaves] == 1).sum(axis=0))
+        assert np.all(turns[t, n_leaves:] == -1)
+        assert np.array_equal(np.asarray(tables.leaf_value)[t, :n_leaves],
+                              forest._trees[t].value[forest._trees[t].feature < 0])
 
 
 def test_refit_forest_uploads_again():
@@ -210,14 +223,123 @@ def test_refit_forest_uploads_again():
     forest.predict(X, backend="jax")
     forest.predict(X[:7], backend="jax")
     once = _counters(*_RESIDENT)
-    stack = forest._stacked()
-    nbytes = sum(getattr(stack, t).nbytes
-                 for t in ("feature", "threshold", "left", "right", "value")) + 8
-    assert _delta(before, once) == {"jax.resident.uploads": 1, "jax.resident.bytes": nbytes}
+    host = jax_predict._dense_tables(forest._stacked())
+    assert _delta(before, once) == {
+        "jax.resident.uploads": 1,
+        "jax.resident.bytes": sum(np.asarray(a).nbytes for a in host),
+        "jax.resident.path_bytes": host.paths.nbytes,
+    }
     forest.fit(X, X.prod(axis=1))
     y = forest.predict(X, backend="jax")
     assert _delta(once, _counters(*_RESIDENT))["jax.resident.uploads"] == 1
     assert np.array_equal(y, forest.predict(X))
+
+
+def _one_leaf_tree(value: float) -> _Tree:
+    return _Tree(np.array([-1], np.int32), np.zeros(1), np.zeros(1, np.int32),
+                 np.zeros(1, np.int32), np.array([value]))
+
+
+def _network_raw(forest, X):
+    """The forest's raw prediction through the network program: one layer a
+    block and one block a network, no launch overhead, nothing fused."""
+    n, d = X.shape
+    nb = jax_predict.bucket_rows(n)
+    Xp = np.zeros((nb, d))
+    Xp[:n] = X
+    at = np.arange(nb)
+    ints = np.concatenate([at, at, [nb], at, np.zeros(2 * nb)]).astype(np.int64)
+    floats = np.concatenate([np.ones(nb), np.zeros(3 * nb), np.ones(nb), Xp.ravel()])
+    groups = (jax_predict.resident_forest(forest._stacked()),)
+    fn = jax_predict._network_fn((False,))
+    with jax_predict.x64():
+        out = fn(groups, ints, floats, np.float64(0.0), (nb, nb, nb, ((nb, d),)))
+    return np.asarray(out)[:n]
+
+
+def _parity_rows(case, stack, rng):
+    inner = stack.feature >= 0
+    if case == "on_threshold":
+        # every value is one of its feature's thresholds: the row goes left there
+        X = rng.uniform(0, 10, size=(200, 3))
+        for f in range(3):
+            X[:, f] = rng.choice(stack.threshold[inner & (stack.feature == f)], size=200)
+        return X
+    X = rng.uniform(-1, 11, size=(333 if case == "multi_chunk" else 50, 3))
+    if case == "nan":
+        X[rng.random(X.shape) < 0.3] = np.nan
+    return X
+
+
+@pytest.mark.parametrize("case", ["bucket64", "on_threshold", "nan", "multi_chunk",
+                                  "only_leaves"])
+def test_dense_traversal_bitwise(case, monkeypatch):
+    """The dense form equals the numpy descent bit for bit, through a forest
+    call and through the network program: trees of different node counts and
+    a one-leaf tree in one stack, rows on a threshold (left), NaN (right), the
+    64-row bucket and a bucket traversed in several chunks of trees."""
+    rng = np.random.default_rng(7)
+    Xtr = rng.uniform(0, 10, size=(150, 3))
+    forest = RandomForestRegressor(n_estimators=7, max_depth=7, seed=1)
+    forest.fit(Xtr, Xtr @ np.array([1.0, 2.0, 0.5]) + 1.0)
+    trees = [_one_leaf_tree(0.25 * (i + 1)) for i in range(3)]
+    if case != "only_leaves":
+        trees = forest._trees[:3] + trees[:1] + forest._trees[3:]
+    forest._trees = trees
+    stack = forest._stacked()
+    assert len({int((t.feature >= 0).sum()) for t in trees}) > 1 or case == "only_leaves"
+    tables = jax_predict.resident_forest(stack)
+    assert isinstance(tables, jax_predict.DenseForest)
+    X = _parity_rows(case, stack, rng)
+    if case == "multi_chunk":
+        # chunks of two of the eight trees at this bucket
+        leaves = tables.paths.shape[2]
+        monkeypatch.setattr(jax_predict, "_CHUNK_ELEMENTS", 2 * 512 * leaves)
+        assert jax_predict._tree_chunk(len(trees), 512, leaves) == 2
+        jax_predict._forest_fn.cache_clear()
+        jax_predict._network_fn.cache_clear()
+    try:
+        ref = forest.predict(X)
+        assert np.array_equal(forest.predict(X, backend="jax"), ref)
+        assert np.array_equal(_network_raw(forest, X), ref)
+    finally:
+        if case == "multi_chunk":
+            jax_predict._forest_fn.cache_clear()
+            jax_predict._network_fn.cache_clear()
+
+
+@pytest.mark.parametrize("dense", [True, False], ids=["dense", "over_budget"])
+def test_traversal_form_follows_the_budget(dense, toy_oracle, monkeypatch):
+    """A forest whose path matrices exceed the budget keeps the descent and
+    still matches numpy; the counters count the form each forest call and
+    each network group ran, and the path bytes uploaded."""
+    import dataclasses
+
+    if not dense:
+        monkeypatch.setattr(jax_predict, "DENSE_BUDGET_BYTES", 0)
+    ests = {}
+    for lt, e in toy_oracle.estimators.items():
+        forest = RandomForestRegressor()
+        forest._trees = e.forest._trees  # a new stack: uploaded afresh
+        ests[lt] = dataclasses.replace(e, forest=forest, log_target=False)
+    oracle = dataclasses.replace(toy_oracle, estimators=ests)
+    cfgs = [{"a": (i * 7) % 64 + 1, "b": (i * 3) % 32 + 1} for i in range(100)]
+    names = (*_RESIDENT, *_FORMS)
+    before = _counters(*names)
+    assert np.array_equal(oracle.predict("toy", cfgs, backend="jax"),
+                          oracle.predict("toy", cfgs))
+    assert np.array_equal(oracle.predict_networks(_toy_nets(), backend="jax"),
+                          oracle.predict_networks(_toy_nets()))
+    d = _delta(before, _counters(*names))
+    stack = ests["toy"].forest._stacked()
+    form = jax_predict.DenseForest if dense else jax_predict.DescentForest
+    assert isinstance(jax_predict.resident_forest(stack), form)
+    paths = jax_predict.resident_forest(stack).paths.nbytes if dense else 0
+    assert d["jax.resident.uploads"] == 1
+    assert d["jax.resident.path_bytes"] == paths
+    # one forest call and one group (the toy layer type) in the network call
+    assert d["jax.traverse.dense_groups"] == (2 if dense else 0)
+    assert d["jax.traverse.descent_groups"] == (0 if dense else 2)
 
 
 def test_layer_predict_bitwise_including_ragged(toy_oracle):
@@ -438,6 +560,7 @@ def test_autotune_uploads_each_forest_once():
     assert d1["jax.resident.uploads"] == len(oracle.estimators)
     assert d1["jax.network.calls"] == 1
     assert d2 == {"jax.resident.uploads": 0, "jax.resident.bytes": 0,
+                  "jax.resident.path_bytes": 0,
                   "jax.network.calls": 1, "jax.network.traces": 0}
     assert first == second
     assert [c for c, _ in first] == [c for c, _ in ranked]

@@ -77,27 +77,69 @@ def test_ssd_scan_mamba2_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _descent_forest(trees, nodes, sharding):
+    from repro.core import jax_predict
+
+    return jax_predict.DescentForest(
+        _sds((trees, nodes), jnp.int32, sharding),
+        _sds((trees, nodes), jnp.float64, sharding),
+        _sds((trees, nodes), jnp.int32, sharding),
+        _sds((trees, nodes), jnp.int32, sharding),
+        _sds((trees, nodes), jnp.float64, sharding),
+        _sds((), jnp.float64, sharding),
+    )
+
+
+def _dense_forest(trees, inner, leaves, feats, edges, sharding):
+    from repro.core import jax_predict
+
+    return jax_predict.DenseForest(
+        _sds((feats, edges), jnp.float64, sharding),
+        _sds((trees, inner), jnp.int32, sharding),
+        _sds((trees, inner), jnp.int32, sharding),
+        _sds((trees, inner, leaves), jnp.int8, sharding),
+        _sds((trees, leaves), jnp.int32, sharding),
+        _sds((trees, leaves), jnp.float64, sharding),
+        _sds((), jnp.float64, sharding),
+    )
+
+
 def test_forest_traversal_f64(one_chip):
     from repro.core import jax_predict
 
     trees, nodes, rows, feats = 32, 4096, 1024, 6
     with jax.enable_x64(True):
-        args = (
-            _sds((trees, nodes), jnp.int32, one_chip),
-            _sds((trees, nodes), jnp.float64, one_chip),
-            _sds((trees, nodes), jnp.int32, one_chip),
-            _sds((trees, nodes), jnp.int32, one_chip),
-            _sds((trees, nodes), jnp.float64, one_chip),
+        compiled = jax_predict._forest_fn().lower(
+            _descent_forest(trees, nodes, one_chip),
             _sds((rows, feats), jnp.float64, one_chip),
-            _sds((), jnp.float64, one_chip),
-        )
-        compiled = jax_predict._forest_fn().lower(*args).compile()
+        ).compile()
     assert "while" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows", [64, 16384])
+def test_forest_traversal_dense(one_chip, rows):
+    """The dense form at the benchmark forests' widths (32 trees padded to
+    1408 internal nodes and 1408 leaves, six features of up to 7040
+    thresholds): its products are int8 with int32 sums, never float64."""
+    import re
+
+    from repro.core import jax_predict
+
+    with jax.enable_x64(True):
+        compiled = jax_predict._forest_fn().lower(
+            _dense_forest(32, 1408, 1408, 6, 7040, one_chip),
+            _sds((rows, 6), jnp.float64, one_chip),
+        ).compile()
+    hlo = compiled.as_text()
+    products = re.findall(r"= (\S+) (?:convolution|dot)\(", hlo)
+    assert products and all(p.startswith("s32[") for p in products), products
+    assert compiled.out_info.shape == (rows,) and compiled.out_info.dtype == jnp.float64
+
+
 def test_network_kernel_eq9_12(one_chip):
-    """One log-target and one linear group through the fused Eq. 9-12 call,
-    with its per-call tables packed into one int64 and one float64 buffer."""
+    """One log-target group in the dense form and one linear group in the
+    descent through the fused Eq. 9-12 call, with its per-call tables packed
+    into one int64 and one float64 buffer."""
     from repro.core import jax_predict
 
     trees, nodes, feats = 32, 2048, 5
@@ -107,17 +149,8 @@ def test_network_kernel_eq9_12(one_chip):
     n_ints = sum(group_rows) + n_layers + 1 + 3 * n_blocks
     n_floats = 5 * n_blocks + sum(rows * feats for rows in group_rows)
     with jax.enable_x64(True):
-        groups = tuple(
-            (
-                _sds((trees, nodes), jnp.int32, one_chip),
-                _sds((trees, nodes), jnp.float64, one_chip),
-                _sds((trees, nodes), jnp.int32, one_chip),
-                _sds((trees, nodes), jnp.int32, one_chip),
-                _sds((trees, nodes), jnp.float64, one_chip),
-                _sds((), jnp.float64, one_chip),
-            )
-            for _ in group_rows
-        )
+        groups = (_dense_forest(trees, 1408, 1408, feats, 7040, one_chip),
+                  _descent_forest(trees, nodes, one_chip))
         compiled = jax_predict._network_fn((True, False)).lower(
             groups,
             _sds((n_ints,), jnp.int64, one_chip),
