@@ -1,7 +1,7 @@
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
-.PHONY: test lint bench-smoke bench bench-engine bench-runtime bench-forest bench-blocks bench-serve bench-predict bench-obs bench-analysis bench-chaos serve-smoke quickstart
+.PHONY: test lint paper-smoke quickstart
 
 test:
 	$(PYTHON) -m pytest -x -q
@@ -9,41 +9,8 @@ test:
 lint:
 	$(PYTHON) -m repro.analysis src
 
-bench-smoke:
-	$(PYTHON) -m benchmarks.run table1 fig2
-
-bench-engine:
-	$(PYTHON) -m benchmarks.bench_engine
-
-bench-runtime:
-	$(PYTHON) -m benchmarks.bench_runtime
-
-bench-forest:
-	$(PYTHON) -m benchmarks.bench_forest
-
-bench-blocks:
-	$(PYTHON) -m benchmarks.bench_blocks
-
-bench-serve:
-	$(PYTHON) -m benchmarks.bench_serve
-
-bench-predict:
-	$(PYTHON) -m benchmarks.bench_predict
-
-bench-obs:
-	$(PYTHON) -m benchmarks.bench_obs
-
-bench-analysis:
-	$(PYTHON) -m benchmarks.bench_analysis
-
-bench-chaos:
-	$(PYTHON) -m benchmarks.bench_chaos
-
-serve-smoke:
-	$(PYTHON) -m benchmarks.serve_smoke
-
-bench:
-	$(PYTHON) -m benchmarks.run
+paper-smoke:
+	$(PYTHON) -m experiments.run table1 fig2
 
 quickstart:
 	$(PYTHON) examples/quickstart.py

@@ -17,9 +17,8 @@ from repro.models.config import SHAPES, shape_applicable
 
 import sys
 import os
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from benchmarks.table2_whole_network import build_network_estimator  # noqa: E402
+from experiments.table2_whole_network import build_network_estimator  # noqa: E402
 
 
 def main() -> None:

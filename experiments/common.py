@@ -1,7 +1,7 @@
-"""Shared benchmark helpers: CSV emission + scale control.
+"""Shared experiment helpers: CSV emission + scale control.
 
-Every benchmark prints ``name,us_per_call,derived`` rows (harness contract).
-``us_per_call`` is the mean wall-time of the benchmark's unit operation in
+Every experiment prints ``name,us_per_call,derived`` rows (harness contract).
+``us_per_call`` is the mean wall-time of the experiment's unit operation in
 microseconds; ``derived`` carries the headline metric (e.g. ``mape=1.23%``).
 
 REPRO_BENCH_SCALE=full reproduces paper-scale sample counts (~9000); the

@@ -6,7 +6,7 @@ report the detected step width (the PRs are the last point of each step).
 
 from __future__ import annotations
 
-from benchmarks.common import Timer, emit
+from experiments.common import Timer, emit
 from repro.accelerators import TPUv5eSim, UltraTrailSim, VTASim
 from repro.core import steps, sweeps
 

@@ -13,8 +13,8 @@ policies.
 
 from __future__ import annotations
 
-from benchmarks.common import Timer, emit, sizes_for_curves
-from benchmarks.table1_single_layer import TCRESNET8, TPU_DENSE, VTA_FC
+from experiments.common import Timer, emit, sizes_for_curves
+from experiments.table1_single_layer import TCRESNET8, TPU_DENSE, VTA_FC
 from repro.api import Campaign, CampaignSpec
 
 CASES = [

@@ -1,10 +1,11 @@
-"""Benchmark harness -- one function per paper table/figure.
+"""The paper's accuracy experiments -- one function per table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows.
+Prints ``name,us_per_call,derived`` CSV rows; ``us_per_call`` is CPU wall
+clock, not a device metric (the on-chip benchmark is ``bench/``).
 
-  PYTHONPATH=src python -m benchmarks.run            # all benchmarks, CI scale
-  REPRO_BENCH_SCALE=full PYTHONPATH=src python -m benchmarks.run   # paper scale
-  PYTHONPATH=src python -m benchmarks.run table1 fig2 ...          # subset
+  PYTHONPATH=src python -m experiments.run            # every suite, CI scale
+  REPRO_BENCH_SCALE=full PYTHONPATH=src python -m experiments.run   # paper scale
+  PYTHONPATH=src python -m experiments.run table1 fig2 ...          # subset
 """
 
 from __future__ import annotations
@@ -13,14 +14,9 @@ import sys
 import time
 import traceback
 
-from benchmarks import (
-    advisor_bench,
-    bench_blocks,
-    bench_engine,
-    bench_forest,
+from experiments import (
     fig2_sweeps,
     fig4to7_curves,
-    roofline_report,
     table1_single_layer,
     table2_whole_network,
     table3_sota,
@@ -32,12 +28,6 @@ SUITES = {
     "fig4to7": fig4to7_curves.main,
     "table2": table2_whole_network.main,
     "table3": table3_sota.main,
-    "roofline": roofline_report.main,
-    "advisor": advisor_bench.main,
-    "engine": bench_engine.main,
-    # argv=[] so the harness's own CLI names don't reach the benches' parsers
-    "forest": lambda: bench_forest.main([]),
-    "blocks": lambda: bench_blocks.main([]),
 }
 
 

@@ -10,7 +10,7 @@ Runs entirely through ``repro.api`` (CampaignSpec -> Campaign -> PerfOracle).
 
 from __future__ import annotations
 
-from benchmarks.common import Timer, emit, table1_size
+from experiments.common import Timer, emit, table1_size
 from repro.api import Campaign, CampaignSpec
 
 # Realistic test layers per platform/layer type (the paper uses TC-ResNet8 and

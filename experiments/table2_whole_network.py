@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import Timer, emit, scale
+from experiments.common import Timer, emit, scale
 from repro.accelerators import TPUv5eSim
 from repro.api import Campaign, CampaignSpec, PerfOracle
 from repro.configs import get_config

@@ -7,7 +7,7 @@ paper competes on.
 
 from __future__ import annotations
 
-from benchmarks.common import emit
+from experiments.common import emit
 
 LITERATURE = [
     # work, type, platform, dataset size, rmspe, mape

@@ -5,8 +5,7 @@ by the third-party-free subprocess test in tests/test_analysis.py): it walks
 Python files, parses each one once, annotates the tree with parent links,
 and hands a :class:`FileContext` to every rule whose module scope matches.
 Rules yield :class:`Finding`s; the engine filters them through per-line
-suppressions and aggregates per-rule wall time (surfaced by
-``benchmarks/bench_analysis.py`` so the full-tree lint stays fast).
+suppressions and aggregates per-rule wall time (``LintResult.rule_seconds``).
 
 Suppression syntax
 ------------------

@@ -588,8 +588,8 @@ class ObsZeroOverhead(Rule):
     """PR 8: a disabled span is one global read — nothing else.
 
     The tracer rides the measurement and serving hot paths; its zero-
-    overhead-when-disabled contract (~290 ns, 0 allocations, pinned in
-    BENCH_obs.json) only holds if call sites do no work *before* the
+    overhead-when-disabled contract (0 allocations, pinned by
+    tests/test_obs.py) only holds if call sites do no work *before* the
     ``span()`` call returns the null singleton.  Flagged: span/instant names
     built with f-strings/formatting (the string is built even when tracing
     is off) and args-dict literals passed positionally (the dict is

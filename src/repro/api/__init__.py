@@ -18,7 +18,7 @@ from repro.api.cache import CachedPlatform, MeasurementCache
 from repro.api.campaign import Campaign, CampaignSpec, train_layer_estimator
 from repro.api.hub import EstimatorHub
 from repro.api.oracle import PerfOracle
-from repro.api.registry import get_platform, list_platforms, register_platform
+from repro.registry import get_platform, list_platforms, register_platform
 from repro.core.batch import BlockBatch, ConfigBatch
 from repro.runtime import (
     DegradationReport,

@@ -31,7 +31,7 @@ from repro.accelerators.base import Platform
 from repro.api.cache import CachedPlatform, MeasurementCache
 from repro.api.hub import EstimatorHub
 from repro.api.oracle import PerfOracle
-from repro.api.registry import get_platform
+from repro.registry import get_platform
 from repro.core import prs, sweeps
 from repro.obs.metrics import metrics as obs_metrics
 from repro.obs.trace import span, tracing
@@ -120,7 +120,7 @@ def train_layer_estimator(
 class CampaignSpec:
     """Declarative description of one benchmarking campaign."""
 
-    #: registered platform name (see repro.api.registry), e.g. "ultratrail"
+    #: registered platform name (see repro.registry), e.g. "ultratrail"
     platform: str
     #: layer types to train; () means every type the platform supports
     layer_types: tuple[str, ...] = ()

@@ -13,6 +13,4 @@ exact-parity wrappers around the batched implementations.
 The public entry point to this pipeline is :mod:`repro.api`
 (``CampaignSpec`` / ``Campaign`` / ``PerfOracle`` / ``EstimatorHub``), which
 adds measurement caching, step-width reuse, and estimator persistence.
-``estimator.build_estimator``, ``estimator.sampling_curve`` and
-``blocks.NetworkEstimator`` remain as deprecated shims.
 """

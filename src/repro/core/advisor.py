@@ -11,7 +11,7 @@ winner needs a compile.
 ``autotune`` returns candidates sorted by estimated step time.  It accepts
 anything with a ``predict_network(blocks) -> float`` method — canonically a
 :class:`repro.api.PerfOracle` (e.g. from ``Campaign.run()`` or reloaded via
-``PerfOracle.load``); the deprecated ``NetworkEstimator`` shim still works.
+``PerfOracle.load``).
 """
 
 from __future__ import annotations
@@ -26,7 +26,7 @@ from repro.obs.trace import span
 
 
 class NetworkPredictor(Protocol):
-    """Structural type served by PerfOracle and NetworkEstimator alike."""
+    """Structural type served by :class:`repro.api.PerfOracle`."""
 
     def predict_network(self, blocks: Sequence[Block]) -> float: ...
 

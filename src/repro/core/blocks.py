@@ -222,54 +222,3 @@ def fit_fusing_model(
     A = np.stack([ops, np.ones(len(ops))], axis=1)
     coef, *_ = np.linalg.lstsq(A, f_targets, rcond=None)
     return FusingModel(w=float(coef[0]), c=float(coef[1]), n_fit=n_fit)
-
-
-@dataclasses.dataclass
-class NetworkEstimator:
-    """Whole-network estimator: per-layer forests + per-block combination.
-
-    .. deprecated::
-        Thin shim kept for backward compatibility; prediction delegates to
-        :class:`repro.api.oracle.PerfOracle`, whose batched ``predict`` is the
-        uniform query path (one forest pass per layer type, not per layer).
-        New code should construct a ``PerfOracle`` directly (e.g. via
-        ``Campaign.run()``).
-    """
-
-    estimators: Mapping[str, LayerEstimator]
-    fusing: Mapping[str, FusingModel] = dataclasses.field(default_factory=dict)
-    #: block kinds whose layers execute on overlapping FUs (Eq. 9 max rule)
-    overlap_kinds: frozenset[str] = frozenset()
-    #: documented per-launch overhead (gray-box knowledge): a fused block pays
-    #: it once, but the summed single-layer estimates include it per layer
-    launch_overhead_s: float = 0.0
-
-    def _oracle(self):
-        from repro.api.oracle import PerfOracle
-
-        return PerfOracle(
-            estimators=self.estimators,
-            fusing=self.fusing,
-            overlap_kinds=self.overlap_kinds,
-            launch_overhead_s=self.launch_overhead_s,
-        )
-
-    def predict_block(self, block: Block) -> float:
-        return self._oracle().predict_block(block)
-
-    def predict_network(self, blocks: Sequence[Block]) -> float:
-        return self._oracle().predict_network(blocks)  # Eq. 9-12
-
-    def evaluate_networks(
-        self, platform: Platform, networks: Sequence[Sequence[Block]]
-    ) -> dict[str, float]:
-        """MAPE/RMSPE of whole-network estimates against measured ground truth.
-
-        Delegates to :meth:`repro.api.oracle.PerfOracle.evaluate_networks`:
-        ground truth rides the columnar block path (each network measured as
-        a batch) and predictions use one forest pass per layer type across
-        the whole network set.  Raises ``TypeError`` when the platform cannot
-        measure blocks (silent ``0.0`` ground truth would read as nan/inf
-        error metrics instead of a broken setup).
-        """
-        return self._oracle().evaluate_networks(platform, networks)

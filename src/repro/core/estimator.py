@@ -2,14 +2,8 @@
 
 :class:`LayerEstimator` is the trained artifact: forest + step widths +
 parameter space.  At query time a configuration is first snapped to its PR
-(Eq. 7/8) and then predicted.
-
-.. deprecated::
-    ``build_estimator`` and ``sampling_curve`` are kept as thin shims for
-    backward compatibility.  New code should go through :mod:`repro.api`
-    (``CampaignSpec`` / ``Campaign`` / ``PerfOracle``), which adds measurement
-    caching, step-width reuse, and estimator persistence on top of the same
-    pipeline.
+(Eq. 7/8) and then predicted.  Training goes through :mod:`repro.api`
+(``train_layer_estimator`` / ``Campaign``).
 """
 
 from __future__ import annotations
@@ -122,56 +116,3 @@ class LayerEstimator:
         )
         y_pred = self.predict(test_configs)
         return {"mape": mape(y_true, y_pred), "rmspe": rmspe(y_true, y_pred)}
-
-
-def build_estimator(
-    platform: Platform,
-    layer_type: str,
-    n_samples: int,
-    sampling: str = "pr",
-    seed: int = 0,
-    threshold_linear: float = 0.02,
-    forest_kwargs: dict | None = None,
-    widths: Mapping[str, int] | None = None,
-) -> LayerEstimator:
-    """Deprecated shim -- delegates to :func:`repro.api.train_layer_estimator`.
-
-    sampling:
-      * "pr"          -- sample from the PR set (the paper's method),
-      * "random"      -- sample uniformly from the complete parameter space
-                         (the paper's baseline comparison),
-      * "random_pr"   -- random sampling *of PR points* (ablation).
-    """
-    from repro.api.campaign import train_layer_estimator
-
-    return train_layer_estimator(
-        platform,
-        layer_type,
-        n_samples,
-        sampling=sampling,
-        seed=seed,
-        threshold_linear=threshold_linear,
-        forest_kwargs=forest_kwargs,
-        widths=widths,
-    )
-
-
-def sampling_curve(
-    platform: Platform,
-    layer_type: str,
-    sizes: Sequence[int],
-    test_configs: Sequence[prs.Config],
-    sampling: str = "pr",
-    seed: int = 0,
-) -> list[dict[str, float]]:
-    """MAPE/RMSPE as a function of training-set size (Figs. 4-7).
-
-    Deprecated shim -- delegates to :meth:`repro.api.Campaign.sampling_curve`,
-    which discovers step widths once and reuses them for every size (the old
-    implementation re-swept the platform at each size).
-    """
-    from repro.api.campaign import Campaign, CampaignSpec
-
-    spec = CampaignSpec(platform=platform.name, sampling=sampling, seed=seed)
-    campaign = Campaign(spec, platform=platform)
-    return campaign.sampling_curve(layer_type, sizes, test_configs, sampling=sampling, seed=seed)

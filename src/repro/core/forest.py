@@ -17,8 +17,7 @@ tree argsorts the bootstrapped matrix once, children inherit sorted orders by
 stable partition, and the split criterion is evaluated for all candidate
 features of a node in one stacked pass.  :func:`_build_tree` below is the
 frozen scalar reference builder the engine must match bitwise — it is kept
-(unused by ``fit``) as the parity baseline for tests/test_forest_fit.py and
-benchmarks/bench_forest.py.
+(unused by ``fit``) as the parity baseline for tests/test_forest_fit.py.
 """
 
 from __future__ import annotations

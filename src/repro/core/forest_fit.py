@@ -41,10 +41,9 @@ implementations use, fully vectorized:
   pow), reading their rows straight from ``X.tolist()`` so their parents
   skip the band partition for them entirely.
 
-Bitwise contract (asserted by tests/test_forest_fit.py and enforced as the
-hard gate of benchmarks/bench_forest.py): node tables, prediction bytes and
-hub checkpoint payloads are identical to the frozen reference builder
-(:func:`repro.core.forest._build_tree`) for every seed.
+Bitwise contract (the hard gate is tests/test_forest_fit.py): node tables,
+prediction bytes and hub checkpoint payloads are identical to the frozen
+reference builder (:func:`repro.core.forest._build_tree`) for every seed.
 
 A note on the RNG stream: the forest draws each tree's bootstrap indices and
 then, while growing that tree, one ``rng.choice`` per splittable node — all

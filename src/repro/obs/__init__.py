@@ -9,8 +9,8 @@ Two halves, one import:
   nanoseconds of overhead — cheap enough to leave on the measurement hot
   path.
 * :mod:`repro.obs.metrics` — counters, pull-based gauges, and p50/p95/p99
-  histograms in one :class:`MetricsRegistry`; supersedes the old
-  ``repro.serving.metrics`` (which now re-exports from here).
+  histograms in one :class:`MetricsRegistry` (``repro.serving`` exports
+  it too).
 
 Hard invariant (pinned by tests/test_obs.py): instrumentation never touches
 the RNG stream, measurement order, or any numeric result — campaigns and

@@ -1,7 +1,7 @@
 """Unified metrics: counters, gauges, histograms and endpoint latencies.
 
 This registry absorbs and supersedes the PR-6 serving ``MetricsRegistry``
-(``repro.serving.metrics`` re-exports it for back-compat) and extends it into
+(``repro.serving`` exports it) and extends it into
 the instrumentation substrate the whole pipeline reports through:
 
 * **endpoint latencies** — the serving surface: per-endpoint request/error/
@@ -206,7 +206,7 @@ class MetricsRegistry:
         return max(time.perf_counter() - self._started_at, 1e-9)
 
     def snapshot(self) -> dict:
-        """Plain-dict view for the stats endpoint / BENCH_*.json files."""
+        """Plain-dict view for the stats endpoint."""
         with self._lock:
             elapsed = self.elapsed()
             endpoints = {}

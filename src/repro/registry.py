@@ -9,10 +9,9 @@ This module lives *outside* ``repro.api`` on purpose: platform modules import
 it at module scope, and importing anything from the ``repro.api`` package
 would run the whole api ``__init__`` (campaign, oracle, cache) — a circular
 import the moment a core module like ``repro.core.blocks`` is the first thing
-a process imports.  ``repro.api.registry`` re-exports this module's surface,
-so the documented public spelling keeps working.  Nothing heavy is imported
-here; ``repro.accelerators`` is imported lazily on first lookup so
-registration has happened by then.
+a process imports.  ``repro.api`` exports this module's surface.  Nothing
+heavy is imported here; ``repro.accelerators`` is imported lazily on first
+lookup so registration has happened by then.
 """
 
 from __future__ import annotations

@@ -1,12 +1,12 @@
-"""Deterministic platform for runtime tests and benchmarks.
+"""Deterministic platform for runtime tests.
 
 ``stepped_sim`` is a tiny black-box staircase timing model with an optional
 per-configuration wall-clock delay (``delay_s``) that emulates the cost of a
 real benchmark without any device dependency.  It matters that this lives in
 an importable, dependency-light module: process-pool workers rebuild their
 platform from a spawn spec by importing this module in a fresh interpreter,
-so runtime determinism tests and ``benchmarks/bench_runtime.py`` exercise the
-exact same spawn path a real-hardware platform uses — minus jax.
+so runtime determinism tests exercise the exact same spawn path a
+real-hardware platform uses — minus jax.
 """
 
 from __future__ import annotations

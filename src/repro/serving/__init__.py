@@ -29,7 +29,7 @@ from repro.serving.batcher import (
     ServingError,
 )
 from repro.serving.cache import ResultCache
-from repro.serving.metrics import MetricsRegistry
+from repro.obs.metrics import MetricsRegistry
 from repro.serving.server import OracleServer, ServeSpec, block_payload, parse_block
 from repro.serving.transport import OracleClient, OracleSocketServer
 

@@ -1,8 +1,8 @@
 """Admission batcher: coalesce concurrent requests into one forest pass.
 
-The batched ``PerfOracle`` sustains tens of thousands of queries per second
-*when the queries arrive as one batch* (BENCH_engine.json); a server answering
-each request with its own forest pass throws that away.  The batcher is the
+The batched ``PerfOracle`` is fast *when the queries arrive as one batch*
+(one forest pass for all rows); a server answering each request with its own
+forest pass throws that away.  The batcher is the
 request-plumbing fix: the first request to arrive opens a small admission
 window (``window_s``), every request that lands inside it joins the batch,
 and one ``process`` call answers all of them — each waiter is handed its

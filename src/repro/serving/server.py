@@ -24,8 +24,8 @@ makes that true for *concurrent, independent* clients:
 Coalescing and caching are *bitwise invisible*: forest predictions are
 row-independent and cached values are the exact float64 bits the forest
 produced, so a served answer is always identical to a direct
-``PerfOracle`` call (asserted in tests/test_serving.py and enforced as a
-hard gate in benchmarks/bench_serve.py).  That contract is backend-aware:
+``PerfOracle`` call (the hard gate is tests/test_serving.py,
+``TestServedParity``).  That contract is backend-aware:
 with ``ServeSpec.predict_backend`` (or ``REPRO_PREDICT_BACKEND``) steering
 queries through the jitted jax engine, cache keys stay shared wherever jax
 and numpy answers are bitwise-identical, and split (:meth:`OracleServer.
@@ -52,7 +52,7 @@ from repro.api.cache import batch_keys
 from repro.api.oracle import PerfOracle
 from repro.core.batch import ConfigBatch
 from repro.core.blocks import Block
-from repro.obs.metrics import metrics as obs_metrics
+from repro.obs.metrics import MetricsRegistry, metrics as obs_metrics
 from repro.obs.trace import get_tracer, span
 from repro.serving.batcher import (
     AdmissionBatcher,
@@ -61,7 +61,6 @@ from repro.serving.batcher import (
     ServingError,
 )
 from repro.serving.cache import ResultCache
-from repro.serving.metrics import MetricsRegistry
 
 
 @dataclasses.dataclass(frozen=True)

@@ -549,6 +549,15 @@ def test_full_tree_lint_is_clean_and_fast():
     assert result.suppressed >= 5  # the documented deliberate exceptions
 
 
+def test_full_tree_lint_is_deterministic():
+    """Two lints of one tree give the same findings, suppressions and files."""
+    runs = [lint_paths([str(SRC)]) for _ in range(2)]
+    prints = {
+        (tuple(f.sort_key() for f in r.findings), r.suppressed, r.files) for r in runs
+    }
+    assert len(prints) == 1
+
+
 def test_analysis_and_obs_report_import_without_third_party():
     """Satellite contract: bare-Python importability, enforced dynamically.
 

@@ -17,7 +17,7 @@ from repro.api import (
     list_platforms,
 )
 from repro.core import prs
-from repro.core.blocks import Block, NetworkEstimator
+from repro.core.blocks import Block
 from repro.core.prs import ParamSpace
 
 
@@ -169,16 +169,15 @@ class TestEstimatorHub:
 
 class TestPerfOracle:
     def test_batched_network_prediction_matches_legacy_path(self):
+        """The batched network path equals Eq. 12's definition, block by block."""
         campaign, _ = _toy_campaign()
         oracle = campaign.run()
         blocks = [
             Block(kind="x", layers=(("toy", {"a": 10, "b": 5}), ("toy", {"a": 33, "b": 17}))),
             Block(kind="x", layers=(("toy", {"a": 64, "b": 32}),), repeat=3),
         ]
-        legacy = NetworkEstimator(estimators=oracle.estimators)
-        assert oracle.predict_network(blocks) == pytest.approx(
-            legacy.predict_network(blocks), rel=1e-12
-        )
+        eq12 = sum(oracle.predict_block(b) * b.repeat for b in blocks)
+        assert oracle.predict_network(blocks) == pytest.approx(eq12, rel=1e-12)
 
     def test_overlap_and_repeat(self):
         campaign, _ = _toy_campaign()

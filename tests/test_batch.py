@@ -15,7 +15,7 @@ import pytest
 
 from repro.api.cache import CachedPlatform, MeasurementCache, batch_keys, config_key
 from repro.api.campaign import Campaign, CampaignSpec
-from repro.api.registry import get_platform
+from repro.registry import get_platform
 from repro.core import prs
 from repro.core.batch import ConfigBatch
 from repro.core.features import derived_features, derived_features_batch
@@ -293,6 +293,47 @@ class TestCampaignParity:
         assert s1 == s2
         # gray box: 2 sweep windows of <=384 points + 80 training samples
         assert s1["unique_measurements"] <= 2 * 384 + 80
+
+    def test_config_path_matches_scalar_reference_end_to_end(self):
+        """PR sampling -> cached measurement -> snap -> features -> forest on a
+        trained estimator equals the per-config scalar loops, bit for bit."""
+        campaign = Campaign(
+            CampaignSpec(
+                platform="tpu_v5e",
+                layer_types=("dense",),
+                n_samples=200,
+                seed=0,
+                forest_kwargs={"n_estimators": 8, "max_depth": 12},
+            )
+        )
+        campaign.run()
+        est = campaign.estimators["dense"]
+        space = get_platform("tpu_v5e").param_space("dense")
+
+        batch = prs.sample_pr_batch(space, est.widths, 300, np.random.default_rng(0))
+        configs = _ref_sample_pr(space, est.widths, 300, np.random.default_rng(0))
+        assert batch.to_dicts() == configs
+        scalar = CachedPlatform(get_platform("tpu_v5e"))
+        batched = CachedPlatform(get_platform("tpu_v5e"))
+        assert np.array_equal(
+            batched.measure_batch("dense", batch),
+            np.array([scalar.measure("dense", c) for c in configs]),
+        )
+
+        queries = prs.sample_random_batch(space, 300, np.random.default_rng(1))
+        for rows in (batch, queries):
+            snapped = [prs.map_to_pr(c, est.widths, est.space) for c in rows.to_dicts()]
+            derived = np.array(
+                [list(derived_features("dense", c).values()) for c in snapped],
+                dtype=np.float64,
+            )
+            X = np.concatenate([prs.configs_to_matrix(snapped, est.params), derived], axis=1)
+            assert np.array_equal(est._features(rows, snap=True), X)
+            acc = np.zeros(X.shape[0])
+            for t in est.forest._trees:
+                acc += t.predict(X)
+            y = acc / len(est.forest._trees)
+            assert np.array_equal(est.predict(rows), np.exp(y) if est.log_target else y)
 
     def test_features_batch_matches_scalar_dicts(self):
         for name, kwargs in PLATFORMS:

@@ -745,6 +745,24 @@ class TestBlockJournalResume:
             == control.cache.block_misses - journaled
         )
 
+    def test_pool_journal_resume_measures_no_block_again(self, tmp_path):
+        """A calibration journaled by a 2-worker pool resumes serially from
+        its journal: every block is replayed and the fit is unchanged."""
+        journal = str(tmp_path / "measurements.jsonl")
+        train = _dense_blocks(40, 7)
+        pool = self._campaign()
+        pool_fusing = pool.calibrate_fusing(
+            {"mlp": train},
+            runtime=RuntimeSpec(workers=2, chunk_size=8, journal_path=journal),
+        )["mlp"]
+        resumed = self._campaign()
+        resumed_fusing = resumed.calibrate_fusing(
+            {"mlp": train}, runtime=RuntimeSpec(workers=1, journal_path=journal)
+        )["mlp"]
+        assert resumed.cache.block_misses == 0
+        assert resumed.cache.block_replayed == pool.cache.block_misses > 0
+        assert (resumed_fusing.w, resumed_fusing.c) == (pool_fusing.w, pool_fusing.c)
+
     def test_block_replay_is_idempotent(self, tmp_path):
         journal_path = str(tmp_path / "j.jsonl")
         batch = BlockBatch.from_blocks(_toy_blocks(5, 8))

@@ -4,10 +4,10 @@ import numpy as np
 import pytest
 
 from repro.accelerators import TPUv5eSim
+from repro.api import PerfOracle, train_layer_estimator
 from repro.configs import ARCHS, get_config
 from repro.core import prs
-from repro.core.blocks import Block, FusingModel, NetworkEstimator, block_ops, fit_fusing_model
-from repro.core.estimator import build_estimator
+from repro.core.blocks import Block, FusingModel, block_ops, fit_fusing_model
 from repro.core.network import decompose, simulate_network
 from repro.models.config import SHAPES, shape_applicable
 
@@ -19,7 +19,7 @@ def tpu():
 
 @pytest.fixture(scope="module")
 def dense_est(tpu):
-    return {"dense": build_estimator(tpu, "dense", 800, sampling="pr", seed=0)}
+    return {"dense": train_layer_estimator(tpu, "dense", 800, sampling="pr", seed=0)}
 
 
 def _mlp_blocks(n, rng):
@@ -44,8 +44,8 @@ def test_fusing_factor_reduces_block_error(tpu, dense_est):
     rng = np.random.default_rng(0)
     train_blocks = _mlp_blocks(120, rng)
     fusing = fit_fusing_model(tpu, dense_est, train_blocks)
-    est_plain = NetworkEstimator(estimators=dense_est)
-    est_fused = NetworkEstimator(estimators=dense_est, fusing={"mlp": fusing})
+    est_plain = PerfOracle(estimators=dense_est)
+    est_fused = PerfOracle(estimators=dense_est, fusing={"mlp": fusing})
     test_blocks = _mlp_blocks(40, np.random.default_rng(1))
     err_plain, err_fused = [], []
     for b in test_blocks:
@@ -60,13 +60,13 @@ def test_fusing_factor_reduces_block_error(tpu, dense_est):
 
 def test_eq9_max_rule():
     ests = {}
-    est = NetworkEstimator(estimators=ests, overlap_kinds=frozenset({"ov"}))
+    est = PerfOracle(estimators=ests, overlap_kinds=frozenset({"ov"}))
 
     class Fake:
         def predict_one(self, cfg):
             return cfg["t"]
 
-    est = NetworkEstimator(estimators={"x": Fake()}, overlap_kinds=frozenset({"ov"}))
+    est = PerfOracle(estimators={"x": Fake()}, overlap_kinds=frozenset({"ov"}))
     b = Block(kind="ov", layers=(("x", {"t": 3.0}), ("x", {"t": 5.0})))
     assert est.predict_block(b) == 5.0  # max, not sum
     b2 = Block(kind="seq", layers=b.layers)
@@ -119,7 +119,7 @@ def test_evaluate_networks_raises_without_measure_block():
         def predict_one(self, cfg):
             return cfg["t"]
 
-    est = NetworkEstimator(estimators={"x": Fake()})
+    est = PerfOracle(estimators={"x": Fake()})
     net = [Block(kind="seq", layers=(("x", {"t": 1.0}),))]
     with pytest.raises(TypeError, match="measure_block"):
         est.evaluate_networks(_NoMeasureBlockPlatform(), [net])

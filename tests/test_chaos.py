@@ -216,6 +216,24 @@ class TestChaosCampaignInvariant:
         assert misses == ref_misses
         assert degradation["injected"] == 2
 
+    def test_fault_free_hooks_change_no_measurement(self):
+        """An empty fault plan and a live health tracker leave every number
+        as a scheduler without them measures it."""
+        platform = SteppedSimPlatform()
+        rows = np.arange(512, dtype=np.int64)
+        batch = ConfigBatch.from_columns({"a": rows % 64 + 1, "b": rows % 32 + 1})
+        hooked = MeasurementScheduler(
+            FaultyExecutor(SerialExecutor(platform), FaultPlan([])),
+            chunk_size=128,
+            health=HealthTracker(HealthPolicy()),
+        )
+        plain = MeasurementScheduler(SerialExecutor(platform), chunk_size=128, health=None)
+        assert np.array_equal(
+            hooked.measure_batch("stepped_sim", "toy", batch),
+            plain.measure_batch("stepped_sim", "toy", batch),
+        )
+        assert hooked.stats.degradation.injected == 0
+
     def test_exhausted_budget_is_a_typed_error(self, tmp_path):
         plan = FaultPlan([FaultEvent(CHUNK_SITE, i, "crash") for i in range(3)])
         campaign = Campaign(_spec())

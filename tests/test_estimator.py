@@ -4,13 +4,13 @@ import numpy as np
 import pytest
 
 from repro.accelerators import TPUv5eSim, UltraTrailSim
+from repro.api import train_layer_estimator
 from repro.core import prs
-from repro.core.estimator import build_estimator
 
 
 @pytest.fixture(scope="module")
 def ut_estimator():
-    return build_estimator(UltraTrailSim(), "conv1d", 1500, sampling="pr", seed=0)
+    return train_layer_estimator(UltraTrailSim(), "conv1d", 1500, sampling="pr", seed=0)
 
 
 def test_same_step_same_prediction(ut_estimator):
@@ -36,8 +36,8 @@ def test_pr_beats_random_on_regular_platform():
     rng = np.random.default_rng(0)
     space = ut.param_space("conv1d")
     test = prs.sample_random_configs(space, 60, rng)
-    est_pr = build_estimator(ut, "conv1d", 1200, sampling="pr", seed=1)
-    est_rand = build_estimator(ut, "conv1d", 1200, sampling="random", seed=1)
+    est_pr = train_layer_estimator(ut, "conv1d", 1200, sampling="pr", seed=1)
+    est_rand = train_layer_estimator(ut, "conv1d", 1200, sampling="random", seed=1)
     m_pr = est_pr.evaluate(ut, test)["mape"]
     m_rand = est_rand.evaluate(ut, test)["mape"]
     assert m_pr < m_rand
@@ -45,7 +45,7 @@ def test_pr_beats_random_on_regular_platform():
 
 def test_estimator_bookkeeping():
     tpu = TPUv5eSim(knowledge="gray")
-    est = build_estimator(tpu, "dense", 400, sampling="pr", seed=0)
+    est = train_layer_estimator(tpu, "dense", 400, sampling="pr", seed=0)
     assert est.n_train == 400
     assert est.n_sweep > 0  # gray box swept to confirm/discover widths
     assert est.mean_measure_seconds >= 0
@@ -54,7 +54,7 @@ def test_estimator_bookkeeping():
 
 def test_tpu_dense_estimator_accuracy():
     tpu = TPUv5eSim(knowledge="white")
-    est = build_estimator(tpu, "dense", 1500, sampling="pr", seed=0)
+    est = train_layer_estimator(tpu, "dense", 1500, sampling="pr", seed=0)
     test = [
         {"tokens": 4096, "d_in": 2048, "d_out": 5504},
         {"tokens": 1024, "d_in": 1536, "d_out": 8960},

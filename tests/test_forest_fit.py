@@ -3,8 +3,8 @@
 The golden digests below were generated by the PRE-refactor scalar
 ``RandomForestRegressor.fit`` (per-node argsorts, Python feature loop) on
 fixed seeds.  The vectorized engine (:mod:`repro.core.forest_fit`) must keep
-node tables and predictions bitwise identical — these tests are the hard gate
-behind the ``benchmarks/bench_forest.py`` speedup claim.
+node tables and predictions bitwise identical — these tests are the engine's
+hard gate.
 
 Three layers of evidence:
 
@@ -125,7 +125,7 @@ def test_platform_shape_goldens(platform_name):
     """Campaign-trained forests on each platform's feature shape match the
     frozen pre-refactor node tables and prediction bytes."""
     from repro.api.campaign import train_layer_estimator
-    from repro.api.registry import get_platform
+    from repro.api import get_platform
     from repro.core import prs
 
     kwargs, layer_type, n_feat, tree_hex, pred_hex = GOLDEN_PLATFORM[platform_name]
@@ -212,6 +212,32 @@ def test_randomized_equality_vs_reference():
         ref = reference_fit(kw, X, y)
         f = RandomForestRegressor(**kw).fit(X, y)
         assert_forests_equal(ref, f._trees)
+
+
+@pytest.mark.parametrize("workload", ["snapped_conv1d", "dense_grid"])
+def test_campaign_shaped_fits_equal_reference(workload):
+    """Node-for-node equality on the two regimes a campaign fit meets: PR-
+    snapped UltraTrail features (low cardinality, many mid-size nodes) and a
+    dense high-cardinality 9-feature grid."""
+    if workload == "snapped_conv1d":
+        from repro.api import get_platform, train_layer_estimator
+        from repro.core import prs
+
+        platform = get_platform("ultratrail")
+        est = train_layer_estimator(
+            platform, "conv1d", 200, seed=0,
+            forest_kwargs={"n_estimators": 2, "max_depth": 6},
+        )
+        rng = np.random.default_rng(0)
+        configs = prs.sample_pr_batch(platform.param_space("conv1d"), est.widths, 600, rng)
+        y = np.log(platform.measure_batch("conv1d", configs))
+        X = est._features(configs, snap=True)
+    else:
+        rng = np.random.default_rng(0)
+        X = rng.integers(1, 512, size=(600, 9)).astype(np.float64)
+        y = np.log(X[:, 0] * X[:, 1] * X[:, 2] + X[:, 3] * 100 + 1.0)
+    kw = dict(n_estimators=4, max_depth=18, seed=0)
+    assert_forests_equal(reference_fit(kw, X, y), RandomForestRegressor(**kw).fit(X, y)._trees)
 
 
 def test_midpoint_rounds_onto_right_neighbour_with_larger_values():

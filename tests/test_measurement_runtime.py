@@ -295,6 +295,22 @@ class TestJournalResume:
                              tuple(record["params"]), tuple(row)))
         assert len(keys) == len(set(keys)) == control.cache.misses
 
+    def test_pool_journal_resume_measures_nothing_again(self, tmp_path):
+        """A campaign journaled by a 2-worker pool resumes serially from its
+        journal: every config is replayed and none is measured again."""
+        journal = str(tmp_path / "measurements.jsonl")
+        first = Campaign(_spec())
+        first_oracle = first.run(
+            runtime=RuntimeSpec(workers=2, chunk_size=16, journal_path=journal)
+        )
+        resumed = Campaign(_spec())
+        oracle = resumed.run(runtime=RuntimeSpec(workers=1, journal_path=journal))
+        assert resumed.cache.misses == 0
+        assert resumed.cache.replayed == first.cache.misses > 0
+        assert np.array_equal(
+            oracle.predict("toy", QUERIES), first_oracle.predict("toy", QUERIES)
+        )
+
     def test_replay_is_idempotent(self, tmp_path):
         journal = str(tmp_path / "j.jsonl")
         spec = _spec()

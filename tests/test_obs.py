@@ -346,6 +346,33 @@ class TestCampaignObservability:
             "cache.measure_batch", "fit.forest", "fit.tree",
         } <= names
 
+    def test_config_path_bitwise_identical_with_tracing_on_and_off(self, tmp_path):
+        """Sampling, cached measurement and features give the same bits with a
+        live tracer as without one, and the tracer writes events."""
+        from repro.api import CachedPlatform, get_platform
+        from repro.core import prs
+
+        campaign = Campaign(_spec())
+        campaign.run()
+        est = campaign.estimators["toy"]
+        space = get_platform("stepped_sim").param_space("toy")
+
+        def config_path():
+            batch = prs.sample_pr_batch(space, est.widths, 200, np.random.default_rng(0))
+            y = CachedPlatform(get_platform("stepped_sim")).measure_batch("toy", batch)
+            return batch, y, est._features(batch, snap=True)
+
+        quiet = config_path()
+        trace = str(tmp_path / "config_path.jsonl")
+        tracer = Tracer(trace)
+        with tracing(tracer):
+            traced = config_path()
+        tracer.close()
+        assert traced[0].to_dicts() == quiet[0].to_dicts()
+        assert np.array_equal(traced[1], quiet[1])
+        assert np.array_equal(traced[2], quiet[2])
+        assert tracer.events_written > 0 and load_events(trace)
+
     def test_fit_tree_histogram_counts_every_tree(self):
         Campaign(_spec()).run()
         snap = obs_metrics().snapshot()

@@ -559,6 +559,32 @@ class TestMetrics:
             assert field in ep
         assert stats["metrics"]["batches"] >= 1
 
+    def test_socket_stats_count_what_was_served(self, oracle):
+        """Over a real socket: ping, platforms, cold and cached predicts and a
+        network estimate equal the direct oracle, and ``stats`` counts them."""
+        cfgs = _configs(4)
+        network = _networks()[0]
+        expected = [float(v) for v in oracle.predict("toy", cfgs)]
+        expected_net = float(oracle.predict_network(network))
+        with _server(oracle) as server:
+            with OracleSocketServer(server, port=0).start() as sock:
+                with OracleClient(address=sock.address) as client:
+                    assert client.ping()
+                    assert PLATFORM in client.platforms()["loaded"]
+                    assert client.predict(PLATFORM, "toy", cfgs) == expected
+                    assert client.predict(PLATFORM, "toy", cfgs) == expected
+                    assert client.predict_network(PLATFORM, network) == expected_net
+                    stats = client.stats()
+        cache = stats["result_cache"]
+        endpoints = stats["metrics"]["endpoints"]
+        assert cache["hits"] >= len(cfgs) and cache["misses"] >= len(cfgs)
+        assert endpoints["predict"]["requests"] == 2
+        assert endpoints["predict"]["items"] == 2 * len(cfgs)
+        assert endpoints["predict"]["errors"] == 0
+        assert endpoints["predict"]["p99_ms"] is not None
+        assert endpoints["predict_networks"]["requests"] == 1
+        assert stats["uptime_s"] > 0.0
+
 
 # --------------------------------------------------------------- concurrency
 class TestConcurrentClients:
@@ -598,6 +624,29 @@ class TestConcurrentClients:
         # concurrency actually coalesced: fewer dispatches than requests
         assert snap["batches"] < n_threads * per_thread
         assert snap["mean_batch_size"] > 1.0
+
+    def test_max_batch_one_answers_each_request_alone_bitwise(self, oracle):
+        """With coalescing off, concurrent single-config requests each take
+        their own forest pass and get the direct oracle's bits."""
+        cfgs = _configs(32)
+        expected = [float(oracle.predict("toy", [c])[0]) for c in cfgs]
+        served: list = [None] * len(cfgs)
+        with _server(oracle, max_batch=1) as server:
+            client = OracleClient(server=server)
+
+            def worker(k):
+                for i in range(k, len(cfgs), 8):
+                    served[i] = client.predict_one(PLATFORM, "toy", cfgs[i])
+
+            threads = [threading.Thread(target=worker, args=(k,)) for k in range(8)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            snap = server.metrics.snapshot()
+        assert served == expected
+        assert snap["batches"] == len(cfgs)
+        assert snap["mean_batch_size"] == 1.0
 
     def test_concurrent_socket_clients(self, oracle):
         cfgs = _configs(5)
