@@ -19,8 +19,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Protocol, Sequence
 
+import numpy as np
+
 from repro.core.blocks import Block
-from repro.core.network import decompose, decompose_steps
+from repro.core.network import decompose, decompose_runs
 from repro.models.config import InputShape, ModelConfig
 from repro.obs.trace import span
 
@@ -74,12 +76,13 @@ def candidates_block_batch(
 ):
     """Columnar :func:`candidate_blocks` of every candidate in one
     :class:`BlockBatch`, built without materialising ``Block`` objects, and
-    the candidate index of each block."""
-    return decompose_steps(cfg, [
-        (dataclasses.replace(shape, global_batch=max(1, shape.global_batch // c.microbatches)),
-         c.dp, c.tp)
-        for c in cands
-    ])
+    the candidate index of each block: one evaluation of the plan over the
+    candidates' columns."""
+    micro = np.asarray([c.microbatches for c in cands], dtype=np.int64)
+    return decompose_runs(cfg, [(
+        shape.kind, shape.seq_len, np.maximum(1, shape.global_batch // micro),
+        [c.dp for c in cands], [c.tp for c in cands],
+    )])
 
 
 def estimate_candidate(

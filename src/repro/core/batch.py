@@ -195,83 +195,126 @@ class ConfigBatch:
         return self.take(first[order]), first[order], rank[inv]
 
 
+def _integer_values(x, params: tuple[str, ...]) -> np.ndarray:
+    """A config value (scalar or column) as int64, refusing what an int64
+    cannot hold exactly: the ``ValueError`` of :meth:`BlockBatch.from_blocks`."""
+    arr = np.asarray(x)
+    if not np.issubdtype(arr.dtype, np.number) or np.issubdtype(arr.dtype, np.complexfloating):
+        raise ValueError(f"non-numeric config value in layer params {params}")
+    cast = arr.astype(np.int64)
+    if not np.array_equal(cast, arr):
+        raise ValueError(f"non-integer config value in layer params {params}")
+    return cast
+
+
 class BlockBatchBuilder:
     """Incremental columnar constructor for :class:`BlockBatch`.
 
     The producer-side twin of :meth:`BlockBatch.from_blocks` for callers that
     never materialise ``Block`` objects (columnar-native ``decompose``):
-    ``add`` appends one block straight into the per-group columns, keyed on
-    the same ``(layer_type, insertion-order key tuple)`` group identity, so
-    ``build()`` is field-for-field identical to
-    ``BlockBatch.from_blocks(blocks)`` over the same walk (asserted in
-    tests/test_jax_predict.py).  Raises the same ``ValueError`` on
-    non-integer config values.
+    :meth:`add_steps` appends the blocks of many steps at once straight into
+    the per-group columns, keyed on the same ``(layer_type, insertion-order
+    key tuple)`` group identity in first-occurrence order, so ``build()`` is
+    field-for-field identical to ``BlockBatch.from_blocks(blocks)`` over the
+    same blocks (asserted in tests/test_jax_predict.py).  Raises the same
+    ``ValueError`` on non-integer config values.
     """
 
     def __init__(self) -> None:
+        self._n_blocks = 0
         self._kinds: list[str] = []
-        self._coll: list[float] = []
-        self._rep: list[float] = []
-        self._block_id: list[int] = []
-        self._group_of: list[int] = []
-        self._row_of: list[int] = []
+        self._coll: list[np.ndarray] = []
+        self._rep: list[np.ndarray] = []
+        self._block_id: list[np.ndarray] = []
+        self._group_of: list[np.ndarray] = []
+        self._row_of: list[np.ndarray] = []
         self._key_to_group: dict[tuple, int] = {}
         self._group_types: list[str] = []
         self._group_params: list[tuple[str, ...]] = []
-        self._group_rows: list[list[list]] = []
+        self._group_rows: list[int] = []
+        self._group_chunks: list[list[np.ndarray]] = []
 
-    def add(
+    def add_steps(
         self,
-        kind: str,
-        layers: Sequence[tuple[str, Config]],
-        collective_bytes: float = 0.0,
-        repeat: float = 1.0,
+        blocks: Sequence[tuple[str, Sequence[tuple[str, Mapping]], object, object]],
+        n: int = 1,
     ) -> None:
-        bid = len(self._kinds)
-        self._kinds.append(str(kind))
-        self._coll.append(float(collective_bytes))
-        self._rep.append(float(repeat))
-        for lt, cfg in layers:
-            key = (lt, tuple(cfg))
-            g = self._key_to_group.get(key)
-            if g is None:
-                g = len(self._group_types)
-                self._key_to_group[key] = g
-                self._group_types.append(lt)
-                self._group_params.append(key[1])
-                self._group_rows.append([])
-            rows = self._group_rows[g]
-            self._block_id.append(bid)
-            self._group_of.append(g)
-            self._row_of.append(len(rows))
-            rows.append(list(cfg.values()))
+        """Append ``n`` steps that each hold ``blocks``.
+
+        ``blocks`` are ``(kind, layers, collective_bytes, repeat)`` whose
+        numbers (config values, payload, repeat) are each a scalar that holds
+        for every step or an ``(n,)`` column with one entry a step.  Blocks
+        land step-major, every block of step 0 before those of step 1, as
+        ``n`` one-step adds would place them; so do each group's rows, an
+        ``(n, occurrences, params)`` stack.
+        """
+        k = len(blocks)
+        step = np.arange(n, dtype=np.int64)[:, None]
+        self._kinds.extend([str(kind) for kind, _, _, _ in blocks] * n)
+        for out, j in ((self._coll, 2), (self._rep, 3)):
+            cols = np.empty((n, k), dtype=np.float64)
+            for i, b in enumerate(blocks):
+                cols[:, i] = b[j]
+            out.append(cols.reshape(-1))
+        slot_block: list[int] = []
+        slot_group: list[int] = []
+        slot_rank: list[int] = []
+        slots: dict[int, list[Mapping]] = {}
+        for j, (_, layers, _, _) in enumerate(blocks):
+            for lt, cfg in layers:
+                key = (lt, tuple(cfg))
+                g = self._key_to_group.get(key)
+                if g is None:
+                    g = len(self._group_types)
+                    self._key_to_group[key] = g
+                    self._group_types.append(lt)
+                    self._group_params.append(key[1])
+                    self._group_rows.append(0)
+                    self._group_chunks.append([])
+                rows = slots.setdefault(g, [])
+                slot_block.append(j)
+                slot_group.append(g)
+                slot_rank.append(len(rows))
+                rows.append(cfg)
+        first_row = np.empty(len(self._group_types), dtype=np.int64)
+        occurrences = np.empty(len(self._group_types), dtype=np.int64)
+        for g, cfgs in slots.items():
+            m = len(cfgs)
+            params = self._group_params[g]
+            stack = np.empty((n, m, len(params)), dtype=np.int64)
+            for r, cfg in enumerate(cfgs):
+                for q, x in enumerate(cfg.values()):
+                    if not (type(x) is int or isinstance(x, np.ndarray) and x.dtype == np.int64):
+                        x = _integer_values(x, params)
+                    stack[:, r, q] = x
+            first_row[g] = self._group_rows[g]
+            occurrences[g] = m
+            self._group_chunks[g].append(stack.reshape(n * m, stack.shape[2]))
+            self._group_rows[g] += n * m
+        g = np.asarray(slot_group, dtype=np.int64)
+        self._block_id.append((self._n_blocks + step * k + np.asarray(slot_block)).reshape(-1))
+        self._group_of.append(np.broadcast_to(g, (n, len(g))).reshape(-1))
+        self._row_of.append(
+            (first_row[g] + step * occurrences[g] + np.asarray(slot_rank)).reshape(-1)
+        )
+        self._n_blocks += n * k
 
     def build(self) -> "BlockBatch":
-        configs = []
-        for params, rows in zip(self._group_params, self._group_rows):
-            arr = np.asarray(rows)
-            if not np.issubdtype(arr.dtype, np.number):
-                raise ValueError(f"non-numeric config value in layer params {params}")
-            if not np.issubdtype(arr.dtype, np.integer):
-                cast = arr.astype(np.int64)
-                if not np.array_equal(cast, arr):
-                    raise ValueError(
-                        f"non-integer config value in layer params {params}"
-                    )
-                arr = cast
-            configs.append(
-                ConfigBatch(
-                    params=params,
-                    values=arr.astype(np.int64).reshape(len(rows), len(params)),
-                )
-            )
+        configs = [
+            ConfigBatch(params=params, values=np.concatenate(chunks) if len(chunks) > 1 else chunks[0])
+            for params, chunks in zip(self._group_params, self._group_chunks)
+        ]
+
+        def column(parts: list[np.ndarray], dtype) -> np.ndarray:
+            return np.concatenate(parts).astype(dtype, copy=False) if parts else np.empty(0, dtype)
+
         return BlockBatch(
             kinds=tuple(self._kinds),
-            collective_bytes=np.asarray(self._coll, dtype=np.float64),
-            repeat=np.asarray(self._rep, dtype=np.float64),
-            block_id=np.asarray(self._block_id, dtype=np.int64),
-            group_of=np.asarray(self._group_of, dtype=np.int64),
-            row_of=np.asarray(self._row_of, dtype=np.int64),
+            collective_bytes=column(self._coll, np.float64),
+            repeat=column(self._rep, np.float64),
+            block_id=column(self._block_id, np.int64),
+            group_of=column(self._group_of, np.int64),
+            row_of=column(self._row_of, np.int64),
             group_types=tuple(self._group_types),
             group_configs=tuple(configs),
         )
@@ -307,7 +350,7 @@ class BlockBatch:
     group_configs: tuple[ConfigBatch, ...]
 
     def __post_init__(self) -> None:
-        object.__setattr__(self, "kinds", tuple(str(k) for k in self.kinds))
+        object.__setattr__(self, "kinds", tuple(map(str, self.kinds)))
         object.__setattr__(self, "group_types", tuple(self.group_types))
         object.__setattr__(self, "group_configs", tuple(self.group_configs))
         coll = np.asarray(self.collective_bytes, dtype=np.float64)

@@ -17,60 +17,61 @@ factors, and every block carries its collective payload so the Eq.-9 max rule
 
 from __future__ import annotations
 
-import math
+import itertools
 from typing import Sequence
 
 import numpy as np
 
 from repro.core.blocks import Block
-from repro.core.prs import Config
 from repro.models.config import InputShape, ModelConfig
-
-
-def _head_policy(cfg: ModelConfig, tp: int) -> str:
-    if tp == 1 or cfg.n_kv_heads % tp == 0:
-        return "kv_sharded"
-    if cfg.n_heads % tp == 0:
-        return "q_sharded"
-    return "replicated"
+from repro.obs.metrics import metrics as obs_metrics
 
 
 def _decompose_plan(
     cfg: ModelConfig,
-    shape: InputShape,
-    dp: int,
-    tp: int,
+    kind: str,
+    seq_len: np.ndarray,
+    global_batch: np.ndarray,
+    dp: np.ndarray,
+    tp: np.ndarray,
     train_factor: float = 3.0,
 ):
-    """Yield ``(kind, layers, collective_bytes, repeat)`` for one step's blocks.
+    """Yield ``(kind, layers, collective_bytes, repeat)`` for the blocks of
+    ``n`` steps of one shape kind at once.
 
-    The single source of truth behind both :func:`decompose` (materialises
-    :class:`Block` objects) and :func:`decompose_batch` (streams straight into
-    a columnar :class:`~repro.core.batch.BlockBatch`), so the two can never
-    drift: same blocks, same order, same fields.
+    ``seq_len``, ``global_batch``, ``dp`` and ``tp`` are ``(n,)`` int64
+    columns, one entry a step.  Every yielded value is either a scalar that
+    holds for all ``n`` steps or an ``(n,)`` column; the block and layer
+    order and the key order of each layer's config depend only on the model
+    and on ``kind``.  The single source of truth behind :func:`decompose`
+    (one step, materialised as :class:`Block` objects) and
+    :func:`decompose_steps` (many steps in one columnar
+    :class:`~repro.core.batch.BlockBatch`), so the two can never drift.
     """
-    is_train = shape.kind == "train"
-    is_decode = shape.kind == "decode"
+    is_train = kind == "train"
+    is_decode = kind == "decode"
     rep = train_factor if is_train else 1.0
-    b_loc = max(1, shape.global_batch // dp)
-    s = 1 if is_decode else shape.seq_len
+    b_loc = np.maximum(1, global_batch // dp)
+    s = 1 if is_decode else seq_len
     t_loc = b_loc * s
     d, f, v = cfg.d_model, cfg.d_ff, cfg.vocab
     hd = cfg.head_dim
-    policy = _head_policy(cfg, tp)
-    h_loc = cfg.n_heads // tp if policy in ("kv_sharded", "q_sharded") else cfg.n_heads
-    kv_loc = cfg.n_kv_heads // tp if policy == "kv_sharded" else cfg.n_kv_heads
-    kv_ratio = max(1, h_loc // max(1, kv_loc))
+    # head policy: kv heads sharded where tp divides them, else the query
+    # heads where it divides them, else both replicated
+    kv_sharded = cfg.n_kv_heads % tp == 0
+    h_loc = np.where(kv_sharded | (cfg.n_heads % tp == 0), cfg.n_heads // tp, cfg.n_heads)
+    kv_loc = np.where(kv_sharded, cfg.n_kv_heads // tp, cfg.n_kv_heads)
+    kv_ratio = np.maximum(1, h_loc // np.maximum(1, kv_loc))
 
     coll_act = t_loc * d * 2.0  # one bf16 activation all-reduce payload
 
     def attn_block() -> tuple:
-        layers: list[tuple[str, Config]] = [
+        layers: list[tuple[str, dict]] = [
             ("dense", {"tokens": t_loc, "d_in": d, "d_out": (h_loc + 2 * kv_loc) * hd}),
         ]
         if is_decode:
             layers.append(
-                ("attention_decode", {"B": b_loc, "S_kv": shape.seq_len, "H": h_loc, "Dh": hd, "kv_ratio": kv_ratio})
+                ("attention_decode", {"B": b_loc, "S_kv": seq_len, "H": h_loc, "Dh": hd, "kv_ratio": kv_ratio})
             )
         else:
             layers.append(
@@ -80,7 +81,7 @@ def _decompose_plan(
         return ("attn", tuple(layers), coll_act)
 
     def mlp_layers(width: int) -> list:
-        f_loc = max(1, width // tp)
+        f_loc = np.maximum(1, width // tp)
         n_in = 2 if cfg.mlp == "swiglu" else 1
         layers = [("dense", {"tokens": t_loc, "d_in": d, "d_out": f_loc})] * n_in
         layers.append(("dense", {"tokens": t_loc, "d_in": f_loc, "d_out": d}))
@@ -90,12 +91,11 @@ def _decompose_plan(
         return ("mlp", tuple(mlp_layers(f)), coll_act)
 
     def moe_block() -> tuple:
-        e_loc = max(1, cfg.moe_experts // tp)
         experts = {
-            "tokens": max(1, t_loc // tp),
+            "tokens": np.maximum(1, t_loc // tp),
             "d_model": d,
             "d_ff": f,
-            "E": e_loc,
+            "E": np.maximum(1, cfg.moe_experts // tp),
             "topk": cfg.moe_top_k,
         }
         if cfg.mlp_mats != 3:  # the platform's default is a gated expert
@@ -109,8 +109,8 @@ def _decompose_plan(
         return ("moe", tuple(layers), 2 * coll_act)
 
     def ssd_block() -> tuple:
-        di_loc = max(1, cfg.d_inner // tp)
-        h_ssm = max(1, cfg.ssm_heads // tp)
+        di_loc = np.maximum(1, cfg.d_inner // tp)
+        h_ssm = np.maximum(1, cfg.ssm_heads // tp)
         p, n = cfg.ssm_headdim, cfg.ssm_state
         # decode advances each sequence's state by one token; it scans no chunk
         if is_decode:
@@ -119,7 +119,7 @@ def _decompose_plan(
             mixer = {"B": b_loc, "S": s, "H": h_ssm, "P": p, "N": n}
         if cfg.layer_pattern:
             # z, x, B, C and dt column-sharded: this chip's share of each
-            g_loc = max(1, cfg.ssm_groups // tp)
+            g_loc = np.maximum(1, cfg.ssm_groups // tp)
             d_proj = 2 * di_loc + 2 * g_loc * n + h_ssm
             mixer["G"] = g_loc
         else:
@@ -141,8 +141,8 @@ def _decompose_plan(
     # ---- body ----
     if cfg.layer_pattern:
         blocks = {"M": ssd_block, "E": moe_block, "*": attn_block}
-        for kind, n in cfg.layer_counts().items():
-            yield body(blocks[kind](), n)
+        for layer_kind, n in cfg.layer_counts().items():
+            yield body(blocks[layer_kind](), n)
     elif cfg.family in ("dense", "vlm"):
         yield body(attn_block(), cfg.n_layers)
         yield body(mlp_block(), cfg.n_layers)
@@ -159,6 +159,7 @@ def _decompose_plan(
     elif cfg.family == "audio":
         if not is_decode:
             enc_t = b_loc * cfg.encoder_seq
+            f_loc = np.maximum(1, f // tp)
             enc_attn = (
                 "attn",
                 (
@@ -171,8 +172,8 @@ def _decompose_plan(
             enc_mlp = (
                 "mlp",
                 (
-                    ("dense", {"tokens": enc_t, "d_in": d, "d_out": max(1, f // tp)}),
-                    ("dense", {"tokens": enc_t, "d_in": max(1, f // tp), "d_out": d}),
+                    ("dense", {"tokens": enc_t, "d_in": d, "d_out": f_loc}),
+                    ("dense", {"tokens": enc_t, "d_in": f_loc, "d_out": d}),
                 ),
                 enc_t * d * 2.0,
             )
@@ -200,10 +201,34 @@ def _decompose_plan(
     # ---- LM head ----
     yield (
         "mlp",
-        (("dense", {"tokens": t_loc, "d_in": d, "d_out": max(1, v // tp)}),),
+        (("dense", {"tokens": t_loc, "d_in": d, "d_out": np.maximum(1, v // tp)}),),
         0.0,
         rep,
     )
+
+
+def _plan(cfg, kind, seq_len, global_batch, dp, tp, train_factor) -> tuple[int, list]:
+    """One evaluation of the plan over the steps of one kind (each column
+    an int64 column or a scalar that holds for every step): the number of
+    steps and the blocks of each step."""
+    columns = [np.asarray(c, dtype=np.int64) for c in (seq_len, global_batch, dp, tp)]
+    n = max(c.size for c in columns)
+    if n == 0:
+        return 0, []
+    seq_len, global_batch, dp, tp = (
+        c.reshape(n) if c.size == n else np.full(n, c) for c in columns
+    )
+    reg = obs_metrics()
+    reg.inc("network.decompose.passes")
+    reg.inc("network.decompose.steps", n)
+    # the module global, looked up at each call: whatever replaces
+    # ``_decompose_plan`` reaches every form of the decomposition
+    return n, list(_decompose_plan(cfg, kind, seq_len, global_batch, dp, tp, train_factor))
+
+
+def _item(value):
+    """A plan value of a one-step plan as a plain Python number."""
+    return value[0].item() if isinstance(value, np.ndarray) else value
 
 
 def decompose(
@@ -213,10 +238,19 @@ def decompose(
     tp: int,
     train_factor: float = 3.0,
 ) -> list[Block]:
-    """Per-device building blocks of one step.  train_factor ~ (fwd+bwd)/fwd."""
+    """Per-device building blocks of one step.  train_factor ~ (fwd+bwd)/fwd.
+
+    The one-step case of the plan, its values turned back into plain Python
+    ``int`` and ``float``."""
+    _, plan = _plan(cfg, shape.kind, shape.seq_len, shape.global_batch, dp, tp, train_factor)
     return [
-        Block(kind=kind, layers=layers, collective_bytes=coll, repeat=repeat)
-        for kind, layers, coll, repeat in _decompose_plan(cfg, shape, dp, tp, train_factor)
+        Block(
+            kind=kind,
+            layers=tuple((lt, {p: _item(x) for p, x in c.items()}) for lt, c in layers),
+            collective_bytes=_item(coll),
+            repeat=_item(repeat),
+        )
+        for kind, layers, coll, repeat in plan
     ]
 
 
@@ -243,19 +277,34 @@ def decompose_steps(
     """Many ``(shape, dp, tp)`` steps of one model in one
     :class:`~repro.core.batch.BlockBatch`, and the step index of each block.
 
-    Field-for-field identical to ``BlockBatch.concat`` of each step's
-    :func:`decompose_batch`: groups merge by first occurrence either way,
-    but one builder validates and stacks the table once, not once a step.
+    The plan is evaluated once for each run of consecutive steps of one
+    ``shape.kind``, over columns of their sizes and meshes.  Field-for-field
+    identical to ``BlockBatch.concat`` of each step's
+    :func:`decompose_batch`: groups merge by first occurrence either way.
     """
+    runs = []
+    for kind, run in itertools.groupby(steps, key=lambda step: step[0].kind):
+        shapes, dp, tp = zip(*run)
+        runs.append((kind, [s.seq_len for s in shapes], [s.global_batch for s in shapes], dp, tp))
+    return decompose_runs(cfg, runs, train_factor)
+
+
+def decompose_runs(cfg: ModelConfig, runs, train_factor: float = 3.0):
+    """:func:`decompose_steps` over runs given as columns:
+    ``(kind, seq_len, global_batch, dp, tp)``, each column per step of the
+    run or a scalar for all of them.  Steps are numbered across runs."""
     from repro.core.batch import BlockBatchBuilder
 
     builder = BlockBatchBuilder()
-    step_of: list[int] = []
-    for k, (shape, dp, tp) in enumerate(steps):
-        for kind, layers, coll, repeat in _decompose_plan(cfg, shape, dp, tp, train_factor):
-            builder.add(kind, layers, collective_bytes=coll, repeat=repeat)
-            step_of.append(k)
-    return builder.build(), np.asarray(step_of, dtype=np.int64)
+    step_of = [np.empty(0, dtype=np.int64)]
+    first = 0
+    for kind, *columns in runs:
+        n, blocks = _plan(cfg, kind, *columns, train_factor)
+        if n:
+            builder.add_steps(blocks, n)
+        step_of.append(np.repeat(np.arange(first, first + n, dtype=np.int64), len(blocks)))
+        first += n
+    return builder.build(), np.concatenate(step_of)
 
 
 def simulate_network(platform, blocks: Sequence[Block]) -> float:

@@ -28,6 +28,9 @@ registry; every one is best-effort and zero-cost when nothing increments it):
 * ``journal.torn_tails_sealed`` — torn write fragments sealed before append
 * ``serve.overload`` / ``serve.deadline_exceeded`` — requests answered with
   explicit backpressure / deadline errors (never silent drops)
+* ``network.decompose.passes`` / ``network.decompose.steps`` — evaluations
+  of the decomposition plan and the steps they covered (one pass a run of
+  steps of one shape kind: a whole mesh search, or one ``decompose``)
 
 Typical use::
 

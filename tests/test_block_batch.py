@@ -32,7 +32,7 @@ from repro.api import (
     RuntimeSpec,
 )
 from repro.core.advisor import autotune, default_candidates, estimate_candidate
-from repro.core.batch import ConfigBatch
+from repro.core.batch import BlockBatchBuilder, ConfigBatch
 from repro.core.blocks import (
     Block,
     block_ops,
@@ -366,6 +366,18 @@ class TestBlockBatchStructure:
         bad = Block(kind="x", layers=(("dense", {"tokens": 7.5, "d_in": 8, "d_out": 8}),))
         with pytest.raises(ValueError):
             BlockBatch.from_blocks([bad])
+
+    @pytest.mark.parametrize("tokens", [7.5, np.array([8.0, 7.5]), "x"],
+                             ids=["scalar", "column", "text"])
+    def test_builder_rejects_what_from_blocks_rejects(self, tokens):
+        builder = BlockBatchBuilder()
+        with pytest.raises(ValueError):
+            builder.add_steps([("x", (("dense", {"tokens": tokens, "d_in": 8}),), 0.0, 1.0)], 2)
+            builder.build()
+        builder = BlockBatchBuilder()  # whole floats are taken, as from_blocks takes them
+        builder.add_steps([("x", (("dense", {"tokens": np.array([8.0, 16.0]), "d_in": 8}),),
+                            0.0, 1.0)], 2)
+        assert builder.build().group_configs[0].values.tolist() == [[8, 8], [16, 8]]
 
     def test_empty(self):
         batch = BlockBatch.from_blocks([])

@@ -3,6 +3,7 @@ decomposition into blocks, the one-token Mamba-2 decode and the expert
 matrices on the TPU v5e platform, with the older decompositions and the
 platform's older times frozen as they were before patterns existed."""
 
+import dataclasses as dc
 import hashlib
 
 import numpy as np
@@ -10,11 +11,13 @@ import pytest
 
 from repro.accelerators import TPUv5eSim
 from repro.configs import ARCHS, ESTIMATED, get_config
+from repro.core.advisor import autotune, candidates_block_batch, default_candidates
 from repro.core.blocks import op_count
 from repro.core.features import derived_features
 from repro.core.batch import BlockBatch
 from repro.core.network import decompose, decompose_batch, decompose_steps
 from repro.models.config import SHAPES, InputShape, ModelConfig, reduced
+from repro.obs import metrics as obs_metrics
 
 NEMOTRON = "nemotron-3-nano-30b-a3b"
 TRAIN = InputShape("train_8k", 8192, 512, "train")
@@ -155,21 +158,92 @@ def test_attention_keeps_gqa_ratio_under_the_head_policy():
     assert attn.layers[0][1]["d_out"] == (32 + 2 * 2) * 128
 
 
-@pytest.mark.parametrize("arch", [NEMOTRON, "olmoe-1b-7b", "zamba2-2.7b"])
+def _step_lists():
+    """A list whose kinds alternate (a run a step), a list of two runs, and
+    each search's full candidate list: the micro-step of every
+    ``default_candidates`` layout of 64, 256 and 1024 chips."""
+    meshes = ((64, 1), (16, 4), (1, 64), (64, 1))
+    yield "alternating", [(shape, dp, tp) for dp, tp in meshes for shape in (TRAIN, DECODE)]
+    yield "two runs", [(shape, dp, tp) for shape in (TRAIN, DECODE) for dp, tp in meshes]
+    for chips in (64, 256, 1024):
+        for shape in (TRAIN, DECODE):
+            yield f"search {shape.name} {chips}", (shape, default_candidates(chips))
+
+
+def _micro_steps(shape, cands):
+    return [(dc.replace(shape, global_batch=max(1, shape.global_batch // c.microbatches)),
+             c.dp, c.tp) for c in cands]
+
+
+@pytest.mark.parametrize("arch", ARCHS + ESTIMATED)
 def test_many_steps_in_one_batch_equal_the_concatenated_steps(arch):
     cfg = get_config(arch)
-    steps = [(shape, dp, tp) for shape in (TRAIN, DECODE)
-             for dp, tp in ((64, 1), (16, 4), (1, 64), (64, 1))]
-    got, step_of = decompose_steps(cfg, steps)
-    parts = [decompose_batch(cfg, *s) for s in steps]
-    ref = BlockBatch.concat(parts)
-    assert np.array_equal(step_of, np.repeat(np.arange(len(parts)), [len(p) for p in parts]))
-    assert got.kinds == ref.kinds and got.group_types == ref.group_types
-    for a in ("collective_bytes", "repeat", "block_id", "group_of", "row_of"):
-        assert np.array_equal(getattr(got, a), getattr(ref, a)), a
-    for a, b in zip(got.group_configs, ref.group_configs):
-        assert a.params == b.params and np.array_equal(a.values, b.values)
-    assert got.fingerprints() == ref.fingerprints()
+    for name, steps in _step_lists():
+        if name.startswith("search"):
+            # the search's own call, and the same micro-steps given as steps
+            got, step_of = candidates_block_batch(cfg, *steps)
+            steps = _micro_steps(*steps)
+            again, again_of = decompose_steps(cfg, steps)
+            assert again.fingerprints() == got.fingerprints(), name
+            assert np.array_equal(again_of, step_of), name
+        else:
+            got, step_of = decompose_steps(cfg, steps)
+        parts = [decompose_batch(cfg, *s) for s in steps]
+        ref = BlockBatch.concat(parts)
+        assert np.array_equal(step_of, np.repeat(np.arange(len(parts)), [len(p) for p in parts]))
+        assert step_of.dtype == np.int64, name
+        assert got.kinds == ref.kinds and got.group_types == ref.group_types, name
+        for a in ("collective_bytes", "repeat", "block_id", "group_of", "row_of"):
+            assert np.array_equal(getattr(got, a), getattr(ref, a)), (name, a)
+        for a, b in zip(got.group_configs, ref.group_configs):
+            assert a.params == b.params and np.array_equal(a.values, b.values), name
+        assert got.fingerprints() == ref.fingerprints(), name
+
+
+class _Counted:
+    """Reads the decomposition's counters around a block of code."""
+
+    def __enter__(self):
+        self.start = self._read()
+        return self
+
+    def __exit__(self, *exc):
+        end = self._read()
+        self.passes, self.steps = (e - s for e, s in zip(end, self.start))
+
+    @staticmethod
+    def _read():
+        counters = obs_metrics().snapshot()["counters"]
+        return (counters.get("network.decompose.passes", 0),
+                counters.get("network.decompose.steps", 0))
+
+
+class _FlatOracle:
+    """Scores every network 1 in one batched call, as the jax path batches."""
+
+    def predict_network_batch(self, batch, net_id, n_nets):
+        return np.ones(n_nets)
+
+
+@pytest.mark.parametrize("arch", [NEMOTRON, "olmoe-1b-7b"])
+def test_one_search_evaluates_the_plan_once(arch):
+    cfg = get_config(arch)
+    with _Counted() as counted:
+        ranking = autotune(_FlatOracle(), cfg, TRAIN, default_candidates(256))
+    chosen = sum(1 for _, score in ranking if np.isfinite(score))
+    assert chosen > 20
+    assert (counted.passes, counted.steps) == (1, chosen)
+
+
+def test_each_run_of_one_kind_is_one_pass():
+    cfg = get_config(NEMOTRON)
+    kinds = (TRAIN, TRAIN, DECODE, DECODE, DECODE, TRAIN, DECODE)
+    with _Counted() as counted:
+        decompose_steps(cfg, [(shape, 16, 4) for shape in kinds])
+    assert (counted.passes, counted.steps) == (4, len(kinds))
+    with _Counted() as counted:
+        decompose(cfg, DECODE, 16, 4)
+    assert (counted.passes, counted.steps) == (1, 1)
 
 
 # ------------------------------------------------------------------ platform
