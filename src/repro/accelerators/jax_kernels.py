@@ -26,6 +26,7 @@ import functools
 
 import numpy as np
 
+from repro.core.features import mla_dims
 from repro.core.jax_predict import bucket_rows, jax_modules, resolve_backend, x64
 
 
@@ -117,6 +118,28 @@ def _tpu_fn(layer_type: str, mxu: int, sublane: int, kv_page: int, ssd_chunk: in
         def terms(cols, kv):
             t, dm = cols["tokens"], cols["d_model"]
             return jnp.zeros(t.shape, dtype=jnp.float64), 2.0 * t * dm * 2 + 4.0 * t
+
+    elif layer_type == "mla_prefill":
+
+        def terms(cols, kv):
+            b, h, s = cols["B"], cols["H"], pad(cols["S"], mxu)
+            nope, rope, dv, _ = mla_dims(cols.get)
+            dqk, dv = pad(nope + rope, mxu), pad(dv, mxu)
+            flops = 1.0 * b * h * s * s * (dqk + dv)
+            bytes_ = 2.0 * b * h * s * (2 * dqk + 2 * dv)
+            return flops, bytes_
+
+    elif layer_type == "mla_decode":
+
+        def terms(cols, kv):
+            b, h = cols["B"], cols["H"]
+            nope, rope, dv, lora = mla_dims(cols.get)
+            hp = pad(h, sublane)
+            s = pad(cols["S_kv"], kv_page)
+            flops = (2.0 * b * hp * s * (lora + rope) + 2.0 * b * hp * s * lora
+                     + 2.0 * b * hp * lora * (nope + dv))
+            bytes_ = 2.0 * (b * s * (lora + rope) + h * lora * (nope + dv) + b * h * (nope + rope + dv))
+            return flops, bytes_
 
     else:
         raise KeyError(layer_type)

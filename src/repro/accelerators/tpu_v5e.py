@@ -10,6 +10,10 @@ hierarchy:
   * Mamba2 SSD runs in 128-token chunks; a decode step advances each
     sequence's (H, P, N) state by one token instead, reading and writing the
     whole state once (memory bound), with (P, N) in (8, 128) tiles;
+  * latent attention (MLA) prefills unabsorbed, a head's QK and V dims each
+    padded to 128; its absorbed decode makes a sequence's heads the rows of
+    one matmul against its cached latent (heads pad to 8), so the cache is
+    read once whatever the head count;
   * MoE expert GEMMs pad tokens-per-expert to 8 -> the *token* step width of an
     (E, top-k) MoE layer is E*8/topk, a step width that is only discoverable by
     sweeps (gray/black-box) unless the mapping is documented (white-box).
@@ -38,6 +42,7 @@ import numpy as np
 from repro.accelerators.base import Platform
 from repro.registry import register_platform
 from repro.core.batch import BlockBatch, ConfigBatch
+from repro.core.features import MLA_FIXED, mla_dims
 from repro.core.prs import Config, ParamSpace
 
 
@@ -126,6 +131,8 @@ class TPUv5eSim(Platform):
             "ssd_scan",
             "ssd_decode",
             "embed",
+            "mla_prefill",
+            "mla_decode",
         )
 
     def param_space(self, layer_type: str) -> ParamSpace:
@@ -157,6 +164,12 @@ class TPUv5eSim(Platform):
             return ParamSpace(ranges={"B": (1, 256), "H": (1, 128), "P": (32, 256), "N": (16, 256)})
         if layer_type == "embed":
             return ParamSpace(ranges={"tokens": (8, 131072), "vocab": (1024, 262144), "d_model": (128, 8192)})
+        if layer_type == "mla_prefill":
+            return ParamSpace(ranges={"B": (1, 64), "S": (128, 32768), "H": (1, 128)},
+                              fixed={k: MLA_FIXED[k] for k in ("nope", "rope", "dv")})
+        if layer_type == "mla_decode":
+            return ParamSpace(ranges={"B": (1, 256), "S_kv": (128, 163840), "H": (1, 128)},
+                              fixed=dict(MLA_FIXED))
         raise KeyError(layer_type)
 
     def defaults(self, layer_type: str) -> Config:
@@ -171,6 +184,8 @@ class TPUv5eSim(Platform):
             "ssd_scan": {"B": 8, "S": 2048, "H": 48, "P": 64, "N": 64},
             "ssd_decode": {"B": 32, "H": 48, "P": 64, "N": 128},
             "embed": {"tokens": 8192, "vocab": 32000, "d_model": 2048},
+            "mla_prefill": {"B": 4, "S": 4096, "H": 32, "nope": 128, "rope": 64, "dv": 128},
+            "mla_decode": {"B": 32, "S_kv": 4096, "H": 32, **MLA_FIXED},
         }[layer_type]
 
     def known_step_widths(self, layer_type: str) -> dict[str, int] | None:
@@ -187,6 +202,8 @@ class TPUv5eSim(Platform):
             "ssd_scan": {"B": 1, "S": c.ssd_chunk, "H": c.sublane, "P": c.mxu, "N": c.mxu},
             "ssd_decode": {"B": 1, "H": 1, "P": c.sublane, "N": c.mxu},
             "embed": {"tokens": 1, "vocab": 1, "d_model": 1},
+            "mla_prefill": {"B": 1, "S": c.mxu, "H": 1},
+            "mla_decode": {"B": 1, "S_kv": c.kv_page, "H": c.sublane},
         }
         if self.knowledge == "white":
             return white[layer_type]
@@ -251,6 +268,24 @@ class TPUv5eSim(Platform):
             t, dm = cfg["tokens"], cfg["d_model"]
             flops = 0.0
             bytes_ = 2.0 * t * dm * 2 + 4.0 * t  # gather read+write, int32 ids
+        elif layer_type == "mla_prefill":
+            b, h, s = cfg["B"], cfg["H"], _pad(cfg["S"], c.mxu)
+            nope, rope, dv, _ = mla_dims(cfg.get)
+            dqk, dv = _pad(nope + rope, c.mxu), _pad(dv, c.mxu)
+            # causal: QK^T at dqk and PV at dv, half the square each
+            flops = 1.0 * b * h * s * s * (dqk + dv)
+            bytes_ = 2.0 * b * h * s * (2 * dqk + 2 * dv)  # q, k, v, o
+        elif layer_type == "mla_decode":
+            b, h = cfg["B"], cfg["H"]
+            nope, rope, dv, lora = mla_dims(cfg.get)
+            hp = _pad(h, c.sublane)
+            s = _pad(cfg["S_kv"], c.kv_page)
+            # scores against the latent and its rope key, the weighted sum of
+            # latents, and q_nope.W_UK^T and .W_UV for each head
+            flops = (2.0 * b * hp * s * (lora + rope) + 2.0 * b * hp * s * lora
+                     + 2.0 * b * hp * lora * (nope + dv))
+            # the cache once for all heads, W_UK and W_UV, q in and o out
+            bytes_ = 2.0 * (b * s * (lora + rope) + h * lora * (nope + dv) + b * h * (nope + rope + dv))
         else:
             raise KeyError(layer_type)
         return flops / c.peak_bf16_flops, bytes_ / c.hbm_bandwidth
@@ -323,6 +358,20 @@ class TPUv5eSim(Platform):
             t, dm = col("tokens"), col("d_model")
             flops = np.zeros(len(batch), dtype=np.float64)
             bytes_ = 2.0 * t * dm * 2 + 4.0 * t
+        elif layer_type == "mla_prefill":
+            b, h, s = col("B"), col("H"), _pad_arr(col("S"), c.mxu)
+            nope, rope, dv, _ = mla_dims(get)
+            dqk, dv = _pad_arr(nope + rope, c.mxu), _pad_arr(dv, c.mxu)
+            flops = 1.0 * b * h * s * s * (dqk + dv)
+            bytes_ = 2.0 * b * h * s * (2 * dqk + 2 * dv)
+        elif layer_type == "mla_decode":
+            b, h = col("B"), col("H")
+            nope, rope, dv, lora = mla_dims(get)
+            hp = _pad_arr(h, c.sublane)
+            s = _pad_arr(col("S_kv"), c.kv_page)
+            flops = (2.0 * b * hp * s * (lora + rope) + 2.0 * b * hp * s * lora
+                     + 2.0 * b * hp * lora * (nope + dv))
+            bytes_ = 2.0 * (b * s * (lora + rope) + h * lora * (nope + dv) + b * h * (nope + rope + dv))
         else:
             raise KeyError(layer_type)
         return flops / c.peak_bf16_flops, bytes_ / c.hbm_bandwidth

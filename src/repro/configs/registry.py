@@ -20,7 +20,7 @@ ARCHS: tuple[str, ...] = (
 )
 
 #: decomposed, scored and ranked by the oracle; no forward pass in the zoo
-ESTIMATED: tuple[str, ...] = ("nemotron-3-nano-30b-a3b",)
+ESTIMATED: tuple[str, ...] = ("nemotron-3-nano-30b-a3b", "deepseek-v3")
 
 _MODULES = {
     "zamba2-2.7b": "zamba2_2p7b",
@@ -34,6 +34,7 @@ _MODULES = {
     "whisper-medium": "whisper_medium",
     "mamba2-780m": "mamba2_780m",
     "nemotron-3-nano-30b-a3b": "nemotron3_nano_30b_a3b",
+    "deepseek-v3": "deepseek_v3",
 }
 
 

@@ -24,6 +24,7 @@ import numpy as np
 from repro.accelerators.base import Platform
 from repro.core.batch import BlockBatch
 from repro.core.estimator import LayerEstimator
+from repro.core.features import mla_dims
 from repro.core.prs import Config
 
 Layer = tuple[str, Config]
@@ -49,6 +50,13 @@ def op_count(layer_type: str, cfg: Config) -> float:
         return 2.0 * cfg["B"] * cfg["H"] * cfg["S"] ** 2 * cfg["Dh"]
     if layer_type == "attention_decode":
         return 4.0 * cfg["B"] * cfg["H"] * cfg["S_kv"] * cfg["Dh"]
+    if layer_type == "mla_prefill":
+        nope, rope, dv, _ = mla_dims(cfg.get)
+        return 1.0 * cfg["B"] * cfg["H"] * cfg["S"] ** 2 * (nope + rope + dv)  # causal
+    if layer_type == "mla_decode":
+        nope, rope, dv, lora = mla_dims(cfg.get)
+        return 2.0 * cfg["B"] * cfg["H"] * (
+            cfg["S_kv"] * (lora + rope) + cfg["S_kv"] * lora + lora * (nope + dv))
     if layer_type == "moe_gemm":
         mats = cfg.get("mats", 3)  # matrices of one expert: 3 gated, 2 not
         return 2.0 * mats * cfg["tokens"] * cfg["topk"] * cfg["d_model"] * cfg["d_ff"]
@@ -89,6 +97,13 @@ def op_count_batch(layer_type: str, batch) -> np.ndarray:
         return 2.0 * col("B") * col("H") * col("S") ** 2 * col("Dh")
     if layer_type == "attention_decode":
         return 4.0 * col("B") * col("H") * col("S_kv") * col("Dh")
+    if layer_type == "mla_prefill":
+        nope, rope, dv, _ = mla_dims(get)
+        return 1.0 * col("B") * col("H") * col("S") ** 2 * (nope + rope + dv)
+    if layer_type == "mla_decode":
+        nope, rope, dv, lora = mla_dims(get)
+        return 2.0 * col("B") * col("H") * (
+            col("S_kv") * (lora + rope) + col("S_kv") * lora + lora * (nope + dv))
     if layer_type == "moe_gemm":
         mats = get("mats", 3)
         return 2.0 * mats * col("tokens") * col("topk") * col("d_model") * col("d_ff")

@@ -16,6 +16,17 @@ import numpy as np
 
 from repro.core.batch import Config, ConfigBatch
 
+#: fixed keys of the latent-attention layer types, at DeepSeek-V3's widths
+#: where a config leaves one out: a head's non-RoPE and RoPE query/key dims
+#: and its value dim, and (``mla_decode``) the width of the cached latent
+MLA_FIXED = {"nope": 128, "rope": 64, "dv": 128, "kv_lora": 512}
+
+
+def mla_dims(get) -> tuple:
+    """``(nope, rope, dv, kv_lora)`` of a latent-attention config through its
+    ``get(key, default)`` (a dict's, a ``ConfigBatch``'s or a column dict's)."""
+    return tuple(get(k, v) for k, v in MLA_FIXED.items())
+
 
 def _conv_out(size: int, f: int, s: int, pad: int) -> int:
     return max(1, (size + 2 * pad - f) // s + 1)
@@ -46,6 +57,20 @@ def derived_features(layer_type: str, cfg: Config) -> dict[str, float]:
         kvh = max(1, cfg["H"] // cfg.get("kv_ratio", 4))
         macs = cfg["B"] * cfg["H"] * cfg["S_kv"] * cfg["Dh"]
         byt = cfg["B"] * kvh * cfg["S_kv"] * cfg["Dh"] * 2
+        return {"macs": macs, "bytes": byt}
+    if layer_type == "mla_prefill":
+        nope, rope, dv, _ = mla_dims(cfg.get)
+        # causal and unabsorbed: QK^T and PV, half the square each
+        macs = cfg["B"] * cfg["H"] * cfg["S"] ** 2 * (nope + rope + dv) / 2
+        byt = cfg["B"] * cfg["S"] * cfg["H"] * (2 * (nope + rope) + 2 * dv)
+        return {"macs": macs, "bytes": byt}
+    if layer_type == "mla_decode":
+        nope, rope, dv, lora = mla_dims(cfg.get)
+        bh = cfg["B"] * cfg["H"]
+        macs = bh * cfg["S_kv"] * (lora + rope) + bh * cfg["S_kv"] * lora + bh * lora * (nope + dv)
+        # the latent cache is read once for all of a sequence's heads
+        byt = cfg["B"] * cfg["S_kv"] * (lora + rope) + cfg["H"] * lora * (nope + dv) + bh * (
+            nope + rope + dv)
         return {"macs": macs, "bytes": byt}
     if layer_type == "moe_gemm":
         mats = cfg.get("mats", 3)
@@ -103,6 +128,18 @@ def derived_features_batch(layer_type: str, batch: ConfigBatch) -> np.ndarray:
         kvh = np.maximum(1, col("H") // get("kv_ratio", 4))
         macs = col("B") * col("H") * col("S_kv") * col("Dh")
         byt = col("B") * kvh * col("S_kv") * col("Dh") * 2
+        cols = [macs, byt]
+    elif layer_type == "mla_prefill":
+        nope, rope, dv, _ = mla_dims(get)
+        macs = col("B") * col("H") * col("S") ** 2 * (nope + rope + dv) / 2
+        byt = col("B") * col("S") * col("H") * (2 * (nope + rope) + 2 * dv)
+        cols = [macs, byt]
+    elif layer_type == "mla_decode":
+        nope, rope, dv, lora = mla_dims(get)
+        bh = col("B") * col("H")
+        macs = bh * col("S_kv") * (lora + rope) + bh * col("S_kv") * lora + bh * lora * (nope + dv)
+        byt = col("B") * col("S_kv") * (lora + rope) + col("H") * lora * (nope + dv) + bh * (
+            nope + rope + dv)
         cols = [macs, byt]
     elif layer_type == "moe_gemm":
         mats = get("mats", 3)

@@ -127,10 +127,13 @@ DEVICE_RTOL = 1e-12
 # count the forest calls and network groups each form traversed.
 # ``jax.network.rows`` counts the block-layer rows a network call sends to its
 # forests, ``jax.network.pad_rows`` the rows added to fill each group's
-# bucket, and ``jax.network.rows.ssm`` the rows of the Mamba-2 groups.
+# bucket, ``jax.network.rows.ssm`` the rows of the Mamba-2 groups and
+# ``jax.network.rows.mla`` those of the latent-attention groups.
 
 #: layer types of the Mamba-2 mixer, counted in ``jax.network.rows.ssm``
 SSM_LAYER_TYPES = frozenset({"ssd_scan", "ssd_decode"})
+#: layer types of latent attention, counted in ``jax.network.rows.mla``
+MLA_LAYER_TYPES = frozenset({"mla_prefill", "mla_decode"})
 
 _seen_forest_sigs: set[tuple] = set()
 _seen_network_sigs: set[tuple] = set()
@@ -611,7 +614,7 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
     pos = []
     Xs = []
     log_flags = []
-    pad_rows = ssm_rows = 0
+    pad_rows = ssm_rows = mla_rows = 0
     for g, (lt, est, cfgs) in enumerate(zip(batch.group_types, ests, batch.group_configs)):
         X = est._features(cfgs, snap=True)
         ng, d = X.shape
@@ -619,6 +622,8 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
         pad_rows += nb - ng
         if lt in SSM_LAYER_TYPES:
             ssm_rows += ng
+        elif lt in MLA_LAYER_TYPES:
+            mla_rows += ng
         Xp = np.zeros((nb, d), dtype=np.float64)
         Xp[:ng] = X
         p = np.full(nb, Lb, dtype=np.int64)  # pads write the dump slot
@@ -667,4 +672,5 @@ def _pack_network(oracle, batch, counts, net_id, n_nets: int) -> tuple | None:
     reg.inc("jax.network.rows", L)
     reg.inc("jax.network.pad_rows", pad_rows)
     reg.inc("jax.network.rows.ssm", ssm_rows)
+    reg.inc("jax.network.rows.mla", mla_rows)
     return tuple(log_flags), args

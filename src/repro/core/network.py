@@ -1,8 +1,9 @@
 """Whole-model decomposition: ModelConfig x InputShape x mesh -> building blocks.
 
 This is the bridge between the paper's methodology and the framework: any of
-the 10 assigned architectures, and any model built by a layer pattern
-(``ModelConfig.layer_pattern``, NemotronH's Mamba-2 / MoE / attention),
+the 10 assigned architectures, any model built by a layer pattern
+(``ModelConfig.layer_pattern``, NemotronH's Mamba-2 / MoE / attention), and
+DeepSeek's latent attention, leading dense layers and MTP module
 decomposes into per-device building-block
 instances (attention block, MLP block, MoE block, SSD block, embed, LM head)
 whose layer configurations live in the TPU-v5e platform's parameter spaces.
@@ -65,7 +66,32 @@ def _decompose_plan(
 
     coll_act = t_loc * d * 2.0  # one bf16 activation all-reduce payload
 
+    def mla_block() -> tuple:
+        # q_a and kv_a (the cached latent beside the shared RoPE key) are
+        # replicated over tp; each chip up-projects its own heads
+        nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        lora = cfg.kv_lora_rank
+        layers = [
+            ("dense", {"tokens": t_loc, "d_in": d, "d_out": cfg.q_lora_rank}),
+            ("dense", {"tokens": t_loc, "d_in": d, "d_out": lora + rope}),
+            ("dense", {"tokens": t_loc, "d_in": cfg.q_lora_rank, "d_out": h_loc * (nope + rope)}),
+        ]
+        if is_decode:
+            # absorbed: every head reads the whole latent of its sequence,
+            # W_UK and W_UV folded into the core
+            layers.append(("mla_decode", {"B": b_loc, "S_kv": seq_len, "H": h_loc, "nope": nope,
+                                          "rope": rope, "dv": dv, "kv_lora": lora}))
+        else:
+            # unabsorbed: the latent decompressed into each head's k_nope and v
+            layers.append(("dense", {"tokens": t_loc, "d_in": lora, "d_out": h_loc * (nope + dv)}))
+            layers.append(("mla_prefill", {"B": b_loc, "S": s, "H": h_loc, "nope": nope,
+                                           "rope": rope, "dv": dv}))
+        layers.append(("dense", {"tokens": t_loc, "d_in": h_loc * dv, "d_out": d}))
+        return ("attn", tuple(layers), coll_act)
+
     def attn_block() -> tuple:
+        if cfg.mla:
+            return mla_block()
         layers: list[tuple[str, dict]] = [
             ("dense", {"tokens": t_loc, "d_in": d, "d_out": (h_loc + 2 * kv_loc) * hd}),
         ]
@@ -148,7 +174,10 @@ def _decompose_plan(
         yield body(mlp_block(), cfg.n_layers)
     elif cfg.family == "moe":
         yield body(attn_block(), cfg.n_layers)
-        yield body(moe_block(), cfg.n_layers)
+        if cfg.moe_dense_layers:
+            yield body(("mlp", tuple(mlp_layers(cfg.moe_dense_d_ff)), coll_act),
+                       cfg.moe_dense_layers)
+        yield body(moe_block(), cfg.n_layers - cfg.moe_dense_layers)
     elif cfg.family == "ssm":
         yield body(ssd_block(), cfg.n_layers)
     elif cfg.family == "hybrid":
@@ -198,12 +227,21 @@ def _decompose_plan(
     else:
         raise ValueError(cfg.family)
 
+    # ---- multi-token prediction, in training only: the hidden state and
+    # the next token's embedding (the same lookup, shifted) concatenated and
+    # projected, one more layer, and the shared LM head once more ----
+    mtp = cfg.mtp_layers if is_train else 0
+    if mtp:
+        yield body(("mlp", (("dense", {"tokens": t_loc, "d_in": 2 * d, "d_out": d}),), 0.0), mtp)
+        yield body(attn_block(), mtp)
+        yield body(moe_block(), mtp)
+
     # ---- LM head ----
     yield (
         "mlp",
         (("dense", {"tokens": t_loc, "d_in": d, "d_out": np.maximum(1, v // tp)}),),
         0.0,
-        rep,
+        rep * (1 + mtp),
     )
 
 

@@ -1,4 +1,5 @@
-"""Model/architecture configuration for the 10 assigned architectures.
+"""Model/architecture configuration for the 10 assigned architectures and
+the ones the oracle only estimates.
 
 Every architecture is expressed as a single ``ModelConfig``; family-specific
 fields are zero/empty when unused.  The full configs (exercised only via the
@@ -42,6 +43,23 @@ class ModelConfig:
     capacity_factor: float = 1.25
     #: width of the shared expert every token passes through (0 = none)
     moe_shared_d_ff: int = 0
+    #: leading layers whose FFN is one dense MLP of ``moe_dense_d_ff`` and
+    #: not experts (DeepSeek's ``first_k_dense_replace``)
+    moe_dense_layers: int = 0
+    moe_dense_d_ff: int = 0
+
+    # --- multi-head latent attention (DeepSeek MLA): queries through a
+    # ``q_lora_rank`` latent, keys and values through one cached latent of
+    # ``kv_lora_rank`` beside a ``qk_rope_head_dim`` key shared by all heads.
+    # kv_lora_rank 0 = the GQA attention of n_heads / n_kv_heads / head_dim ---
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # --- multi-token prediction: depth of the MTP module (training only) ---
+    mtp_layers: int = 0
 
     # --- SSM (Mamba2) ---
     ssm_state: int = 0
@@ -86,6 +104,8 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0 and self.n_heads > 0:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.kv_lora_rank and not self.q_lora_rank:
+            raise ValueError("latent attention (kv_lora_rank) needs q_lora_rank")
         if self.layer_pattern:
             unknown = set(self.layer_pattern) - set(LAYER_KINDS)
             if unknown or len(self.layer_pattern) != self.n_layers:
@@ -106,6 +126,11 @@ class ModelConfig:
         if self.ssm_n_heads:
             return self.ssm_n_heads
         return self.d_inner // self.ssm_headdim if self.ssm_state else 0
+
+    @property
+    def mla(self) -> bool:
+        """Multi-head latent attention in place of GQA."""
+        return self.kv_lora_rank > 0
 
     @property
     def mlp_mats(self) -> int:
@@ -138,6 +163,12 @@ class ModelConfig:
         attn = d * n_q + 2 * d * n_kv + n_q * d
         if self.qkv_bias:
             attn += n_q + 2 * n_kv
+        if self.mla:
+            h, rope = self.n_heads, self.qk_rope_head_dim
+            q, kv = self.q_lora_rank, self.kv_lora_rank
+            attn = (d * q + q * h * (self.qk_nope_head_dim + rope) + d * (kv + rope)
+                    + kv * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d + q + kv)  # + the two latents' norms
         mlp = d * f * self.mlp_mats
         moe_mlp = self.mlp_mats * d * f * self.moe_experts + d * self.moe_experts
         ssm = 0
@@ -155,7 +186,13 @@ class ModelConfig:
             n += kinds.get("E", 0) * (moe_mlp + shared + d)
             n += kinds.get("*", 0) * (attn + d)
         elif self.family == "moe":
-            n += self.n_layers * (attn + moe_mlp + per_layer_norms)
+            shared = self.mlp_mats * d * self.moe_shared_d_ff
+            n_moe = self.n_layers - self.moe_dense_layers
+            n += self.n_layers * (attn + per_layer_norms) + n_moe * (moe_mlp + shared)
+            n += self.moe_dense_layers * self.mlp_mats * d * self.moe_dense_d_ff
+            # MTP: the concat projection, one more layer and its three norms;
+            # the embedding and the LM head are shared with the main model
+            n += self.mtp_layers * (2 * d * d + attn + moe_mlp + shared + per_layer_norms + 3 * d)
         elif self.family == "ssm":
             n += self.n_layers * (ssm + d)
         elif self.family == "hybrid":
@@ -175,7 +212,10 @@ class ModelConfig:
         """Active parameters per token (MoE: top-k experts only)."""
         if not self.moe_experts:
             return self.param_count()
-        moe_layers = self.layer_counts().get("E", 0) if self.layer_pattern else self.n_layers
+        if self.layer_pattern:
+            moe_layers = self.layer_counts().get("E", 0)
+        else:
+            moe_layers = self.n_layers - self.moe_dense_layers + self.mtp_layers
         d, f = self.d_model, self.d_ff
         dense_moe = self.mlp_mats * d * f * self.moe_experts
         active_moe = self.mlp_mats * d * f * self.moe_top_k
@@ -225,6 +265,13 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         moe_experts=min(cfg.moe_experts, 8) if cfg.moe_experts else 0,
         moe_top_k=min(cfg.moe_top_k, 2) if cfg.moe_top_k else 0,
         moe_shared_d_ff=256 if cfg.moe_shared_d_ff else 0,
+        moe_dense_layers=min(cfg.moe_dense_layers, 1),
+        moe_dense_d_ff=512 if cfg.moe_dense_d_ff else 0,
+        q_lora_rank=32 if cfg.q_lora_rank else 0,
+        kv_lora_rank=16 if cfg.kv_lora_rank else 0,
+        qk_nope_head_dim=16 if cfg.qk_nope_head_dim else 0,
+        qk_rope_head_dim=8 if cfg.qk_rope_head_dim else 0,
+        v_head_dim=16 if cfg.v_head_dim else 0,
         ssm_state=min(cfg.ssm_state, 16) if cfg.ssm_state else 0,
         ssm_headdim=32 if cfg.ssm_state else cfg.ssm_headdim,
         ssm_groups=min(cfg.ssm_groups, 2),

@@ -8,9 +8,14 @@ it became columnar, and every form of the decomposition must reproduce them:
 * every ``ARCHS`` and ``ESTIMATED`` model under every applicable ``SHAPES``
   entry and five ``(dp, tp)`` meshes, one step at a time, and all of them in
   one ``decompose_steps`` call whose kinds alternate;
-* the benchmark's two search models under their cells' shapes, every
-  ``default_candidates`` list of the cells' chip counts in one call, as the
+* the benchmark's search models under their cells' shapes, every
+  ``default_candidates`` list of 64, 256 and 1024 chips in one call, as the
   search makes it.
+
+DeepSeek-V3 came after the plan became columnar: its digests were recorded
+from that plan, with each step's blocks checked against the decomposition
+written out by hand (``tests/test_mla.py``) and against the benchmark's
+plain restatement (``tests/bench/test_bench_mla.py``).
 
 Regenerate deliberately, on a tree whose plan is known good, with
 ``PYTHONPATH=src python tests/test_decompose_golden.py``.
@@ -53,6 +58,15 @@ SEARCH_MODELS = {
          "ssm_chunk": 128,
          "layer_pattern": "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"},
         {"train_8k": (8192, 512, "train"), "decode_128k": (131072, 64, "decode")},
+    ),
+    "deepseek3.mla_search": (
+        {"name": "deepseek-v3", "family": "moe", "n_layers": 61, "d_model": 7168,
+         "n_heads": 128, "n_kv_heads": 128, "d_ff": 2048, "vocab": 129280,
+         "rope_theta": 10000.0, "norm_eps": 1e-06, "moe_experts": 256, "moe_top_k": 8,
+         "moe_shared_d_ff": 2048, "moe_dense_layers": 3, "moe_dense_d_ff": 18432,
+         "q_lora_rank": 1536, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+         "qk_rope_head_dim": 64, "v_head_dim": 128, "mtp_layers": 1},
+        {"prefill_32k": (32768, 64, "prefill"), "decode_128k": (131072, 256, "decode")},
     ),
 }
 
